@@ -33,9 +33,9 @@ def main() -> None:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
-    from __graft_entry__ import _enable_compile_cache
+    from consensus_tpu.parallel.topology import apply_compile_cache
 
-    _enable_compile_cache()
+    apply_compile_cache()
 
     from benchmarks.mp_common import make_client_keyring, make_raw_engine
     from consensus_tpu.models import ThreadCoalescingVerifier

@@ -3,7 +3,7 @@ TCP, one shared TPU behind a verification sidecar.
 
 The in-process benchmark (benchmarks/chain_crypto_tps.py) runs all n
 replicas under one Python GIL, which caps the integrated multiple at ~2x
-regardless of crypto speed (BASELINE.md round-3 analysis).  The reference
+regardless of crypto speed.  The reference
 never carries that handicap: its replicas are separate Go processes wired
 by Comm (reference pkg/api/dependencies.go:22-30).  This benchmark removes
 it the same way — every replica is its own interpreter/process:

@@ -1,5 +1,5 @@
 """Chain throughput benchmark: n in-process replicas over real TCP sockets
-with realtime schedulers, trivial crypto — the BASELINE.md "naive_chain
+with realtime schedulers, trivial crypto — the BASELINE.json "naive_chain
 tx/sec" harness (reference examples/naive_chain/chain_test.go:71-98 is the
 equivalent surface; the reference publishes no number).
 
@@ -100,8 +100,8 @@ def run_cell(
         from consensus_tpu.wal import WriteAheadLog
 
         # Real fsyncs with the repo's group-commit window (identical in
-        # every cell).  VERDICT.md records that the window "recovers
-        # nothing at depth-1 pipelining": with one slot in flight each
+        # every cell).  The window recovers nothing at depth-1
+        # pipelining: with one slot in flight each
         # persist barrier just waits out the window.  The sweep measures
         # how much of that the in-flight window wins back.
         return WriteAheadLog.create(
@@ -271,7 +271,7 @@ def main() -> None:
         results[depth] = cell
         print(json.dumps(cell), flush=True)
         if depth == 1:
-            # Historical record BASELINE.md tracks: the legacy protocol.
+            # The BASELINE.json metric: the legacy (depth-1) protocol.
             legacy = {
                 "metric": "naive_chain_tx_per_sec",
                 "value": cell["value"],

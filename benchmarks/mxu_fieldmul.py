@@ -17,7 +17,7 @@ Three lowerings of c = a * b over GF(2^255-19) limbs, all bit-exact:
             included to quantify why it cannot win (N-fold FLOP waste).
             Runs at a reduced batch to keep the waste affordable.
 
-The analysis this script exists to confirm or refute (BASELINE.md cost
+The analysis this script exists to confirm or refute (PERF.md §5 cost
 model): a matmul computes sum_i A[m,i] * B[i,n] — a SHARED contraction
 operand.  Batched elementwise bignum products share nothing across
 elements, so the MXU can only be fed by (a) replicating per-element
@@ -77,9 +77,9 @@ def main() -> None:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
-    from __graft_entry__ import _enable_compile_cache
+    from consensus_tpu.parallel.topology import apply_compile_cache
 
-    _enable_compile_cache()
+    apply_compile_cache()
 
     import random
 

@@ -63,32 +63,23 @@ class ObsConfig:
 
 @dataclass(frozen=True)
 class CompileCacheConfig:
-    """Compilation-cache knobs for the batch crypto engines (no reference
+    """Compilation-cache knob for the batch crypto engines (no reference
     counterpart).
 
     ``enabled`` governs the in-process compiled-kernel memo
     (parallel/sharding.py ``compiled_kernel``): engines built over the same
     ``(kernel, topology[, shape])`` key share one traced jit wrapper, so a
     fleet restart or supervisor ladder rebuild books ZERO new compiles in
-    the kernel ledger instead of a retrace storm.  ``persistent_dir`` (when
-    non-empty) additionally wires jax's persistent compilation cache to
-    that directory via :func:`consensus_tpu.parallel.topology.
-    apply_compile_cache`, so even a fresh PROCESS skips the XLA backend
-    compile; ``min_compile_time_secs`` filters which compiles are worth
-    persisting.  Both caches change only construction latency, never
-    verdicts.
+    the kernel ledger instead of a retrace storm.  The memo changes only
+    construction latency, never verdicts.
+
+    Where jax's PERSISTENT on-disk cache lives is not a configuration
+    field: the environment places it (``JAX_COMPILATION_CACHE_DIR``), else
+    it sits at a fixed path in the checkout — see
+    :func:`consensus_tpu.parallel.topology.apply_compile_cache`.
     """
 
     enabled: bool = True
-    persistent_dir: str = ""
-    min_compile_time_secs: float = 1.0
-
-    def validate(self) -> None:
-        if self.min_compile_time_secs < 0:
-            raise ValueError(
-                "invalid configuration: "
-                "compile_cache.min_compile_time_secs must be >= 0"
-            )
 
 
 @dataclass(frozen=True)
@@ -177,8 +168,9 @@ class Configuration:
     # only slices bytes into SHA-512 block layout.  Verdicts are bit-identical
     # to the host-prep engines on every accept/reject class (SAFETY.md §10),
     # so like mesh_shards this knob changes only WHERE the work runs, never
-    # the verdict — replicas in a cluster may differ freely.  Ed25519-only
-    # (engine_for_config rejects device_prep with the p256 curve).
+    # the verdict — replicas in a cluster may differ freely.  Ed25519 strict
+    # verification only (engine_for_config rejects device_prep with the p256
+    # curve and with batch_verify_mode).
     device_prep: bool = False
     # Device-mesh width for the batch engine (parallel/sharding.py): 1 keeps
     # today's single-device engines bit-for-bit; >1 selects the sharded
@@ -196,8 +188,8 @@ class Configuration:
     # verdict (the 2-D host-mesh parity gate pins this).  When both are
     # set, the axes product must equal mesh_shards.
     mesh_topology: tuple = ()
-    # Engine compilation caching (CompileCacheConfig above): default-on
-    # in-process kernel memo + optional persistent XLA cache directory.
+    # Engine compilation caching (CompileCacheConfig above): the default-on
+    # in-process kernel memo.
     compile_cache: CompileCacheConfig = field(default=CompileCacheConfig())
     # Engine supervision (models/supervisor.py): wrap the configured engine
     # in an EngineSupervisor — fault-classed circuit breakers (launch
@@ -297,10 +289,6 @@ class Configuration:
                         "mesh_topology axes product must equal mesh_shards "
                         "when both are set"
                     )
-        try:
-            self.compile_cache.validate()
-        except ValueError as exc:
-            errs.append(str(exc).replace("invalid configuration: ", ""))
         if self.engine_crosscheck_interval < 0:
             errs.append("engine_crosscheck_interval must be >= 0")
         if self.engine_crosscheck_interval and not self.engine_supervision:

@@ -776,7 +776,7 @@ class View:
         self.metrics.count_txs_in_batch.set(len(requests))
         # Stamped post-verification on every replica, keeping
         # latency_batch_processing's definition (prepare/commit exchange
-        # only) identical to the pre-reordering numbers in BASELINE.md.
+        # only) identical before and after the reveal-before-verify reordering.
         self._begin_pre_prepare = self._sched.now()
         self.phase = Phase.PROPOSED
         self.metrics.phase.set(int(self.phase))

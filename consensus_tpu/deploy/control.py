@@ -228,10 +228,13 @@ class ControlClient:
         except (OSError, ValueError):
             return None
 
-    def wait_ready(self, timeout: float) -> bool:
-        """Poll ``ping`` until the process answers or ``timeout`` elapses."""
+    def wait_ready(self, timeout: float, *, abort=None) -> bool:
+        """Poll ``ping`` until the process answers, ``abort()`` turns true
+        (the caller knows it never will), or ``timeout`` elapses."""
         deadline = time.monotonic() + timeout  # wallclock-ok
         while time.monotonic() < deadline:  # wallclock-ok
+            if abort is not None and abort():
+                return False
             reply = self.try_call("ping")
             if reply is not None and "error" not in reply:
                 return True

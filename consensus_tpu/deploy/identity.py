@@ -6,11 +6,7 @@ hex string minted once by the launcher and distributed in the config
 file).  Derivation is pure SHA-256 over namespaced tags — restarting a
 killed replica re-derives its identity bit-for-bit, which is what lets it
 rejoin the cluster after a ``kill -9`` with nothing but its config file
-and its WAL directory.
-
-Ed25519 only: the pure-Python RFC 8032 fallback in
-``consensus_tpu/models`` keeps the rig dependency-free (the ``cryptography``
-package is not required).
+and its WAL directory.  Ed25519 only.
 """
 
 from __future__ import annotations

@@ -4,8 +4,17 @@
 boots the sidecar fleet first (replicas dial it at verify time), then the
 replicas — every process under its own
 :class:`~consensus_tpu.deploy.supervisor.NodeSupervisor` — and waits for
-each control socket to answer.  From there the launcher is the rig's
-operator console:
+each control socket to answer.
+
+One process per chip: a sidecar is the only process of a rig that may open
+a JAX backend.  Replica (and driver) children get ``JAX_PLATFORMS=cpu``;
+the sidecar's platform is left to the environment, and the orchestrator
+that owns this launcher never initialises a backend itself.  Sidecars boot
+ONE AT A TIME with restart disarmed: each compiles its launch shape before
+it answers, and one that exits at boot (no TPU and not pinned to the CPU;
+a second device sidecar on a one-chip host) fails ``start`` with its own
+last line instead of being restarted behind replicas that would verify on
+the host.  From there the launcher is the rig's operator console:
 
 * health/leader probes and Prometheus scrapes across every process,
 * ledger-digest collection feeding the
@@ -70,14 +79,18 @@ class ClusterLauncher:
         self._sidecar_window: Dict[str, dict] = {}
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
+        #: Sidecar children: the environment as it is — on a machine with a
+        #: chip the sidecar is the one process that takes it.
         self._env = os.environ.copy()
         self._env["PYTHONPATH"] = (
             repo_root + os.pathsep + self._env.get("PYTHONPATH", "")
         ).rstrip(os.pathsep)
+        #: Everything else the rig spawns stays off the accelerator.
+        self.cpu_env = dict(self._env, JAX_PLATFORMS="cpu")
 
     # ------------------------------------------------------------- boot
 
-    def _make_supervisor(self, name, argv, control_addr) -> NodeSupervisor:
+    def _make_supervisor(self, name, argv, control_addr, env) -> NodeSupervisor:
         sup = NodeSupervisor(
             name,
             argv,
@@ -86,9 +99,26 @@ class ClusterLauncher:
             restart=self.restart,
             backoff_initial=self.backoff_initial,
             max_restarts=self.max_restarts,
-            env=self._env,
+            env=env,
         )
         self._all_sups.append(sup)
+        return sup
+
+    def _boot_sidecar(self, sc, timeout: float) -> NodeSupervisor:
+        """Spawn one sidecar and wait for it with restart DISARMED: its
+        exit at boot is a failure of the rig, not a crash to ride out."""
+        sup = self._make_supervisor(
+            sc.sidecar_id,
+            self._sidecar_argv(sc.sidecar_id),
+            (sc.host, sc.control_port),
+            self._env,
+        )
+        self.sidecars[sc.sidecar_id] = sup
+        sup.restart_enabled = False
+        sup.start()
+        if not sup.wait_healthy(timeout):
+            raise RuntimeError(sup.boot_failure())
+        sup.restart_enabled = self.restart
         return sup
 
     def _replica_argv(self, node_id: int) -> list:
@@ -113,22 +143,19 @@ class ClusterLauncher:
         deadline = time.monotonic() + timeout  # wallclock-ok
         sidecars = self.spec.sidecars if self.spawn_sidecars else []
         for sc in sidecars:
-            sup = self._make_supervisor(
-                sc.sidecar_id,
-                self._sidecar_argv(sc.sidecar_id),
-                (sc.host, sc.control_port),
+            self._boot_sidecar(
+                sc, max(0.0, deadline - time.monotonic())  # wallclock-ok
             )
-            self.sidecars[sc.sidecar_id] = sup
-            sup.start()
         for r in self.spec.replicas:
             sup = self._make_supervisor(
                 f"replica-{r.node_id}",
                 self._replica_argv(r.node_id),
                 (r.host, r.control_port),
+                self.cpu_env,
             )
             self.replicas[r.node_id] = sup
             sup.start()
-        for sup in list(self.sidecars.values()) + list(self.replicas.values()):
+        for sup in self.replicas.values():
             remaining = deadline - time.monotonic()  # wallclock-ok
             if remaining <= 0 or not sup.wait_healthy(remaining):
                 raise TimeoutError(f"{sup.name} failed to come up")
@@ -251,15 +278,7 @@ class ClusterLauncher:
     def add_sidecar(self, timeout: float = 60.0) -> str:
         sc = self.spec.add_sidecar()
         self.spec.write()
-        sup = self._make_supervisor(
-            sc.sidecar_id,
-            self._sidecar_argv(sc.sidecar_id),
-            (sc.host, sc.control_port),
-        )
-        self.sidecars[sc.sidecar_id] = sup
-        sup.start()
-        if not sup.wait_healthy(timeout):
-            raise TimeoutError(f"{sc.sidecar_id} failed to come up")
+        self._boot_sidecar(sc, timeout)
         logger.info("autoscaler: added %s", sc.sidecar_id)
         return sc.sidecar_id
 

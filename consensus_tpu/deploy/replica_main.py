@@ -22,6 +22,7 @@ timestamps.
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import logging
 import os
@@ -79,7 +80,11 @@ def main() -> int:
         SyncServer,
         TcpSyncTransport,
     )
-    from consensus_tpu.testing.crypto_app import SignedRequestApp
+    from consensus_tpu.testing.app import unpack_batch
+    from consensus_tpu.testing.crypto_app import (
+        SignedRequestApp,
+        request_ids_digest,
+    )
     from consensus_tpu.testing.storage import StorageFaultInjector
     from consensus_tpu.wal.log import initialize_and_read_all
 
@@ -89,6 +94,9 @@ def main() -> int:
     secret = spec.auth_secret
 
     # --- identity + engine ------------------------------------------------
+    # A replica never opens an accelerator backend (the launcher pins it to
+    # the CPU): its own engine is the host path, the device lives behind
+    # the sidecar client.
     host_engine = Ed25519BatchVerifier(min_device_batch=10**9)
     fleet = None
     if spec.sidecars:
@@ -209,13 +217,32 @@ def main() -> int:
     # --- control socket ---------------------------------------------------
     stop_event = threading.Event()
     scrape_count = [0]
+    # Committed-request count, advanced incrementally over the ledger
+    # (however its decisions arrived) so a load generator can poll it.
+    request_count = {"decisions": 0, "requests": 0}
+    request_count_lock = threading.Lock()
+
+    def _committed_requests() -> int:
+        with request_count_lock:
+            ledger = app.ledger
+            while request_count["decisions"] < len(ledger):
+                decision = ledger[request_count["decisions"]]
+                request_count["requests"] += len(
+                    unpack_batch(decision.proposal.payload)
+                )
+                request_count["decisions"] += 1
+            return request_count["requests"]
 
     def _health(_request) -> dict:
         h = dict(consensus.controller.health()) if consensus.controller else {}
         h.update(
             ok=True, role="replica", node_id=args.node_id, pid=os.getpid(),
             running=True, ledger=len(app.ledger), restarted=restarted,
+            requests=_committed_requests(),
             wal_recovery=bool(getattr(wal, "recovery", None)),
+            # Where this replica's signature verdicts came from (None: no
+            # sidecar fleet configured, everything is local by design).
+            sidecar=engine.counts() if fleet is not None else None,
         )
         return h
 
@@ -223,6 +250,26 @@ def main() -> int:
         start = int(request.get("from", 0))
         digests = [d.proposal.digest() for d in list(app.ledger)]
         return {"height": len(digests), "digests": digests[start:]}
+
+    def _delivered(_request) -> dict:
+        """Audit of what this replica delivered: request totals, how many
+        distinct (client, seq) identities, an order-free digest of those
+        identities and an ordered digest of the raw requests — "all of
+        them, exactly once, identically" is decidable from these."""
+        ordered = hashlib.sha256()
+        raws = []
+        decisions = list(app.ledger)
+        for decision in decisions:
+            for raw in unpack_batch(decision.proposal.payload):
+                ordered.update(raw)
+                raws.append(raw)
+        return {
+            "decisions": len(decisions),
+            "requests": len(raws),
+            "distinct": len({raw[:12] for raw in raws}),
+            "ids_digest": request_ids_digest(raws),
+            "digest": ordered.hexdigest(),
+        }
 
     def _prom(_request) -> dict:
         h = _health({})
@@ -265,6 +312,7 @@ def main() -> int:
                            "role": "replica", "node_id": args.node_id},
         "health": _health,
         "ledger": _ledger,
+        "delivered": _delivered,
         "metrics": lambda r: {"ok": True, "metrics": provider.dump()},
         "prom": _prom,
         "net_pause": lambda r: (comm.pause_listener(), {"ok": True})[1],

@@ -2,18 +2,41 @@
 
 ``python -m consensus_tpu.deploy.sidecar_main --config cluster.json
 --sidecar-id sc-K`` serves signature verification over authenticated TCP
-(:class:`~consensus_tpu.net.sidecar.VerifySidecarServer`) as one member of
-the horizontally scaled fleet.  Replicas reach it through
-:class:`~consensus_tpu.ingress.placement.SidecarFleet`; killing this
-process mid-run exercises the client's structured reroute path (the
-PR-12/13 fleet story), and the autoscaler drains/adds members by
-stopping/spawning these processes.
+(:class:`~consensus_tpu.net.sidecar.VerifySidecarServer`).  It is the ONE
+process of a rig that may open a JAX backend — on a machine with a chip,
+the process that holds it: replicas, the driver and the orchestrator are
+pinned to the CPU by the launcher, the sidecar's platform is left to the
+environment.
 
-The control socket exposes wave counters (offered/rejected) and an
-``engine_degraded`` flag — the two autoscaler input signals — plus a
-``degrade`` chaos arm that makes the engine wrapper report degraded
-without changing verdicts (the PR-13 shape: degraded means slow-but-
-correct, served from the host twin).
+Boot order is the contract ``launcher.start`` relies on:
+
+1. open the backend.  Unless ``JAX_PLATFORMS`` pins the process to the CPU
+   (the tier-1 rigs), anything but a TPU is refused with one line and a
+   non-zero exit — a sidecar that silently served from the host would let
+   replicas commit happily while the chip did nothing;
+2. build the engine the spec selects (``engine_for_config`` over
+   ``spec.make_configuration``) behind the thread coalescer, padded to the
+   ONE launch shape the spec implies
+   (:meth:`~consensus_tpu.deploy.spec.ClusterSpec.sidecar_wave_lanes`);
+3. compile that shape by pushing a warm-up wave through the coalescer, so
+   the first compile — like every later launch — runs on the flusher
+   thread and no two threads of this process ever compile at once;
+4. only then open the verify and control sockets and print ``ready``.
+
+Whether small waves go to the host is the spec's existing decision
+(``crypto_tpu_min_batch`` in ``config_overrides``).  A rig that wants a
+host-only sidecar — the tier-1 rigs, the process-chaos soak — sets it
+above any wave: such a sidecar opens no backend, compiles nothing, reports
+``platform: "host"`` and never competes for the chip.
+
+The control socket's ``health`` reports what a run needs to prove the
+device did the work: ``platform`` / ``device_kind`` / ``device_count``, the
+kernel ledger (launches, compiles, compiles since ready), signatures and
+lanes launched on the device, signatures served from the host, the
+coalescer's ``device_suspect`` flag and its degrade count — plus the wave
+counters (offered/rejected) and ``engine_degraded`` the autoscaler reads,
+and a ``degrade`` chaos arm that makes the engine wrapper report degraded
+without changing verdicts.
 """
 
 from __future__ import annotations
@@ -24,31 +47,74 @@ import logging
 import os
 import sys
 import threading
+import time
+
+#: Exit code of a sidecar that found no TPU and was not pinned to the CPU,
+#: or whose warm-up wave never came back from the device.
+EXIT_NO_DEVICE = 3
+#: Budget for the cold compile of the one launch shape (the launcher's own
+#: ``start`` deadline is usually the tighter bound).
+WARMUP_TIMEOUT = 900.0
 
 
 class _CountingEngine:
-    """Engine wrapper: counts waves for the autoscaler signals and honors
-    a chaos-armed degraded flag (verdicts never change — degraded is a
-    health report, not a correctness state)."""
+    """Engine wrapper under the coalescer: books every wave by where it
+    ran (device launch at the one padded shape, or the engine's host path)
+    and honors a chaos-armed degraded flag (verdicts never change —
+    degraded is a health report, not a correctness state)."""
 
-    def __init__(self, inner) -> None:
+    def __init__(self, inner, *, min_device_batch: int, lanes: int) -> None:
         self._inner = inner
+        self._min_device_batch = min_device_batch
+        self._lanes = lanes
         self.offered = 0
+        self.device_waves = 0
+        self.device_signatures = 0
+        self.device_lanes = 0
+        self.host_signatures = 0
         self.degraded = False
         self._lock = threading.Lock()
 
     def verify_batch(self, messages, signatures, public_keys):
+        n = len(messages)
         with self._lock:
-            self.offered += len(messages)
+            self.offered += n
+            if n >= self._min_device_batch:
+                self.device_waves += 1
+                self.device_signatures += n
+                self.device_lanes += self._lanes
+            else:
+                self.host_signatures += n
         return self._inner.verify_batch(messages, signatures, public_keys)
 
     def verify_host(self, messages, signatures, public_keys):
         with self._lock:
             self.offered += len(messages)
+            self.host_signatures += len(messages)
         return self._inner.verify_host(messages, signatures, public_keys)
+
+    def counts(self) -> dict:
+        with self._lock:
+            return {
+                "offered": self.offered,
+                "device_waves": self.device_waves,
+                "device_signatures": self.device_signatures,
+                "device_lanes": self.device_lanes,
+                "host_signatures": self.host_signatures,
+            }
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
+
+
+def _warm_wave(n: int):
+    """``n`` honest signatures under one fixed key (content is irrelevant
+    to the compile; they must only verify)."""
+    from consensus_tpu.models import Ed25519Signer
+
+    signer = Ed25519Signer(0, private_key_bytes=b"\x17" * 32)
+    msgs = [b"ctpu/sidecar-warm/%d" % i for i in range(n)]
+    return msgs, [signer.sign_raw(m) for m in msgs], [signer.public_bytes] * n
 
 
 def main() -> int:
@@ -65,32 +131,132 @@ def main() -> int:
 
     from consensus_tpu.deploy.control import ControlServer
     from consensus_tpu.deploy.spec import ClusterSpec
-    from consensus_tpu.models.ed25519 import Ed25519BatchVerifier
-    from consensus_tpu.net.sidecar import VerifySidecarServer
 
     spec = ClusterSpec.load(args.config)
     me = spec.sidecar(args.sidecar_id)
 
-    # Host path: on a machine without an accelerator the sidecar still
-    # serves real Ed25519 verification (pure host batches); with one, drop
-    # min_device_batch to route big waves to the device.
-    engine = _CountingEngine(Ed25519BatchVerifier(min_device_batch=10**9))
+    from consensus_tpu.models import ThreadCoalescingVerifier, engine_for_config
+    from consensus_tpu.net.sidecar import VerifySidecarServer
+    from consensus_tpu.obs.kernels import KERNELS
+
+    # --- the engine the spec selects, at the one shape it implies ---------
+    config = spec.make_configuration(spec.node_ids()[0])
+    lanes = spec.sidecar_wave_lanes()
+    min_device_batch = config.crypto_tpu_min_batch
+    #: The spec routes every wave to the host: no backend is opened and no
+    #: kernel compiled, so this process never competes for the chip.
+    host_only = min_device_batch > lanes
+
+    device_report = {"platform": "host", "device_kind": "", "device_count": 0,
+                     "cache_dir": ""}
+    backend_secs = 0.0
+    if not host_only:
+        import jax
+
+        from consensus_tpu.parallel.topology import apply_compile_cache
+
+        cache_dir = apply_compile_cache()
+        pinned_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+        t_boot = time.monotonic()  # wallclock-ok
+        devices = jax.devices()
+        backend_secs = time.monotonic() - t_boot  # wallclock-ok
+        device_report = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "cache_dir": cache_dir,
+        }
+        if devices[0].platform != "tpu" and not pinned_cpu:
+            print(
+                f"{args.sidecar_id}: no TPU for this process (jax found "
+                f"platform={devices[0].platform!r}) and JAX_PLATFORMS does "
+                "not pin it to cpu — refusing to serve verification from "
+                "the host",
+                file=sys.stderr, flush=True,
+            )
+            return EXIT_NO_DEVICE
+
+    engine = _CountingEngine(
+        engine_for_config(config, pad_to=lanes),
+        min_device_batch=min_device_batch,
+        lanes=lanes,
+    )
+    # hard_cap = the compiled shape: no launch can need another one.
+    coalescer = ThreadCoalescingVerifier(
+        engine,
+        window=config.crypto_batch_window,
+        max_batch=lanes,
+        hard_cap=lanes,
+        bypass_below=min_device_batch,
+        name=f"{args.sidecar_id}-flusher",
+    )
+
+    # --- compile before ready, on the flusher thread ----------------------
+    warm_secs = 0.0
+    if not host_only:
+        t0 = time.monotonic()  # wallclock-ok
+        try:
+            ok = coalescer.warm(
+                *_warm_wave(max(min_device_batch, 8)), timeout=WARMUP_TIMEOUT
+            )
+            failure = None if ok.all() and not coalescer.device_suspect else (
+                f"all valid: {bool(ok.all())}, "
+                f"device_suspect: {coalescer.device_suspect}"
+            )
+        except (TimeoutError, RuntimeError) as exc:
+            failure = repr(exc)
+        warm_secs = time.monotonic() - t0  # wallclock-ok
+        if failure is not None:
+            print(
+                f"{args.sidecar_id}: warm-up wave did not come back valid "
+                f"from the device ({failure}) — refusing to serve",
+                file=sys.stderr, flush=True,
+            )
+            return EXIT_NO_DEVICE
+    ready_ledger = KERNELS.totals()
+    ready_counts = engine.counts()
+    logging.getLogger("consensus_tpu.deploy").info(
+        "backend %s up in %.1fs, %d-lane shape warm in %.1fs",
+        device_report, backend_secs, lanes, warm_secs,
+    )
+
     server = VerifySidecarServer(
-        (me.host, me.port), engine, auth_secret=spec.auth_secret
+        (me.host, me.port), coalescer, auth_secret=spec.auth_secret
     )
     server.start()
 
     stop_event = threading.Event()
-    rejected = [0]
 
     def _health(_request) -> dict:
+        ledger = KERNELS.totals()
+        counts = engine.counts()
         return {
             "ok": True,
             "role": "sidecar",
             "sidecar_id": args.sidecar_id,
             "pid": os.getpid(),
-            "offered": engine.offered,
-            "rejected": rejected[0],
+            **device_report,
+            "lanes": lanes,
+            "min_device_batch": min_device_batch,
+            "backend_secs": round(backend_secs, 3),
+            "warm_compile_secs": round(warm_secs, 3),
+            "kernels": KERNELS.snapshot(),
+            "launches": ledger["launches"],
+            "compiles": ledger["compiles"],
+            "compiles_after_ready": ledger["compiles"] - ready_ledger["compiles"],
+            # Traffic only: the warm-up wave is booked apart.
+            "launches_after_ready": ledger["launches"] - ready_ledger["launches"],
+            "device_waves": counts["device_waves"] - ready_counts["device_waves"],
+            "device_signatures": (
+                counts["device_signatures"] - ready_counts["device_signatures"]
+            ),
+            "device_lanes": counts["device_lanes"] - ready_counts["device_lanes"],
+            "host_signatures": counts["host_signatures"],
+            "device_suspect": coalescer.device_suspect,
+            "degrade_count": coalescer.health.suspect_marks,
+            # Autoscaler signals.
+            "offered": counts["offered"],
+            "rejected": 0,
             "engine_degraded": engine.degraded,
         }
 
@@ -111,12 +277,15 @@ def main() -> int:
         port=me.control_port,
     )
     print(json.dumps({"ready": True, "sidecar_id": args.sidecar_id,
-                      "pid": os.getpid()}), flush=True)
+                      "pid": os.getpid(),
+                      "platform": device_report["platform"]}),
+          flush=True)
 
     while not stop_event.wait(0.5):
         pass
 
     server.stop()
+    coalescer.close()
     control.close()
     return 0
 

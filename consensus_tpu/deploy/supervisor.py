@@ -228,12 +228,33 @@ class NodeSupervisor:
         proc = self._proc
         return proc is not None and proc.poll() is None
 
+    @property
+    def exit_code(self) -> Optional[int]:
+        """The current child's exit code (negative: a signal), None while
+        it runs."""
+        proc = self._proc
+        return proc.poll() if proc is not None else None
+
     def probe(self) -> Optional[dict]:
         """The child's ``health`` answer, or None when unreachable."""
         return self.control.try_call("health")
 
     def wait_healthy(self, timeout: float) -> bool:
-        return self.control.wait_ready(timeout)
+        """Until the child answers ``ping`` or ``timeout`` elapses.  With
+        restart disabled a dead child can never answer, so its exit ends
+        the wait at once (:meth:`boot_failure` says why)."""
+        return self.control.wait_ready(
+            timeout, abort=lambda: not self.restart_enabled and not self.alive
+        )
+
+    def boot_failure(self) -> str:
+        """Why :meth:`wait_healthy` gave up: the child's exit code and last
+        stderr line, or a plain timeout."""
+        code = self.exit_code
+        if code is None:
+            return f"{self.name} did not answer its control socket in time"
+        last = self._tail[-1] if self._tail else ""
+        return f"{self.name} exited with code {code} at boot: {last}"
 
     # -------------------------------------------------------------- chaos
 

@@ -17,10 +17,7 @@ from consensus_tpu.models.supervisor import (
     HostTwin,
     LaunchTimeout,
 )
-from consensus_tpu.models.fused import (
-    FusedEd25519BatchVerifier,
-    FusedEd25519RandomizedBatchVerifier,
-)
+from consensus_tpu.models.fused import FusedEd25519BatchVerifier
 from consensus_tpu.models.verifier import (
     EcdsaP256Signer,
     EcdsaP256VerifierMixin,
@@ -39,7 +36,6 @@ __all__ = [
     "Ed25519BatchVerifier",
     "Ed25519RandomizedBatchVerifier",
     "FusedEd25519BatchVerifier",
-    "FusedEd25519RandomizedBatchVerifier",
     "L",
     "BatchCoalescer",
     "ThreadCoalescingVerifier",

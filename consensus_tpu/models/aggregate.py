@@ -139,7 +139,6 @@ class HalfAggregator:
         min_device_batch: int = 1,
         pad_to: int = 0,
         min_bisect: int = 2,
-        device_prep: Optional[bool] = None,
     ) -> None:
         if engine is not None:
             pad_pow2 = getattr(engine, "_pad_pow2", pad_pow2)
@@ -147,17 +146,11 @@ class HalfAggregator:
                 engine, "_min_device_batch", min_device_batch
             )
             pad_to = getattr(engine, "_pad_to", pad_to)
-            if device_prep is None:
-                # Inherit the fused front-end from the engine: a
-                # device_prep deployment's cert verifies go bytes-in →
-                # verdict-out too.
-                device_prep = bool(getattr(engine, "fused", False))
         self._engine = engine
         self._pad_pow2 = pad_pow2
         self._min_device_batch = min_device_batch
         self._pad_to = pad_to
         self._min_bisect = max(2, int(min_bisect))
-        self._device_prep = bool(device_prep)
         #: Aggregate-equation checks performed (each is one MSM launch on
         #: the device path / one host-twin evaluation).
         self.aggregate_checks = 0
@@ -284,25 +277,6 @@ class HalfAggregator:
             ) >= fe.P:
                 return False
         self.aggregate_checks += 1
-        if self._device_prep and n >= self._min_device_batch:
-            # Fused path: coefficient transcript, challenge hashing, and
-            # the mod-L products all happen inside the one MSM launch
-            # (models/fused.py) — the host work above was byte compares.
-            from consensus_tpu.models.fused import fused_aggregate_check
-
-            eq_ok, valid = fused_aggregate_check(
-                name="ed25519.fused_halfagg_verify",
-                tag=_HALFAGG_TAG,
-                messages=messages,
-                rs=rs,
-                keys=public_keys,
-                leaf_mids=rs,
-                pad_to=self._pad_to,
-                pad_pow2=self._pad_pow2,
-                u_bytes=s_agg,
-                fixed_z1=True,
-            )
-            return bool(all(valid) and eq_ok)
         zs = halfagg_coefficients(messages, rs, public_keys)
         zk = [
             (z * _challenge(r, a, m)) % L

@@ -118,37 +118,23 @@ def verify_impl(
     r2 = r2.astype(jnp.float32)
     q = p256.affine_like(qx, qy)
     q_ok = p256.on_curve(qx, qy)
-    from consensus_tpu.ops.pallas_scan import scan_config
+    q_table = p256.multiples_table(q, _TABLE_SIGNED)
+    lanes = jnp.arange(_TABLE_SIGNED, dtype=jnp.int32)[:, None]
 
-    pallas_cfg = scan_config(qx.shape[-1])
-    if pallas_cfg is not None:
-        # Opt-in whole-scan-in-VMEM Pallas kernel (CTPU_PALLAS_SCAN=1):
-        # same arithmetic, different scheduling — see ops/pallas_scan.py.
-        tile, interpret = pallas_cfg
-        from consensus_tpu.ops.pallas_scan import horner_scan_p256
-
-        acc = horner_scan_p256(
-            qx, qy, u2_digits, tile=tile, interpret=interpret
+    def step(acc: p256.Point, w):
+        d = w - 8  # signed digit in [-8, 7] ({0, 1} for the carry window)
+        oh2 = (jnp.abs(d)[None] == lanes).astype(jnp.float32)
+        # 4 doubles as an inner scan: one double body in the graph instead
+        # of four (trace/compile-size economy, identical runtime schedule).
+        acc, _ = jax.lax.scan(
+            lambda a, _: (p256.double(a), None), acc, None, length=4
         )
-    else:
-        q_table = p256.multiples_table(q, _TABLE_SIGNED)
-        lanes = jnp.arange(_TABLE_SIGNED, dtype=jnp.int32)[:, None]
+        t = p256.table_lookup(q_table, oh2)
+        t = p256.select(d < 0, p256.negate(t), t)
+        acc = p256.add(acc, t)
+        return acc, None
 
-        def step(acc: p256.Point, w):
-            d = w - 8  # signed digit in [-8, 7] ({0, 1} for the carry window)
-            oh2 = (jnp.abs(d)[None] == lanes).astype(jnp.float32)
-            # 4 doubles as an inner scan: one double body in the graph
-            # instead of four (trace/compile-size economy, identical
-            # runtime schedule).
-            acc, _ = jax.lax.scan(
-                lambda a, _: (p256.double(a), None), acc, None, length=4
-            )
-            t = p256.table_lookup(q_table, oh2)
-            t = p256.select(d < 0, p256.negate(t), t)
-            acc = p256.add(acc, t)
-            return acc, None
-
-        acc, _ = jax.lax.scan(step, p256.identity_like(qx), u2_digits)
+    acc, _ = jax.lax.scan(step, p256.identity_like(qx), u2_digits)
     acc = p256.add(acc, p256.fixed_base_mul_comb(u1_digits))
 
     # Accept iff R' is not the identity and x(R') ≡ r (mod n):

@@ -1,38 +1,31 @@
-"""Fused bytes-in → verdict-out Ed25519 engines (``Configuration.device_prep``).
+"""Fused bytes-in → verdict-out strict Ed25519 engine
+(``Configuration.device_prep``).
 
-The legacy engines split a wave into host prep (SHA-512 challenge hashing,
-mod-L reduction, canonical-range checks, digit recoding — a Python loop per
-signature) and a device MSM launch; the last live device measurement
-attributed its throughput to *pipelining* that prep behind the kernel.
-These engines delete the tax instead: the host does byte movement only
-(slice ``R ‖ A ‖ M`` into padded SHA-512 block layout — :func:`consensus_tpu
-.ops.sha512.pad_messages`), and one jitted graph per wave does everything
-else on device:
+The host-prep engine splits a wave into host prep (SHA-512 challenge
+hashing, mod-L reduction, canonical-range checks, digit recoding — a Python
+loop per signature) and a device launch.  This engine moves that prep into
+the launch: the host does byte movement only (slice ``R ‖ A ‖ M`` into
+padded SHA-512 block layout — :func:`consensus_tpu.ops.sha512
+.pad_messages`), and one jitted graph per wave does everything else on
+device:
 
     SHA-512 → reduce mod L → digit recode → canonical checks →
-    decompress → MSM → verdict
-
-For the randomized-batch and half-agg paths the Fiat–Shamir transcript
-itself moves on device: per-lane leaf hashes, the root hash assembled from
-the leaf digests *without leaving the device* (:func:`consensus_tpu.ops
-.sha512.pack_bytes_device`), coefficient hashes ``zᵢ = H(root ‖ i)``, the
-products ``zᵢkᵢ mod L`` / ``Σ zᵢsᵢ mod L``, and the shared-doubling MSM —
-one launch per aggregate check, no host round-trip between hashing and MSM.
+    decompress → double-scalar multiplication → verdict
 
 Parity contract (SAFETY.md §10): with ``device_prep`` on, accept/reject is
-bit-identical to the host-prep engines on every rejection class — forged
-and tampered lanes reject by math, ``S ≥ L`` / non-canonical ``y`` reject
-by the same range checks (now computed on device for the strict path), and
-the randomized transcript bytes are identical, so bisection takes identical
-paths.  ``device_prep`` off is bit-for-bit the previous protocol: these
-classes are additive.
+bit-identical to the host-prep strict engine on every rejection class —
+forged and tampered lanes reject by math, ``S ≥ L`` / non-canonical ``y``
+reject by the same range checks, now computed on device.  ``device_prep``
+off is bit-for-bit the previous protocol: the class is additive.
 
-Graph shapes: the strict kernel is shape-polymorphic over (block count ×
-batch) like the legacy kernel ladder; the aggregate kernels additionally
-specialize on the live subset size ``n`` (the transcript's root message
-length is ``len(tag) + 8 + 64n`` bytes — a different committed count IS a
-different hash).  Waves formed at fixed sizes (``pad_to``/coalescer) hit
-one compiled graph forever.
+The graph is shape-polymorphic over (block count × batch) like the
+host-prep kernel ladder.  There is no fused randomized or half-agg lane:
+those graphs baked the LIVE subset size into the compiled transcript (a
+new compile for every distinct wave size and every bisection level), which
+on the chip meant minutes of compilation per wave — removed in PR 22 (see
+PERF.md); ``device_prep`` with ``batch_verify_mode`` is refused by the
+engine registry, and half-aggregated certs always use the host-derived
+transcript in front of the device MSM.
 
 Input buffers are donated to the runtime on accelerator backends (the
 block arrays are the dominant transfer; donation lets XLA alias them into
@@ -57,14 +50,9 @@ from consensus_tpu.ops import sha512 as sh
 
 from consensus_tpu.models.ed25519 import (
     _WINDOWS,
-    _Z_TAG,
-    _Z_WINDOWS,
     _next_pow2,
-    _transcript_coefficients,
     Ed25519BatchVerifier,
-    Ed25519RandomizedBatchVerifier,
     L,
-    batch_verify_impl,
     verify_impl,
 )
 
@@ -278,311 +266,8 @@ class FusedEd25519BatchVerifier(Ed25519BatchVerifier):
             yield np.asarray(pending[1])[: pending[0]]
 
 
-# --- the fused aggregate kernels (randomized batch + half-agg) -------------
-
-
-def _aggregate_constants(tag: bytes, n: int, padded: int):
-    """Host constants baked into one aggregate graph: the transcript
-    prefix/trailers and the per-lane index rows."""
-    prefix = tag + n.to_bytes(8, "little")
-    root_len = len(prefix) + 64 * n
-    root_blocks = sh.padded_blocks_for(root_len)
-    root_prefix = np.frombuffer(prefix, dtype=np.uint8)[:, None]
-    root_trailer = np.frombuffer(sh.pad_trailer(root_len), dtype=np.uint8)[:, None]
-    z_trailer = np.broadcast_to(
-        np.frombuffer(sh.pad_trailer(72), dtype=np.uint8)[:, None], (56, padded)
-    )
-    idx_rows = _byte_rows(
-        [i.to_bytes(8, "little") for i in range(padded)], 8
-    ).T  # (8, padded)
-    return root_prefix, root_trailer, root_blocks, z_trailer, idx_rows
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_aggregate_kernel(
-    name: str, tag: bytes, n: int, padded: int, fixed_z1: bool, u_input: bool
-):
-    """Build + jit one aggregate graph: device Fiat–Shamir transcript
-    (leaves → root → coefficients) feeding the shared-doubling MSM.
-
-    ``fixed_z1`` pins lane 0's coefficient to 1 (half-aggregation);
-    ``u_input`` takes the aggregate base scalar from the cert instead of
-    computing ``Σ zᵢsᵢ mod L`` from per-lane S (which half-agg verifiers
-    never see).  Specialized per (n, padded) — stats still accumulate
-    under one kernel-accounting ``name``.
-    """
-    (
-        root_prefix, root_trailer, root_blocks, z_trailer, idx_rows
-    ) = _aggregate_constants(tag, n, padded)
-    one_z = np.zeros((16, 1), dtype=np.int32)
-    one_z[0, 0] = 1
-
-    def impl(
-        r_rows,       # (32, padded) R bytes
-        s_rows,       # (32, padded) S bytes (zeros when u_input)
-        key_rows,     # (32, padded) A bytes
-        k_blocks,     # (Bk, 16, 2, padded) SHA-512(R‖A‖M) blocks
-        k_nblocks,    # (padded,)
-        leaf_blocks,  # (Bl, 16, 2, padded) transcript leaf blocks
-        leaf_nblocks, # (padded,)
-        u_bytes,      # (32, 1) aggregate base scalar (ignored unless u_input)
-        host_ok,      # (padded,)
-    ):
-        r = r_rows.astype(jnp.int32)
-        key = key_rows.astype(jnp.int32)
-
-        # Challenge scalars kᵢ = H(Rᵢ‖Aᵢ‖mᵢ) mod L.
-        k_digest = sh.digest_bytes(sh.sha512_blocks(k_blocks, k_nblocks))
-        k_bytes = sc.reduce_bytes_mod_l(k_digest)
-
-        # Transcript: leaves on every lane, root over the live n, then
-        # zᵢ = H(root ‖ i)[:16] (or 1) — all without leaving the device.
-        leaves = sh.digest_bytes(sh.sha512_blocks(leaf_blocks, leaf_nblocks))
-        root_rows = jnp.concatenate(
-            [
-                jnp.asarray(root_prefix, jnp.int32),
-                leaves[:, :n].T.reshape(64 * n, 1),
-                jnp.asarray(root_trailer, jnp.int32),
-            ],
-            axis=0,
-        )
-        root_state = sh.sha512_blocks(
-            sh.pack_bytes_device(root_rows),
-            jnp.full((1,), root_blocks, jnp.int32),
-        )
-        root = sh.digest_bytes(root_state)  # (64, 1)
-
-        z_rows = jnp.concatenate(
-            [
-                jnp.broadcast_to(root, (64, padded)),
-                jnp.asarray(idx_rows, jnp.int32),
-                jnp.asarray(z_trailer, jnp.int32),
-            ],
-            axis=0,
-        )
-        z_digest = sh.digest_bytes(
-            sh.sha512_blocks(
-                sh.pack_bytes_device(z_rows), jnp.ones((padded,), jnp.int32)
-            )
-        )
-        z = z_digest[:16]
-        z = jnp.where(
-            (z == 0).all(axis=0)[None], jnp.asarray(one_z), z
-        )  # z = 0 is re-mapped to 1, same as the host derivation
-        if fixed_z1:
-            lane0 = (jnp.arange(padded) == 0)[None]
-            z = jnp.where(lane0, jnp.asarray(one_z), z)
-
-        zk = sc.mul_mod_l(z, k_bytes)
-        zk_digits = sc.signed_window_digits(zk, _WINDOWS)
-        z_digits = sc.signed_window_digits(z, _Z_WINDOWS)
-
-        if u_input:
-            u = u_bytes.astype(jnp.int32)
-        else:
-            u = sc.sum_mod_l(sc.mul_mod_l(z, s_rows.astype(jnp.int32)))
-
-        y_r = jnp.concatenate([r[:31], (r[31] & 0x7F)[None]], axis=0)
-        y_a = jnp.concatenate([key[:31], (key[31] & 0x7F)[None]], axis=0)
-        return batch_verify_impl(
-            y_r, r[31] >> 7, y_a, key[31] >> 7, u, zk_digits, z_digits, host_ok
-        )
-
-    donate = (3, 5) if jax.default_backend() != "cpu" else ()
-    return instrumented_jit(impl, name, donate_argnums=donate)
-
-
-def _frame(raw: bytes) -> bytes:
-    return len(raw).to_bytes(8, "little") + bytes(raw)
-
-
-def fused_aggregate_check(
-    *,
-    name: str,
-    tag: bytes,
-    messages: Sequence[bytes],
-    rs: Sequence[bytes],
-    keys: Sequence[bytes],
-    leaf_mids: Sequence[bytes],
-    pad_to: int,
-    pad_pow2: bool,
-    s_rows: Optional[np.ndarray] = None,
-    u_bytes: Optional[bytes] = None,
-    fixed_z1: bool = False,
-) -> tuple[bool, list[bool]]:
-    """Run one fused aggregate check: returns ``(eq_ok, valid)``.
-
-    ``leaf_mids`` is the middle frame of each transcript leaf — the full
-    signature for the randomized batch (``ctpu/batchz/v1``), R alone for
-    half-agg (``ctpu/halfagg/v1``).  Callers guarantee every lane already
-    passed the canonical host pre-checks (transcript membership must match
-    the host twin exactly).
-    """
-    n = len(messages)
-    r_rows = _byte_rows([bytes(r) for r in rs], 32)
-    key_rows = _byte_rows([bytes(a) for a in keys], 32)
-    k_blocks, k_nblocks = _pack_blocks(
-        [bytes(r) + bytes(a) + bytes(m) for r, a, m in zip(rs, keys, messages)]
-    )
-    leaf_blocks, leaf_nblocks = _pack_blocks(
-        [
-            _frame(m) + _frame(mid) + _frame(a)
-            for m, mid, a in zip(messages, leaf_mids, keys)
-        ]
-    )
-    if s_rows is None:
-        s_rows = np.zeros((n, 32), dtype=np.uint8)
-    host_ok = np.ones(n, dtype=bool)
-
-    if pad_to >= n:
-        padded = pad_to
-    else:
-        padded = _next_pow2(n) if pad_pow2 else n
-    r_rows, s_rows, key_rows, k_nblocks, leaf_nblocks, host_ok = _pad_wave(
-        [r_rows, s_rows, key_rows, k_nblocks, leaf_nblocks, host_ok], n, padded
-    )
-    if padded != n:
-        batch_pad = ((0, 0),) * 3 + ((0, padded - n),)
-        k_blocks = np.pad(k_blocks, batch_pad)
-        leaf_blocks = np.pad(leaf_blocks, batch_pad)
-
-    u_row = np.frombuffer(
-        u_bytes if u_bytes is not None else b"\x00" * 32, dtype=np.uint8
-    ).reshape(32, 1)
-
-    kernel = _fused_aggregate_kernel(
-        name, bytes(tag), n, padded, fixed_z1, u_bytes is not None
-    )
-    eq_ok, valid = kernel(
-        jnp.asarray(np.ascontiguousarray(r_rows.T)),
-        jnp.asarray(np.ascontiguousarray(s_rows.T)),
-        jnp.asarray(np.ascontiguousarray(key_rows.T)),
-        jnp.asarray(k_blocks),
-        jnp.asarray(k_nblocks),
-        jnp.asarray(leaf_blocks),
-        jnp.asarray(leaf_nblocks),
-        jnp.asarray(u_row),
-        jnp.asarray(host_ok),
-    )
-    return bool(np.asarray(eq_ok)), list(np.asarray(valid)[:n])
-
-
-class FusedEd25519RandomizedBatchVerifier(
-    Ed25519RandomizedBatchVerifier, FusedEd25519BatchVerifier
-):
-    """Randomized batch verification with the transcript derived on device.
-
-    Bit-identical verdicts to the host-prep
-    :class:`~consensus_tpu.models.ed25519.Ed25519RandomizedBatchVerifier`:
-    the device transcript hashes the same framed bytes, so coefficients,
-    aggregate verdicts, and bisection paths coincide exactly.  Host
-    challenge scalars are never computed on the device path — the
-    ``hashlib`` loop only runs if a subset falls back to the host twin
-    (``min_device_batch`` routing) or under the strict floor.
-    """
-
-    fused = True
-
-    def verify_batch(self, messages, signatures, public_keys) -> np.ndarray:
-        n = len(messages)
-        if not (n == len(signatures) == len(public_keys)):
-            raise ValueError("batch length mismatch")
-        results = np.zeros(n, dtype=bool)
-        if n == 0:
-            return results
-        host_ok = canonical_ok_fast(signatures, public_keys)
-        self._check(
-            [i for i in range(n) if host_ok[i]],
-            messages, signatures, public_keys, {}, results,
-        )
-        return results
-
-    @staticmethod
-    def _host_scalars(idx, messages, signatures, public_keys) -> dict:
-        """Lazy (s, k) big-int scalars for the host-twin fallback only."""
-        import hashlib
-
-        scalars = {}
-        for i in idx:
-            sig = bytes(signatures[i])
-            k = int.from_bytes(
-                hashlib.sha512(
-                    sig[:32] + bytes(public_keys[i]) + bytes(messages[i])
-                ).digest(),
-                "little",
-            ) % L
-            scalars[i] = (int.from_bytes(sig[32:], "little"), k)
-        return scalars
-
-    def _strict_floor(self, messages, signatures, public_keys) -> np.ndarray:
-        """Strict verification under ``min_randomized`` — stays on the fused
-        engine (the sharded subclass re-routes it onto the mesh)."""
-        return FusedEd25519BatchVerifier.verify_batch(
-            self, messages, signatures, public_keys
-        )
-
-    def _fused_aggregate(self, idx, messages, signatures, public_keys):
-        """One fused aggregate check over the subset ``idx`` — the seam the
-        sharded engine overrides with its mesh launch."""
-        return fused_aggregate_check(
-            name="ed25519.fused_batch_verify" + kernel_lane_suffix(),
-            tag=_Z_TAG,
-            messages=[messages[i] for i in idx],
-            rs=[bytes(signatures[i])[:32] for i in idx],
-            keys=[public_keys[i] for i in idx],
-            leaf_mids=[signatures[i] for i in idx],
-            s_rows=_byte_rows(
-                [bytes(signatures[i])[32:] for i in idx], 32
-            ),
-            pad_to=self._pad_to,
-            pad_pow2=self._pad_pow2,
-        )
-
-    def _check(self, idx, messages, signatures, public_keys, scalars, results):
-        if not idx:
-            return
-        if len(idx) < self._min_randomized:
-            sub = self._strict_floor(
-                [messages[i] for i in idx],
-                [signatures[i] for i in idx],
-                [public_keys[i] for i in idx],
-            )
-            for j, i in enumerate(idx):
-                results[i] = bool(sub[j])
-            return
-        if len(idx) >= self._min_device_batch:
-            eq_ok, valid = self._fused_aggregate(
-                idx, messages, signatures, public_keys
-            )
-        else:
-            zs = _transcript_coefficients(
-                [messages[i] for i in idx],
-                [signatures[i] for i in idx],
-                [public_keys[i] for i in idx],
-            )
-            eq_ok, valid = self._aggregate_host(
-                idx, signatures, public_keys,
-                self._host_scalars(idx, messages, signatures, public_keys), zs,
-            )
-        if not all(valid):
-            survivors = [i for i, ok in zip(idx, valid) if ok]
-            self._check(
-                survivors, messages, signatures, public_keys, scalars, results
-            )
-            return
-        if eq_ok:
-            for i in idx:
-                results[i] = True
-            return
-        mid = len(idx) // 2
-        self._check(idx[:mid], messages, signatures, public_keys, scalars, results)
-        self._check(idx[mid:], messages, signatures, public_keys, scalars, results)
-
-
 __all__ = [
     "FusedEd25519BatchVerifier",
-    "FusedEd25519RandomizedBatchVerifier",
     "canonical_ok_fast",
-    "fused_aggregate_check",
     "fused_verify_impl",
 ]

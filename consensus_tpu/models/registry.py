@@ -53,7 +53,7 @@ class EngineKey:
     #: only — the lane is selected at trace time by the environment, so a
     #: config knob would let key and traced graph disagree; the key axis
     #: exists so the registry can refuse cells the lane does not cover
-    #: (P-256 has no MXU MSM) instead of silently falling back.
+    #: (P-256) instead of silently falling back.
     mxu: bool = False
 
     def __post_init__(self) -> None:
@@ -108,15 +108,21 @@ class EngineRegistry:
             return f"unknown curve {key.curve!r}"
         if key.mxu and key.curve != "ed25519":
             return (
-                "CTPU_MXU_LIMBS engines are Ed25519-only: P-256 has no MXU "
-                "Straus/MSM kernel yet, and building a P-256 engine under "
-                "an MXU key would silently run a half-MXU lane the A/B "
-                "never measured — unset CTPU_MXU_LIMBS for P-256 engines"
+                "CTPU_MXU_LIMBS engines are Ed25519-only: a P-256 engine "
+                "under an MXU key would silently run a lane no chip run "
+                "ever checked — unset CTPU_MXU_LIMBS for P-256 engines"
             )
         if key.curve == "p256" and key.mode == "randomized":
             return "batch_verify_mode is Ed25519-only (no randomized P-256 lane)"
         if key.curve == "p256" and key.device_prep:
             return "device_prep is Ed25519-only (no fused P-256 front-end)"
+        if key.device_prep and key.mode == "randomized":
+            return (
+                "device_prep is strict-only: the fused randomized lane "
+                "compiled a new graph for every live wave size (minutes "
+                "each on the chip) and was removed — use batch_verify_mode "
+                "with host prep, or device_prep with strict verification"
+            )
         return (
             f"no engine registered under {key} "
             f"(registered: {', '.join(str(k) for k in self.keys())})"
@@ -151,8 +157,9 @@ class EngineRegistry:
 # --- the default matrix ------------------------------------------------------
 #
 # 2 curves x strict/randomized x single/mesh x host-prep/device-prep, minus
-# the Ed25519-only lanes: randomized and fused have no P-256 counterpart,
-# so those cells stay UNREGISTERED and lookups explain why.
+# the Ed25519-only lanes (randomized and fused have no P-256 counterpart)
+# and minus device-prep x randomized (removed in PR 22): those cells stay
+# UNREGISTERED and lookups explain why.
 
 
 def _require_mxu_lane() -> None:
@@ -174,16 +181,7 @@ def _ed25519_single(topology, compile_cache, *, randomized, fused, mxu=False, **
     if mxu:
         _require_mxu_lane()
     if fused:
-        from consensus_tpu.models.fused import (
-            FusedEd25519BatchVerifier,
-            FusedEd25519RandomizedBatchVerifier,
-        )
-
-        cls = (
-            FusedEd25519RandomizedBatchVerifier
-            if randomized
-            else FusedEd25519BatchVerifier
-        )
+        from consensus_tpu.models.fused import FusedEd25519BatchVerifier as cls
     else:
         from consensus_tpu.models.ed25519 import (
             Ed25519BatchVerifier,
@@ -205,7 +203,6 @@ def _ed25519_mesh(topology, compile_cache, *, randomized, fused, mxu=False, **kw
         (False, False): sharding.ShardedEd25519Verifier,
         (True, False): sharding.ShardedEd25519RandomizedVerifier,
         (False, True): sharding.ShardedFusedEd25519Verifier,
-        (True, True): sharding.ShardedFusedEd25519RandomizedVerifier,
     }[(randomized, fused)]
     return cls(topology, compile_cache=compile_cache, **kw)
 
@@ -228,8 +225,10 @@ def _default_registry() -> EngineRegistry:
     reg = EngineRegistry()
     for mode in MODES:
         for fused in (False, True):
+            randomized = mode == "randomized"
+            if fused and randomized:
+                continue  # UNREGISTERED: _missing_reason names the refusal
             for mxu in (False, True):
-                randomized = mode == "randomized"
                 reg.register(
                     EngineKey("ed25519", mode, "single", fused, mxu),
                     partial(
@@ -244,8 +243,7 @@ def _default_registry() -> EngineRegistry:
                         randomized=randomized, fused=fused, mxu=mxu,
                     ),
                 )
-    # p256 x mxu stays UNREGISTERED (no MXU MSM for P-256);
-    # _missing_reason names the refusal.
+    # p256 x mxu stays UNREGISTERED; _missing_reason names the refusal.
     reg.register(EngineKey("p256", "strict", "single", False), _p256_single)
     reg.register(EngineKey("p256", "strict", "mesh", False), _p256_mesh)
     return reg

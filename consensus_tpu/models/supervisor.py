@@ -52,7 +52,7 @@ FAULT_CLASSES = ("launch_timeout", "launch_raise", "wrong_answer")
 
 
 class LaunchTimeout(TimeoutError):
-    """A device launch exceeded its deadline (wedged tunnel, hung transfer).
+    """A device launch exceeded its deadline (hung device call, hung transfer).
 
     Raised into the supervisor by integration points that can observe a
     timeout without blocking forever — the coalescer's waiter path, or the

@@ -46,7 +46,9 @@ def raw_message(data: bytes) -> bytes:
     return _RAW_TAG + data
 
 
-def engine_for_config(config, curve: str = "ed25519", *, metrics=None):
+def engine_for_config(
+    config, curve: str = "ed25519", *, metrics=None, pad_to: int = 0
+):
     """The batch engine matching a ``Configuration``'s crypto knobs
     (``batch_verify_mode``, ``crypto_pad_pow2``, ``crypto_tpu_min_batch``,
     ``mesh_shards`` / ``mesh_topology``, ``device_prep``), routed through
@@ -66,8 +68,7 @@ def engine_for_config(config, curve: str = "ed25519", *, metrics=None):
     ``config.compile_cache`` governs construction cost: the in-process
     compiled-kernel memo means rebuilding an engine over the same topology
     (restart, supervisor ladder, tenant churn) books zero new compiles in
-    the kernel ledger, and a non-empty ``persistent_dir`` additionally
-    wires jax's on-disk compilation cache.  Pass a node ``Metrics`` bundle
+    the kernel ledger.  Pass a node ``Metrics`` bundle
     as ``metrics`` to book this construction's memo hits/misses into the
     pinned ``engine_compile_cache_{hits,misses}_total`` counters.
 
@@ -77,17 +78,23 @@ def engine_for_config(config, curve: str = "ed25519", *, metrics=None):
     circuit breakers route launches down fused → unfused → host (and
     mesh → single device → host) and re-promote when the breaker
     closes.  Supervision, too, changes only WHERE work runs — never the
-    verdict — so it is per-replica free."""
+    verdict — so it is per-replica free.
+
+    ``pad_to`` > 0 pins every device launch to that ONE padded shape (the
+    engines' ``pad_to``): a server that knows its largest wave — the rig
+    sidecar derives it from the cluster spec — compiles once before it
+    serves and never mid-run."""
     from consensus_tpu.obs.kernels import COMPILE_CACHE
 
     before = COMPILE_CACHE.snapshot()
     if not getattr(config, "engine_supervision", False):
-        engine = _engine_for_config(config, curve)
+        engine = _engine_for_config(config, curve, pad_to)
     else:
         from consensus_tpu.models.supervisor import EngineSupervisor
 
         rungs = [
-            _engine_for_config(c, curve) for c in degrade_ladder_configs(config)
+            _engine_for_config(c, curve, pad_to)
+            for c in degrade_ladder_configs(config)
         ]
         engine = EngineSupervisor(
             rungs,
@@ -128,53 +135,34 @@ def degrade_ladder_configs(config) -> list:
     return ladder
 
 
-def _engine_for_config(config, curve: str = "ed25519"):
+def _engine_for_config(config, curve: str = "ed25519", pad_to: int = 0):
     """The unsupervised engine routing (see :func:`engine_for_config`):
     config -> ``EngineKey`` -> registered builder."""
     from consensus_tpu.models.registry import ENGINE_REGISTRY, engine_key_for
-    from consensus_tpu.parallel.topology import (
-        apply_compile_cache,
-        topology_for_config,
-    )
+    from consensus_tpu.parallel.topology import topology_for_config
 
     cache = getattr(config, "compile_cache", None)
-    apply_compile_cache(cache)
     return ENGINE_REGISTRY.build(
         engine_key_for(config, curve),
         topology=topology_for_config(config),
         compile_cache=bool(getattr(cache, "enabled", True)),
         pad_pow2=config.crypto_pad_pow2,
         min_device_batch=config.crypto_tpu_min_batch,
+        pad_to=pad_to,
     )
 
 
 class Ed25519Signer(Signer):
-    """This replica's signing identity (private key stays host-side).
-
-    Uses the ``cryptography`` package when installed; otherwise signs with
-    the pure-Python RFC 8032 reference in :mod:`consensus_tpu.models
-    .ed25519` — same keys, same signatures, Python-speed."""
+    """This replica's signing identity (private key stays host-side),
+    signing through the ``cryptography`` package (OpenSSL)."""
 
     def __init__(self, node_id: int, private_key_bytes: Optional[bytes] = None) -> None:
+        from cryptography.hazmat.primitives import serialization
+        from cryptography.hazmat.primitives.asymmetric.ed25519 import (
+            Ed25519PrivateKey,
+        )
+
         self.node_id = node_id
-        self._key = None
-        try:
-            from cryptography.hazmat.primitives import serialization
-            from cryptography.hazmat.primitives.asymmetric.ed25519 import (
-                Ed25519PrivateKey,
-            )
-        except ImportError:
-            import os
-
-            from consensus_tpu.models.ed25519 import ref_public_key, ref_sign
-
-            seed = (
-                private_key_bytes if private_key_bytes is not None
-                else os.urandom(32)
-            )
-            self.public_bytes = ref_public_key(seed)
-            self._sign_fn = lambda data, _seed=seed: ref_sign(_seed, data)
-            return
         if private_key_bytes is None:
             self._key = Ed25519PrivateKey.generate()
         else:

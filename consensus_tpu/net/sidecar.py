@@ -30,9 +30,12 @@ attribution in :data:`consensus_tpu.obs.kernels.TENANT_KERNELS`.
 
 Client side, :class:`SidecarVerifierClient` is a drop-in ``engine`` for the
 ``Verifier`` mixins (same ``verify_batch`` contract).  With a
-``local_engine`` supplied it also inherits the wedged-device escape hatch:
+``local_engine`` supplied it also inherits the hung-device escape hatch:
 a sidecar that dies or stalls past ``request_timeout`` fails over to local
 host verification (slower, still correct) instead of wedging the replica.
+Every signature is booked by where its verdict came from
+(:meth:`SidecarVerifierClient.counts`), so a run can prove — or disprove —
+that the sidecar's device did the work.
 An admission reject surfaces as :class:`TenantAdmissionReject` (structured:
 tenant, queue depth, limit) and falls back locally WITHOUT marking the
 sidecar suspect — the service is healthy, the tenant is over quota.
@@ -747,6 +750,26 @@ class SidecarVerifierClient:
         #: while a background probe watches for recovery.
         self._suspect = False
         self._closed = False
+        #: Where every signature's verdict came from (see :meth:`counts`).
+        self._counts = {"sent": 0, "served": 0, "bypassed": 0, "fallen_back": 0}
+        self._counts_lock = threading.Lock()
+
+    def _book(self, key: str, n: int) -> None:
+        with self._counts_lock:
+            self._counts[key] += n
+
+    def counts(self) -> dict:
+        """Signature counts by verdict source, plus the suspect flag:
+        ``sent`` (put on the wire to the sidecar), ``served`` (answered by
+        it), ``bypassed`` (below ``bypass_below``: verified locally by
+        design) and ``fallen_back`` (verified locally because the sidecar
+        was suspect, timed out, errored or rejected the batch).  A replica
+        whose device path is healthy shows ``served == sent`` and
+        ``fallen_back == 0``."""
+        with self._counts_lock:
+            out = dict(self._counts)
+        out["suspect"] = self._suspect
+        return out
 
     # -- engine contract ---------------------------------------------------
 
@@ -762,13 +785,16 @@ class SidecarVerifierClient:
         if self._suspect and self._local is not None:
             # Wedged sidecar: don't stall request_timeout on every call —
             # the background probe clears the flag when it recovers.
+            self._book("fallen_back", n)
             return np.asarray(
                 self._local.verify_host(messages, signatures, public_keys)
             )
         if n < self._bypass_below:
+            self._book("bypassed", n)
             return np.asarray(
                 self._local.verify_host(messages, signatures, public_keys)
             )
+        self._book("sent", n)
         try:
             result = self._roundtrip(messages, signatures, public_keys)
         except TenantAdmissionReject as reject:
@@ -787,6 +813,7 @@ class SidecarVerifierClient:
             )
             if tracer is not None and tracer.enabled:
                 tracer.instant("net", "sidecar.fallback", n=n)
+            self._book("fallen_back", n)
             return np.asarray(
                 self._local.verify_host(messages, signatures, public_keys)
             )
@@ -805,9 +832,11 @@ class SidecarVerifierClient:
             )
             if tracer is not None and tracer.enabled:
                 tracer.instant("net", "sidecar.fallback", n=n)
+            self._book("fallen_back", n)
             return np.asarray(
                 self._local.verify_host(messages, signatures, public_keys)
             )
+        self._book("served", n)
         return result
 
     def _fleet_reroute(self, messages, signatures, keys, reject):

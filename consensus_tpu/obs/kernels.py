@@ -166,11 +166,7 @@ def _cache_size(jitted) -> int:
 
 
 def _cost_number(analysis, key: str) -> Optional[float]:
-    # cost_analysis() is a flat dict on current jax; older versions returned
-    # a one-element list of dicts.
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
-    if not isinstance(analysis, dict):
+    if not isinstance(analysis, dict):  # a backend may report no analysis
         return None
     v = analysis.get(key)
     return float(v) if v is not None else None

@@ -424,28 +424,7 @@ def straus_shared_msm(
 
     Because z < 2^128 its high windows are all zero, the scan runs in two
     phases — ``64 - Wz`` A-only windows, then ``Wz`` combined windows —
-    instead of padding z to 64 rows of dead lookups/adds.
-
-    Under ``CTPU_MXU_LIMBS=1`` (and outside ``suppress_pallas_scan`` —
-    the sharded engines trace under it) this dispatches to the
-    VMEM-resident Pallas kernel (:func:`pallas_scan.straus_msm`), seeded
-    from each table's entry 1 (the base points).  Verdicts are invariant
-    — see the kernel's projective-representative note.  Counted traces
-    (``limbs.counting()``) keep the XLA path: a ``fori_loop`` body traces
-    once without the scan-weight stack, so the kernel would silently
-    undercount — the measured denominator describes the XLA-scheduled
-    MSM with MXU field contractions."""
-    if not limbs.counting():
-        from consensus_tpu.ops import pallas_scan
-
-        cfg = pallas_scan.msm_config(int(zk_digits.shape[-1]))
-        if cfg is not None:
-            tile, interpret = cfg
-            return pallas_scan.straus_msm(
-                a_table.x[1], a_table.y[1], a_table.z[1], a_table.t[1],
-                r_table.x[1], r_table.y[1], r_table.z[1], r_table.t[1],
-                zk_digits, z_digits, tile=tile, interpret=interpret,
-            )
+    instead of padding z to 64 rows of dead lookups/adds."""
     n_low = z_digits.shape[0]
     n_high = zk_digits.shape[0] - n_low
     acc0 = identity_like(a_table.x[0][..., :1])  # (32limbs, 1)

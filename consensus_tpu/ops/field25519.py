@@ -25,14 +25,13 @@ Why pure XLA and no hand-written Pallas kernel *on this lane*: the verify
 graph is a ``lax.scan`` of elementwise/broadcast limb arithmetic, which
 XLA already fuses into large VPU kernels; a per-field-op ``pallas_call``
 only adds launch overhead (a round-2 prototype confirmed parity but no
-win and was removed).  The two deferred headroom items both landed behind
-``CTPU_MXU_LIMBS=1``: :mod:`consensus_tpu.ops.mxu_limbs` re-expresses the
-schoolbook convolution as integer ``dot_general`` tiles for the MXU
-(``mul``/``square`` below dispatch there at trace time, bit-identical
-output), and :mod:`consensus_tpu.ops.pallas_scan` grew the VMEM-resident
-Straus/MSM kernel that keeps the 64-step doubling chain's table and
-accumulator on-chip.  Measured CPU denominators for the A/B live in
-BASELINE.md ("MXU lane" section).
+win and was removed), and the whole-scan-in-VMEM kernels that followed
+were refused by Mosaic on the chip and removed in PR 22 (PERF.md §6).
+One headroom item remains behind ``CTPU_MXU_LIMBS=1``:
+:mod:`consensus_tpu.ops.mxu_limbs` re-expresses the schoolbook convolution
+as integer ``dot_general`` tiles for the MXU (``mul``/``square`` below
+dispatch there at trace time, bit-identical output).  Counted denominators
+for the A/B live in PERF.md §5.
 
 Normalization contract: public ops take and return *weakly reduced*
 elements — |limb| <= 340 with value within (-2^250, 2^255 + 2^13), exact

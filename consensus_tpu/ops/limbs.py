@@ -6,7 +6,7 @@ vectors; the exact sequential int32 carry normalization is identical and
 lives here so a carry-semantics fix can never diverge between curves.
 
 This module also hosts the **field-multiplication counting shim** that makes
-kernel cost models *measured* instead of estimated (BASELINE.md).  The field
+kernel cost models *counted* instead of estimated (PERF.md §5).  The field
 stacks report every ``mul``/``square`` through :func:`note_mul` /
 :func:`note_square`, weighted by how many independent field elements the op
 touches (the batch lanes) and by the length of every enclosing ``lax.scan``

@@ -39,18 +39,15 @@ Lane selection is **trace-time**: the field stacks consult
 :func:`lane_active` inside ``mul``/``square``, so a process opts in with
 ``CTPU_MXU_LIMBS=1`` (read per trace — already-compiled shapes keep their
 lane) and bench A/Bs flip lanes in-process with :func:`force_mxu_limbs` /
-:func:`suppress_mxu_limbs` around fresh jits.  Pallas kernel bodies trace
-under :func:`suppress_mxu_limbs` — a ``dot_general`` inside a Mosaic
-kernel is unvalidated lowering risk, and the kernels' whole point is VPU
-scheduling.
+:func:`suppress_mxu_limbs` around fresh jits.
 
 Counting: the shim (:mod:`consensus_tpu.ops.limbs`) records this lane's
 work through :func:`~consensus_tpu.ops.limbs.note_dot` as dense MACs —
 the outer product is 1024 MACs/lane and the column assembly 63 * 1024 =
 64,512 MACs/lane, ~64x the VPU lane's useful multiplies.  That ratio is
 the honest price of dense tiling (the MXU does not skip the zeros in C);
-BASELINE.md records it as the measured denominator the device A/B must
-beat with systolic-array throughput.
+PERF.md §5 records it as the counted denominator a device A/B must beat
+with systolic-array throughput.
 """
 
 from __future__ import annotations
@@ -74,7 +71,7 @@ _FOLD = 38
 _TOP_FOLD = 19
 
 #: Trace-time lane overrides (module globals, mutated only under the
-#: context managers below — same discipline as pallas_scan._SUPPRESSED).
+#: context managers below).
 _FORCED = False
 _SUPPRESSED = False
 
@@ -83,8 +80,7 @@ def lane_active() -> bool:
     """True when field ``mul``/``square`` should trace the MXU lane.
 
     Checked per trace by the field stacks; already-compiled shapes keep
-    whichever lane they were traced under.  Suppression wins over forcing
-    (a Pallas kernel body must stay VPU-shaped even inside a forced A/B).
+    whichever lane they were traced under.  Suppression wins over forcing.
     """
     if _SUPPRESSED:
         return False
@@ -110,7 +106,7 @@ def force_mxu_limbs():
 @contextlib.contextmanager
 def suppress_mxu_limbs():
     """Trace the VPU lane inside this block regardless of the environment
-    (Pallas kernel bodies; the bench A/B's control arm)."""
+    (the bench A/B's control arm)."""
     global _SUPPRESSED
     prev = _SUPPRESSED
     _SUPPRESSED = True
@@ -237,10 +233,9 @@ def square25519(a: jnp.ndarray) -> jnp.ndarray:
 @functools.lru_cache(maxsize=1)
 def _solinas_i32() -> np.ndarray:
     """field_p256's (32, 64) Solinas matrix as exact int32 (entries are
-    integers with |m| <= 4, so the f32 -> int32 cast is lossless).  A
-    snapshot, deliberately NOT the live ``fp._SOLINAS_M`` global — the
-    Pallas trace windows monkeypatch that, and this lane is suppressed
-    inside kernels anyway."""
+    integers with |m| <= 4, so the f32 -> int32 cast is lossless).  Built
+    from ``fp._solinas_matrix()``, not read from the ``fp._SOLINAS_M``
+    global."""
     from consensus_tpu.ops import field_p256 as fp
 
     return np.asarray(fp._solinas_matrix(), dtype=np.int32)

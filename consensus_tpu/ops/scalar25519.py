@@ -1,10 +1,9 @@
 """On-device scalar arithmetic mod L (the edwards25519 group order).
 
-The last host big-int holdout of the verification pipeline: reducing the
-512-bit challenge hash mod L, the Fiat–Shamir coefficient products
-``zᵢ·kᵢ mod L``, and the aggregate base scalar ``Σ zᵢ·sᵢ mod L`` all ran
-as Python integers between the SHA-512 stage and the MSM kernels.  This
-module does them in the same batched 8-bit-limb discipline as
+The last host big-int holdout of the strict verification pipeline:
+reducing the 512-bit challenge hash mod L ran as Python integers between
+the SHA-512 stage and the kernel.  This module does it in the same batched
+8-bit-limb discipline as
 :mod:`consensus_tpu.ops.field25519` — bytes on the trailing-batch lanes,
 products held exactly in f32's 24-bit integer window, sequential int32
 carries only at stage boundaries.
@@ -97,35 +96,6 @@ def reduce_bytes_mod_l(x_bytes: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
-def mul_mod_l(a_bytes: jnp.ndarray, b_bytes: jnp.ndarray) -> jnp.ndarray:
-    """Product mod L of little-endian byte rows ``(na, batch)`` ×
-    ``(nb, batch)`` with na·min(na,nb) small enough that schoolbook columns
-    stay f32-exact (the pipeline's shapes are 16×32 and 32×32: columns
-    <= 32·255² < 2^22)."""
-    na, batch = a_bytes.shape
-    nb = b_bytes.shape[0]
-    if min(na, nb) > 32:
-        raise ValueError("schoolbook columns would overflow the f32 window")
-    a = a_bytes.astype(jnp.float32)
-    b = b_bytes.astype(jnp.float32)
-    limbs.note_byte_muls(na * nb, batch)
-    cols = jnp.zeros((64, batch), jnp.float32)
-    for i in range(na):  # static unroll: na broadcast-multiplies
-        cols = cols.at[i : i + nb].add(a[i][None] * b)
-    canon, _ = limbs.carry_i32(cols.astype(jnp.int32))  # < 2^384 << 2^512
-    return reduce_bytes_mod_l(canon)
-
-
-def sum_mod_l(vals_bytes: jnp.ndarray) -> jnp.ndarray:
-    """Sum over the batch axis mod L: canonical byte rows ``(32, batch)``
-    -> canonical bytes ``(32, 1)``.  Column sums stay int32-exact up to
-    batch 2^23."""
-    summed = vals_bytes.astype(jnp.int32).sum(axis=-1, keepdims=True)
-    ext = jnp.concatenate([summed, jnp.zeros((32, 1), jnp.int32)], axis=0)
-    canon, _ = limbs.carry_i32(ext)  # value < batch·L < 2^280 << 2^512
-    return reduce_bytes_mod_l(canon)
-
-
 def lt_l(s_bytes: jnp.ndarray) -> jnp.ndarray:
     """On-device malleability check ``S < L`` (RFC 8032 §5.1.7) over
     ``(32, batch)`` little-endian byte rows."""
@@ -171,8 +141,6 @@ __all__ = [
     "L",
     "L_BYTES_LE",
     "lt_l",
-    "mul_mod_l",
     "reduce_bytes_mod_l",
     "signed_window_digits",
-    "sum_mod_l",
 ]

@@ -1,9 +1,9 @@
 """Vectorized SHA-512 over lanes of padded blocks (FIPS 180-4).
 
 The missing piece of the bytes-in → verdict-out pipeline: the Ed25519
-challenge ``k = SHA-512(R ‖ A ‖ M) mod L`` and the Fiat–Shamir transcript
-hashes all ran through ``hashlib`` on the host, serializing a Python loop
-in front of every MSM launch.  This module hashes a whole wave per launch:
+challenge ``k = SHA-512(R ‖ A ‖ M) mod L`` ran through ``hashlib`` on the
+host, serializing a Python loop in front of every launch.  This module
+hashes a whole wave per launch:
 the batch rides the trailing axis (the vector lanes), the 80-round
 compression runs as one ``lax.scan`` body, and multi-block messages scan
 over a leading block axis with a per-lane active-block count so one fixed
@@ -23,9 +23,7 @@ Layouts:
 * device: :func:`sha512_blocks` → state ``(8, 2, batch)`` uint32;
   :func:`digest_bytes` → ``(64, batch)`` int32 digest bytes in stream
   order (byte 0 first — little-endian weight ``2^(8i)`` for the scalar
-  stack); :func:`pack_bytes_device` turns device-resident padded byte
-  rows back into block layout (transcript hashing composes hashes of
-  hashes without a host round-trip).
+  stack).
 """
 
 from __future__ import annotations
@@ -228,20 +226,6 @@ def digest_bytes(state: jnp.ndarray) -> jnp.ndarray:
     return expanded.reshape(64, state.shape[-1]).astype(jnp.int32)
 
 
-def pack_bytes_device(rows: jnp.ndarray) -> jnp.ndarray:
-    """Device-resident padded byte rows ``(B*128, batch)`` -> block layout
-    ``(B, 16, 2, batch)`` uint32.  Lets transcript stages hash values that
-    were themselves just hashed on device (leaves -> root -> coefficients)
-    without a host round-trip."""
-    total, batch = rows.shape
-    if total % BLOCK_BYTES:
-        raise ValueError("row length must be a multiple of 128")
-    r = rows.astype(jnp.uint32).reshape(total // BLOCK_BYTES, 16, 2, 4, batch)
-    return (
-        (r[..., 0, :] << 24) | (r[..., 1, :] << 16) | (r[..., 2, :] << 8) | r[..., 3, :]
-    )
-
-
 # --- host packing ----------------------------------------------------------
 
 
@@ -289,7 +273,6 @@ def pad_messages(
 __all__ = [
     "BLOCK_BYTES",
     "digest_bytes",
-    "pack_bytes_device",
     "pad_messages",
     "pad_trailer",
     "padded_blocks_for",

@@ -24,14 +24,12 @@ _SHARDING_NAMES = frozenset(
         "ShardedEcdsaP256Verifier",
         "ShardedEd25519RandomizedVerifier",
         "ShardedEd25519Verifier",
-        "ShardedFusedEd25519RandomizedVerifier",
         "ShardedFusedEd25519Verifier",
         "clear_compiled_kernels",
         "compiled_kernel",
         "make_mesh",
         "mesh_for_shards",
         "sharded_batch_verify_fn",
-        "sharded_fused_aggregate_fn",
         "sharded_fused_verify_fn",
         "sharded_p256_verify_fn",
         "sharded_verify_fn",

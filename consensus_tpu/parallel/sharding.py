@@ -23,7 +23,7 @@ Two entry points:
   :class:`~consensus_tpu.models.ed25519.Ed25519BatchVerifier` that pads the
   batch to a multiple of the mesh size and runs the sharded kernel.
 
-Kernel construction rides an in-process ``(kernel, topology[, shape])`` ->
+Kernel construction rides an in-process ``(kernel, topology)`` ->
 compiled-fn memo (:func:`compiled_kernel`): rebuilding an engine — fleet
 restart, tenant churn, supervisor ladder reconstruction — reuses the
 already-traced jit wrapper instead of paying a retrace storm, which the obs
@@ -48,10 +48,7 @@ from consensus_tpu.models.ed25519 import (
     to_kernel_layout,
     verify_impl,
 )
-from consensus_tpu.models.fused import (
-    FusedEd25519BatchVerifier,
-    FusedEd25519RandomizedBatchVerifier,
-)
+from consensus_tpu.models.fused import FusedEd25519BatchVerifier
 from consensus_tpu.obs.kernels import (
     COMPILE_CACHE,
     instrumented_jit,
@@ -63,13 +60,6 @@ from consensus_tpu.parallel.topology import (
     engine_padded_size,
     mesh_padded_size,
 )
-
-# jax.shard_map was promoted to the top level after 0.4.x; older releases
-# ship it under jax.experimental only.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.5 installs
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 #: Device-layout partition specs: limb/bit arrays are (20|256, batch) —
 #: batch is the trailing axis; per-element vectors are (batch,).  These are
@@ -97,8 +87,7 @@ def _reduce_axes(mesh: Mesh):
 def _mesh_specs(mesh: Mesh, specs):
     """Widen 1-D spec templates to ``mesh``: every ``BATCH_AXIS`` entry
     becomes the full axis-name tuple, so the batch dimension is sharded
-    across ALL mesh axes (row-major — matching tiled ``all_gather`` order
-    and the linear :func:`_shard_index`)."""
+    across ALL mesh axes (row-major)."""
     names = tuple(mesh.axis_names)
     if names == (BATCH_AXIS,):
         return tuple(specs)
@@ -108,29 +97,17 @@ def _mesh_specs(mesh: Mesh, specs):
     )
 
 
-def _shard_index(mesh: Mesh):
-    """This shard's linear index in global (row-major) lane order — inside a
-    shard body only.  Reduces to the historical ``axis_index(BATCH_AXIS)``
-    on 1-D meshes."""
-    names = tuple(mesh.axis_names)
-    idx = jax.lax.axis_index(names[0])
-    for name in names[1:]:
-        idx = idx * mesh.shape[name] + jax.lax.axis_index(name)
-    return idx
-
-
 # --- in-process compiled-kernel memo ----------------------------------------
 
 _COMPILED_KERNELS: dict = {}
 
 
-def _kernel_key(name: str, mesh: Mesh, extra: tuple) -> tuple:
+def _kernel_key(name: str, mesh: Mesh) -> tuple:
     return (
         name,
         tuple(mesh.axis_names),
         tuple(mesh.devices.shape),
         tuple(int(d.id) for d in mesh.devices.flat),
-        extra,
     )
 
 
@@ -140,17 +117,15 @@ def compiled_kernel(
     builder: Callable[[], Callable],
     *,
     memo: bool = True,
-    extra: tuple = (),
 ) -> Callable:
-    """The in-process ``(kernel, topology[, shape])`` -> compiled-fn memo.
+    """The in-process ``(kernel, topology)`` -> compiled-fn memo.
 
     A jit wrapper's trace cache lives on the wrapper object, so an engine
     that builds a fresh wrapper per construction re-traces every compiled
     shape on rebuild even when XLA's persistent cache skips the backend
     compile.  Two engines over the same mesh run the same computation, so
     the wrapper itself is shared here instead — a rebuilt engine's warmup
-    books ZERO new compiles in the kernel ledger.  ``extra`` extends the key
-    for shape-specialized graphs (the fused aggregate's ``(n, padded)``).
+    books ZERO new compiles in the kernel ledger.
     Hits/misses book into :data:`consensus_tpu.obs.kernels.COMPILE_CACHE`;
     ``memo=False`` (``CompileCacheConfig.enabled=False``) always builds
     fresh and books a miss.
@@ -158,7 +133,7 @@ def compiled_kernel(
     if not memo:
         COMPILE_CACHE.record(hit=False)
         return builder()
-    key = _kernel_key(name, mesh, extra)
+    key = _kernel_key(name, mesh)
     fn = _COMPILED_KERNELS.get(key)
     if fn is None:
         COMPILE_CACHE.record(hit=False)
@@ -255,19 +230,13 @@ def sharded_verify_fn(mesh: Mesh):
     axes = _reduce_axes(mesh)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_mesh_specs(mesh, _IN_SPECS),
         out_specs=_mesh_specs(mesh, (P(BATCH_AXIS), P())),
     )
     def _shard(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
-        from consensus_tpu.models.ed25519 import suppress_pallas_scan
-
-        # pallas_call-under-shard_map is unvalidated (and per-shard batch
-        # sizes would change the tiling decision): the multi-chip path
-        # always traces the XLA scan, opt-in flag or not.
-        with suppress_pallas_scan():
-            ok = verify_impl(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)
+        ok = verify_impl(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)
         total = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), axes)
         return ok, total
 
@@ -344,17 +313,13 @@ def sharded_p256_verify_fn(mesh: Mesh):
     axes = _reduce_axes(mesh)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_mesh_specs(mesh, _P256_IN_SPECS),
         out_specs=_mesh_specs(mesh, (P(BATCH_AXIS), P())),
     )
     def _shard(qx, qy, u1d, u2d, r1, r2, has_r2, host_ok):
-        from consensus_tpu.ops.pallas_scan import suppress_pallas_scan
-
-        # Same rule as the Ed25519 shard: no pallas_call under shard_map.
-        with suppress_pallas_scan():
-            ok = p256_verify_impl(qx, qy, u1d, u2d, r1, r2, has_r2, host_ok)
+        ok = p256_verify_impl(qx, qy, u1d, u2d, r1, r2, has_r2, host_ok)
         total = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), axes)
         return ok, total
 
@@ -432,19 +397,15 @@ def sharded_batch_verify_fn(mesh: Mesh):
     axes = _reduce_axes(mesh)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_mesh_specs(mesh, _RAND_IN_SPECS),
         out_specs=_mesh_specs(mesh, (P(), P(BATCH_AXIS))),
     )
     def _shard(y_r, sign_r, y_a, sign_a, zs_digits8, zk_digits, z_digits, host_ok):
-        from consensus_tpu.models.ed25519 import suppress_pallas_scan
-
-        # Same rule as the strict shard: no pallas_call under shard_map.
-        with suppress_pallas_scan():
-            eq_ok, valid = batch_verify_impl(
-                y_r, sign_r, y_a, sign_a, zs_digits8, zk_digits, z_digits, host_ok
-            )
+        eq_ok, valid = batch_verify_impl(
+            y_r, sign_r, y_a, sign_a, zs_digits8, zk_digits, z_digits, host_ok
+        )
         bad = jax.lax.psum(1 - eq_ok.astype(jnp.int32), axes)
         return bad == 0, valid
 
@@ -567,17 +528,13 @@ def sharded_fused_verify_fn(mesh: Mesh):
     axes = _reduce_axes(mesh)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_mesh_specs(mesh, _FUSED_IN_SPECS),
         out_specs=_mesh_specs(mesh, (P(BATCH_AXIS), P())),
     )
     def _shard(sig_rows, key_rows, blocks, n_blocks, host_ok):
-        from consensus_tpu.models.ed25519 import suppress_pallas_scan
-
-        # Same rule as the host-prep shards: no pallas_call under shard_map.
-        with suppress_pallas_scan():
-            ok = fused_verify_impl(sig_rows, key_rows, blocks, n_blocks, host_ok)
+        ok = fused_verify_impl(sig_rows, key_rows, blocks, n_blocks, host_ok)
         total = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), axes)
         return ok, total
 
@@ -636,235 +593,6 @@ class ShardedFusedEd25519Verifier(_MeshEngine, FusedEd25519BatchVerifier):
         return np.asarray(ok)[:n]
 
 
-#: Specs for the sharded fused aggregate: byte rows and block arrays shard
-#: on the trailing batch axis; the transcript's cross-shard edge (every
-#: shard needs every lane's leaf digest to assemble the root) is an
-#: all_gather INSIDE the shard body, not an input spec.
-_FUSED_AGG_IN_SPECS = (
-    P(None, BATCH_AXIS),              # r_rows
-    P(None, BATCH_AXIS),              # s_rows
-    P(None, BATCH_AXIS),              # key_rows
-    P(None, None, None, BATCH_AXIS),  # k_blocks
-    P(BATCH_AXIS),                    # k_nblocks
-    P(None, None, None, BATCH_AXIS),  # leaf_blocks
-    P(BATCH_AXIS),                    # leaf_nblocks
-    P(BATCH_AXIS),                    # host_ok
-)
-
-
-def sharded_fused_aggregate_fn(mesh: Mesh, tag: bytes, n: int, padded: int):
-    """jitted fused randomized-aggregate check over ``mesh``.
-
-    Device Fiat–Shamir with one collective: each shard hashes its own
-    lanes' transcript leaves, an ``all_gather`` assembles the full leaf
-    digest table on every shard, and each shard then derives the IDENTICAL
-    root and its own lanes' coefficients ``zᵢ = H(root ‖ i)`` — the same
-    transcript bytes as the host twin, so coefficients match bit-for-bit.
-    (On an N-D topology the gather runs over the full axis tuple in
-    row-major order — the same global lane order the input sharding uses,
-    so the assembled table is identical to the 1-D mesh's.)
-    As in :func:`sharded_batch_verify_fn`, every shard checks an
-    independent aggregate over its lane subset with its own base scalar
-    ``u_s = Σ zᵢsᵢ`` (pad lanes carry s = 0 and masked digits, so a
-    padding-only shard votes ok), and one psum tree-reduces the verdict.
-    Specialized per (n, padded) like the single-device aggregate graphs —
-    stats accumulate under one kernel-accounting name."""
-    from consensus_tpu.models.ed25519 import (
-        _WINDOWS,
-        _Z_WINDOWS,
-        batch_verify_impl,
-    )
-    from consensus_tpu.models.fused import _aggregate_constants
-    from consensus_tpu.ops import scalar25519 as sc
-    from consensus_tpu.ops import sha512 as sh
-
-    n_shards = mesh.devices.size
-    if padded % n_shards:
-        raise ValueError("padded batch must be a multiple of the mesh size")
-    per = padded // n_shards
-    axes = _reduce_axes(mesh)
-    (
-        root_prefix, root_trailer, root_blocks, z_trailer, idx_rows
-    ) = _aggregate_constants(tag, n, padded)
-    one_z = np.zeros((16, 1), dtype=np.int32)
-    one_z[0, 0] = 1
-
-    @partial(
-        _shard_map,
-        mesh=mesh,
-        in_specs=_mesh_specs(mesh, _FUSED_AGG_IN_SPECS),
-        out_specs=_mesh_specs(mesh, (P(), P(BATCH_AXIS))),
-    )
-    def _shard(
-        r_rows, s_rows, key_rows, k_blocks, k_nblocks,
-        leaf_blocks, leaf_nblocks, host_ok,
-    ):
-        from consensus_tpu.models.ed25519 import suppress_pallas_scan
-
-        shard = _shard_index(mesh)
-        r = r_rows.astype(jnp.int32)
-        key = key_rows.astype(jnp.int32)
-        with suppress_pallas_scan():
-            k_digest = sh.digest_bytes(sh.sha512_blocks(k_blocks, k_nblocks))
-            k_bytes = sc.reduce_bytes_mod_l(k_digest)
-
-            leaves = sh.digest_bytes(
-                sh.sha512_blocks(leaf_blocks, leaf_nblocks)
-            )  # (64, per)
-            gathered = jax.lax.all_gather(
-                leaves, axes, axis=1, tiled=True
-            )  # (64, padded), global lane order
-            root_rows = jnp.concatenate(
-                [
-                    jnp.asarray(root_prefix, jnp.int32),
-                    gathered[:, :n].T.reshape(64 * n, 1),
-                    jnp.asarray(root_trailer, jnp.int32),
-                ],
-                axis=0,
-            )
-            root = sh.digest_bytes(
-                sh.sha512_blocks(
-                    sh.pack_bytes_device(root_rows),
-                    jnp.full((1,), root_blocks, jnp.int32),
-                )
-            )
-
-            local_idx = jax.lax.dynamic_slice_in_dim(
-                jnp.asarray(idx_rows, jnp.int32), shard * per, per, axis=1
-            )
-            z_rows = jnp.concatenate(
-                [
-                    jnp.broadcast_to(root, (64, per)),
-                    local_idx,
-                    jnp.asarray(z_trailer[:, :per], jnp.int32),
-                ],
-                axis=0,
-            )
-            z_digest = sh.digest_bytes(
-                sh.sha512_blocks(
-                    sh.pack_bytes_device(z_rows), jnp.ones((per,), jnp.int32)
-                )
-            )
-            z = z_digest[:16]
-            z = jnp.where((z == 0).all(axis=0)[None], jnp.asarray(one_z), z)
-
-            zk = sc.mul_mod_l(z, k_bytes)
-            zk_digits = sc.signed_window_digits(zk, _WINDOWS)
-            z_digits = sc.signed_window_digits(z, _Z_WINDOWS)
-            u = sc.sum_mod_l(sc.mul_mod_l(z, s_rows.astype(jnp.int32)))
-
-            y_r = jnp.concatenate([r[:31], (r[31] & 0x7F)[None]], axis=0)
-            y_a = jnp.concatenate([key[:31], (key[31] & 0x7F)[None]], axis=0)
-            eq_ok, valid = batch_verify_impl(
-                y_r, r[31] >> 7, y_a, key[31] >> 7, u, zk_digits, z_digits,
-                host_ok,
-            )
-        bad = jax.lax.psum(1 - eq_ok.astype(jnp.int32), axes)
-        return bad == 0, valid
-
-    return instrumented_jit(
-        _shard, "ed25519.sharded_fused_batch_verify" + kernel_lane_suffix()
-    )
-
-
-class ShardedFusedEd25519RandomizedVerifier(
-    FusedEd25519RandomizedBatchVerifier, ShardedFusedEd25519Verifier
-):
-    """Randomized fused verifier whose aggregate check (and strict floor)
-    ride the mesh.  The bisection driver, host fallback, and canonical
-    pre-filter are inherited from the single-device fused engine; only the
-    two launch seams are re-routed."""
-
-    def __init__(
-        self,
-        mesh: Union[Mesh, MeshTopology, None] = None,
-        *,
-        compile_cache: bool = True,
-        **kw,
-    ) -> None:
-        # The randomized base consumes min_randomized before the strict
-        # chain; with the diamond MRO here the strict chain would skip it,
-        # so pop + set it explicitly (same clamp as the base).
-        min_randomized = kw.pop("min_randomized", 2)
-        ShardedFusedEd25519Verifier.__init__(
-            self, mesh, compile_cache=compile_cache, **kw
-        )
-        self._min_randomized = max(2, int(min_randomized))
-        self._agg_fns: dict = {}
-
-    def _strict_floor(self, messages, signatures, public_keys) -> np.ndarray:
-        return ShardedFusedEd25519Verifier.verify_batch(
-            self, messages, signatures, public_keys
-        )
-
-    def _fused_aggregate(self, idx, messages, signatures, public_keys):
-        from consensus_tpu.models.ed25519 import _Z_TAG
-        from consensus_tpu.models.fused import (
-            _byte_rows,
-            _frame,
-            _pack_blocks,
-            _pad_wave,
-        )
-
-        m = len(idx)
-        rs = [bytes(signatures[i])[:32] for i in idx]
-        keys = [bytes(public_keys[i]) for i in idx]
-        msgs = [bytes(messages[i]) for i in idx]
-        r_rows = _byte_rows(rs, 32)
-        key_rows = _byte_rows(keys, 32)
-        s_rows = _byte_rows([bytes(signatures[i])[32:] for i in idx], 32)
-        k_blocks, k_nblocks = _pack_blocks(
-            [r + a + mm for r, a, mm in zip(rs, keys, msgs)]
-        )
-        leaf_blocks, leaf_nblocks = _pack_blocks(
-            [
-                _frame(mm) + _frame(bytes(signatures[i])) + _frame(a)
-                for mm, i, a in zip(msgs, idx, keys)
-            ]
-        )
-        host_ok = np.ones(m, dtype=bool)
-
-        padded = engine_padded_size(
-            m, self._n_shards, pad_to=self._pad_to, pad_pow2=self._pad_pow2
-        )
-        r_rows, s_rows, key_rows, k_nblocks, leaf_nblocks, host_ok = _pad_wave(
-            [r_rows, s_rows, key_rows, k_nblocks, leaf_nblocks, host_ok],
-            m, padded,
-        )
-        if padded != m:
-            batch_pad = ((0, 0),) * 3 + ((0, padded - m),)
-            k_blocks = np.pad(k_blocks, batch_pad)
-            leaf_blocks = np.pad(leaf_blocks, batch_pad)
-
-        # Instance memo first (the historical per-engine shape cache), then
-        # the process-wide memo so a REBUILT engine reuses the traced graph.
-        fn = self._agg_fns.get((m, padded))
-        if fn is None:
-            fn = self._agg_fns[(m, padded)] = compiled_kernel(
-                "ed25519.sharded_fused_batch_verify",
-                self.mesh,
-                lambda: sharded_fused_aggregate_fn(self.mesh, _Z_TAG, m, padded),
-                memo=self._compile_cache,
-                extra=(_Z_TAG, m, padded),
-            )
-        device_args = (
-            np.ascontiguousarray(r_rows.T),
-            np.ascontiguousarray(s_rows.T),
-            np.ascontiguousarray(key_rows.T),
-            k_blocks,
-            k_nblocks,
-            leaf_blocks,
-            leaf_nblocks,
-            host_ok,
-        )
-        args = [
-            jax.device_put(np.asarray(a), NamedSharding(self.mesh, spec))
-            for a, spec in zip(device_args, _mesh_specs(self.mesh, _FUSED_AGG_IN_SPECS))
-        ]
-        eq_ok, valid = fn(*args)
-        return bool(np.asarray(eq_ok)), list(np.asarray(valid)[:m])
-
-
 __all__ = [
     "make_mesh",
     "mesh_for_shards",
@@ -874,12 +602,10 @@ __all__ = [
     "sharded_batch_verify_fn",
     "sharded_p256_verify_fn",
     "sharded_fused_verify_fn",
-    "sharded_fused_aggregate_fn",
     "ShardedEd25519Verifier",
     "ShardedEd25519RandomizedVerifier",
     "ShardedEcdsaP256Verifier",
     "ShardedFusedEd25519Verifier",
-    "ShardedFusedEd25519RandomizedVerifier",
     "MeshTopology",
     "mesh_padded_size",
     "engine_padded_size",

@@ -25,6 +25,7 @@ the accelerator stack.
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 from typing import Optional, Sequence, Union
 
@@ -203,27 +204,43 @@ def topology_for_config(config) -> MeshTopology:
     return MeshTopology.normalize(int(getattr(config, "mesh_shards", 1) or 1))
 
 
-def apply_compile_cache(cache) -> None:
-    """Wire a ``CompileCacheConfig``'s persistent-cache knobs into
-    ``jax.config`` (idempotent; repeated calls with the same values are
-    no-ops inside jax).  ``persistent_dir=""`` leaves the runtime default
-    untouched — the in-process memo works either way."""
-    if cache is None or not getattr(cache, "persistent_dir", ""):
-        return
+#: Where the persistent XLA cache lives when the environment names no
+#: place: a FIXED path inside the checkout (the path is part of the cache
+#: key, so a directory that moves — temp name, pid, timestamp — never hits).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def apply_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache — the ONE place this
+    program decides where compiled kernels are kept, called by every entry
+    point that compiles (the rig sidecar, tests/conftest.py,
+    ``__graft_entry__``, ``bench.py``, ``benchmarks/*``, ``chip_smoke.py``'s
+    lane children).
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set jax reads it by
+    itself and this function sets NO directory, so an operator (or the
+    machine the program runs on) places the cache from outside.  Otherwise
+    the cache goes to :data:`DEFAULT_COMPILE_CACHE_DIR`.  Returns the
+    directory in effect.  Idempotent."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache.persistent_dir)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs",
-        float(getattr(cache, "min_compile_time_secs", 1.0)),
-    )
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     # Cache every entry regardless of serialized size: correctness work like
     # this repo's is dominated by many small-but-slow-to-trace kernels.
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 __all__ = [
     "BATCH_AXIS",
+    "DEFAULT_COMPILE_CACHE_DIR",
     "MeshTopology",
     "apply_compile_cache",
     "engine_padded_size",

@@ -21,6 +21,7 @@ everything before the signature with the client's key.
 
 from __future__ import annotations
 
+import hashlib
 import struct
 from typing import Mapping, Optional, Sequence
 
@@ -107,6 +108,16 @@ class ClientKeyring:
     def make_request(self, client_idx: int, seq: int, body: bytes = b"x" * 64) -> bytes:
         head = struct.pack(">IQ", client_idx, seq) + body
         return head + self.signers[client_idx].sign_raw(_REQ_TAG + head)
+
+
+def request_ids_digest(raw_requests) -> str:
+    """Order-free digest of a request set's DISTINCT ``(client_idx, seq)``
+    identities (the 12-byte head of the wire format) — two parties hold the
+    same set of requests iff their digests agree."""
+    h = hashlib.sha256()
+    for head in sorted({bytes(raw[:12]) for raw in raw_requests}):
+        h.update(head)
+    return h.hexdigest()
 
 
 class SignedRequestApp(CryptoApp):
@@ -242,4 +253,10 @@ class SignedRequestApp(CryptoApp):
         return infos, cert_results
 
 
-__all__ = ["CryptoApp", "SigOnlyVerifier", "SignedRequestApp", "ClientKeyring"]
+__all__ = [
+    "CryptoApp",
+    "SigOnlyVerifier",
+    "SignedRequestApp",
+    "ClientKeyring",
+    "request_ids_digest",
+]

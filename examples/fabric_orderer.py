@@ -5,7 +5,7 @@ Fabric implements the ~10 dependency ports around ``pkg/consensus`` —
 envelopes in, hash-chained blocks out, per-consenter block signatures
 (reference pkg/api/dependencies.go:14-99; README.md names Fabric as the
 consumer).  A REAL Fabric integration is out of scope in this environment
-(no Fabric tree, no Go toolchain — see BASELINE.md config-5 note); this
+(no Fabric tree, no Go toolchain); this
 example is the Fabric-SHAPED embedding: every port implemented the way the
 orderer implements it, against this framework's API, so an embedder can
 see the whole integration surface in ~200 lines.

@@ -55,11 +55,10 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
-def start_driver(spec, seconds: float, seed: int, rate: float):
-    """The ingress plane as its own OS process (PR-12 driver)."""
-    env = os.environ.copy()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+def start_driver(launcher, seconds: float, seed: int, rate: float):
+    """The ingress plane as its own OS process (PR-12 driver), pinned to
+    the CPU like every rig child but the sidecar."""
+    spec = launcher.spec
     return subprocess.Popen(
         [
             sys.executable, "-m", "consensus_tpu.deploy.driver_main",
@@ -70,7 +69,7 @@ def start_driver(spec, seconds: float, seed: int, rate: float):
         ],
         stdout=subprocess.PIPE,
         stderr=subprocess.DEVNULL,
-        env=env,
+        env=launcher.cpu_env,
         text=True,
     )
 
@@ -92,6 +91,11 @@ def main(argv=None) -> int:
             "view_change_resend_interval": 1.0,
             "leader_heartbeat_timeout": 3.0,
             "leader_heartbeat_count": 10,
+            # A process-chaos soak, not a device run: its 20-request
+            # proposals never reach the sidecars' device threshold anyway,
+            # so say so — host-only sidecars open no backend, and a fleet of
+            # them fits a host with one chip or none.
+            "crypto_tpu_min_batch": 10**9,
         },
     )
     launcher = ClusterLauncher(spec, backoff_initial=1.0)
@@ -133,7 +137,7 @@ def main(argv=None) -> int:
                             pass
                 driver_seed += 1
                 driver = start_driver(
-                    spec,
+                    launcher,
                     seconds=max(args.period * 3, 30.0),
                     seed=driver_seed,
                     rate=args.driver_rate,

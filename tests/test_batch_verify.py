@@ -13,9 +13,9 @@ clustered, all, bisection boundaries) and assert that parity.
 Also covered: the deps.py multi-batch coalescing seam (one engine launch
 for many quorum groups when batch_verify_mode is on), the chaos-engine
 crypto parity gate (strict vs randomized engines on the SAME schedule must
-produce identical ledgers), the field-op counting shim that produced the
-BASELINE.md amortization numbers, and bench.py's structured skip path for
-the new batch-verify column.
+produce identical ledgers), the field-op counting shim behind the
+amortization counts in PERF.md, and bench.py's refusal to report a device
+family without a TPU.
 """
 
 import hashlib
@@ -248,11 +248,11 @@ def test_counting_shim_weighs_lanes_and_scan_trips():
 
 @pytest.mark.slow
 def test_amortized_field_muls_at_512_below_half_of_strict():
-    """THE acceptance measurement (BASELINE.md records the numbers): at
+    """THE acceptance count (PERF.md §5 records the numbers): at
     batch 512 the randomized aggregate path costs <= 50% of the strict
     kernel's field multiplications per signature.  Abstract tracing only
     (jax.eval_shape) — but tracing two batch-512 graphs still takes
-    minutes, hence the slow marker; the committed BASELINE.md table is the
+    minutes, hence the slow marker; the committed PERF.md table is the
     tier-1-visible artifact of this claim."""
     import jax
     import jax.numpy as jnp
@@ -496,24 +496,22 @@ def test_chaos_byzantine_mutation_parity_strict_vs_batch():
     assert max(len(d) for d in strict.ledgers.values()) >= 1
 
 
-# --- bench.py structured skip path ------------------------------------------
+# --- bench.py: no exit 0 without a chip ---------------------------------------
 
 
-def test_bench_skip_record_carries_batch_verify_column():
-    """With the device unreachable (JAX_PLATFORMS=tpu on a TPU-less host,
-    zero retry window) bench.py must exit 0 and emit the machine-readable
-    skip record INCLUDING the batch_verify column's own skip + trail."""
-    env = dict(os.environ, JAX_PLATFORMS="tpu", CTPU_BENCH_RETRY_WINDOW="0")
+@pytest.mark.parametrize("family", [[], ["p256"], ["mxu_limbs"]])
+def test_bench_device_family_without_tpu_fails_and_replays_nothing(family):
+    """A device family on a TPU-less host must exit non-zero and print no
+    record at all — in particular no replayed ``last_good`` number."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
-        [sys.executable, "bench.py"],
+        [sys.executable, "bench.py", *family],
         cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
     )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    record = json.loads(line)
-    assert record["metric"] == "ed25519_verify_throughput"
-    assert record["skipped"] == "device-unavailable"
-    assert record["batch_verify"]["skipped"] == "device-unavailable"
+    assert proc.returncode != 0, proc.stdout + proc.stderr
+    assert "need a TPU" in proc.stderr
+    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
+    assert "last_good" not in proc.stdout + proc.stderr
 
 
 def test_wallclock_lint_covers_batch_verify_modules():

@@ -384,3 +384,145 @@ def test_chaos_schedule_skips_sidecar_verb_without_fleet():
     for _ in range(6):
         sched.step()
     assert all(r["action"] != "kill9_sidecar" for r in sched.history)
+
+
+# ------------------------------------------------- launcher: one chip owner
+
+
+#: A stand-in rig child: answers ping/health/exit on the given control port
+#: and reports the JAX platform pin it was started with.
+_ENV_CHILD = """
+import os, sys, threading
+from consensus_tpu.deploy.control import ControlServer
+done = threading.Event()
+ControlServer(
+    {"ping": lambda r: {"ok": True},
+     "health": lambda r: {"ok": True,
+                          "jax_platforms": os.environ.get("JAX_PLATFORMS")},
+     "exit": lambda r: (done.set(), {"ok": True})[1]},
+    port=int(sys.argv[1]),
+)
+done.wait()
+"""
+
+
+def test_launcher_pins_everything_but_the_sidecar_to_the_cpu(
+    tmp_path, monkeypatch
+):
+    """Replica (and driver) children carry JAX_PLATFORMS=cpu; the sidecar's
+    platform is left to the environment — it is the one process of a rig
+    that may hold the chip."""
+    from consensus_tpu.deploy import ClusterLauncher
+
+    monkeypatch.setenv("JAX_PLATFORMS", "as-the-operator-set-it")
+    spec = ClusterSpec.generate(2, 1, str(tmp_path))
+    launcher = ClusterLauncher(spec, restart=False)
+    monkeypatch.setattr(
+        launcher, "_replica_argv",
+        lambda node_id: [sys.executable, "-c", _ENV_CHILD,
+                         str(spec.replica(node_id).control_port)],
+    )
+    monkeypatch.setattr(
+        launcher, "_sidecar_argv",
+        lambda sid: [sys.executable, "-c", _ENV_CHILD,
+                     str(spec.sidecar(sid).control_port)],
+    )
+    try:
+        launcher.start(timeout=60)
+        health = launcher.health()
+        assert health["sc-0"]["jax_platforms"] == "as-the-operator-set-it"
+        assert health["replica-1"]["jax_platforms"] == "cpu"
+        assert health["replica-2"]["jax_platforms"] == "cpu"
+        assert launcher.cpu_env["JAX_PLATFORMS"] == "cpu"  # the driver's env
+    finally:
+        launcher.stop()
+
+
+def test_sidecar_that_exits_at_boot_fails_start_without_restart(
+    tmp_path, monkeypatch
+):
+    """A sidecar that cannot get its device exits non-zero with one line;
+    ``start`` fails on it at once — no restart loop behind replicas that
+    would verify on the host, and no replica is ever spawned."""
+    from consensus_tpu.deploy import ClusterLauncher
+
+    spec = ClusterSpec.generate(2, 1, str(tmp_path))
+    launcher = ClusterLauncher(spec, backoff_initial=0.05)
+    monkeypatch.setattr(
+        launcher, "_sidecar_argv",
+        lambda sid: [sys.executable, "-c",
+                     "import sys; print('sc-0: no TPU for this process', "
+                     "file=sys.stderr); sys.exit(3)"],
+    )
+    started = time.monotonic()
+    try:
+        with pytest.raises(RuntimeError, match="exited with code 3 at boot"):
+            launcher.start(timeout=60)
+        assert time.monotonic() - started < 30.0
+        time.sleep(0.5)  # a restart, were one armed, would have fired by now
+        sup = launcher.sidecars["sc-0"]
+        assert sup.restarts == 0 and not sup.alive
+        assert "no TPU" in sup.boot_failure()
+        assert launcher.replicas == {}
+    finally:
+        launcher.stop()
+
+
+def test_device_sidecar_refuses_to_serve_without_a_tpu(tmp_path):
+    """The real sidecar main, platform NOT pinned to the CPU, on a host
+    where jax finds no TPU: one clear line, exit code EXIT_NO_DEVICE —
+    never a silent host-serving sidecar.  (A host-only spec opens no
+    backend at all and is unaffected.)"""
+    import subprocess
+
+    from consensus_tpu.deploy.sidecar_main import EXIT_NO_DEVICE
+
+    spec = ClusterSpec.generate(
+        4, 1, str(tmp_path), config_overrides={"request_batch_max_count": 64}
+    )
+    spec.write()
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-m", "consensus_tpu.deploy.sidecar_main",
+         "--config", spec.config_path, "--sidecar-id", "sc-0"],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    if "platform='tpu'" in proc.stderr or proc.returncode == 0:
+        pytest.skip("this host has a TPU: the refusal path cannot be shown")
+    assert proc.returncode == EXIT_NO_DEVICE, proc.stderr[-2000:]
+    last = proc.stderr.strip().splitlines()[-1]
+    assert "no TPU for this process" in last and "refusing" in last
+    assert proc.stdout.strip() == ""  # never printed ready
+
+
+def test_orchestrator_side_opens_no_jax_backend(tmp_path):
+    """What chip_smoke.py, scripts/soak.py and ``bench.py deploy`` do in
+    their own process — import the rig, mint a spec, build a launcher, sign
+    requests, verify on the host — initialises no jax backend: the chip
+    stays free for the sidecar."""
+    import subprocess
+
+    code = """
+import sys, tempfile
+sys.path.insert(0, %r)
+import bench, chip_smoke
+import scripts.soak
+from consensus_tpu.deploy import ClusterLauncher, ClusterSpec
+from consensus_tpu.deploy.identity import make_client_keyring
+from consensus_tpu.models import Ed25519BatchVerifier
+spec = ClusterSpec.generate(4, 1, tempfile.mkdtemp(dir=%r))
+ClusterLauncher(spec)
+ring = make_client_keyring(spec.key_namespace, 2)
+ring.make_request(0, 1)
+wave, planted = chip_smoke._ed25519_wave(16, 3)
+assert not Ed25519BatchVerifier().verify_host(*wave).all()
+print(chip_smoke._parent_backends())
+""" % (os.path.dirname(os.path.dirname(os.path.abspath(__file__))), str(tmp_path))
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    proc = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "[]"

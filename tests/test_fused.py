@@ -1,19 +1,19 @@
-"""Fused bytes-in → verdict-out engines (``Configuration.device_prep``).
+"""Fused bytes-in → verdict-out strict engine (``Configuration.device_prep``).
 
 Parity contract under test (SAFETY.md §10): with device_prep on, every
-accept/reject verdict is bit-identical to the host-prep engines — across
-forged/tampered lanes, ``S ≥ L``, non-canonical/non-decodable encodings,
-wrong keys, and malformed lengths — and the randomized Fiat–Shamir
-transcript produces the exact same coefficients, so bisection takes the
-same paths.  Plus the launch-count gate: one fused kernel launch per wave
-for the strict, randomized-batch, and half-agg paths.
+accept/reject verdict is bit-identical to the host-prep strict engine —
+across forged/tampered lanes, ``S ≥ L``, non-canonical/non-decodable
+encodings, wrong keys, and malformed lengths.  Plus the launch-count gate:
+one fused kernel launch per wave.  The fused randomized and half-agg lanes
+were removed in PR 22 (one compile per live wave size); the registry
+refuses the combination and that refusal is pinned here.
 
 Shape discipline: every device test pins one compiled-shape set (n = 8
 lanes, pad_to = 8, ~100-byte messages → a 2-block SHA ladder) so the
-whole file compiles a handful of graphs once — warmed by the repo-local
-persistent compile cache thereafter.  End-to-end engine tests are marked
-slow (XLA CPU compiles the big fused graphs in minutes cold); the eager
-transcript/pre-check parity tests stay tier-1.
+whole file compiles a handful of graphs once — warmed by the persistent
+compile cache thereafter.  End-to-end engine tests are marked slow (XLA CPU
+compiles the big fused graph in minutes cold); the pre-check parity and
+routing tests stay tier-1.
 """
 
 import numpy as np
@@ -24,8 +24,6 @@ jnp = jax.numpy
 
 from consensus_tpu.models.aggregate import HalfAggregator  # noqa: E402
 from consensus_tpu.models.ed25519 import (  # noqa: E402
-    _transcript_coefficients,
-    _Z_TAG,
     Ed25519BatchVerifier,
     Ed25519RandomizedBatchVerifier,
     L,
@@ -34,11 +32,9 @@ from consensus_tpu.models.ed25519 import (  # noqa: E402
 )
 from consensus_tpu.models.fused import (  # noqa: E402
     FusedEd25519BatchVerifier,
-    FusedEd25519RandomizedBatchVerifier,
     canonical_ok_fast,
 )
 from consensus_tpu.ops import field25519 as fe  # noqa: E402
-from consensus_tpu.ops import sha512 as sh  # noqa: E402
 
 
 def _batch(n, seed=0, msg_len=100):
@@ -85,7 +81,7 @@ def _adversarial_waves():
     ]
 
 
-# --- tier-1: host pre-checks + device transcript parity (eager, cheap) ------
+# --- tier-1: host pre-checks + routing (cheap) -------------------------------
 
 
 def test_canonical_ok_fast_matches_loop_twin():
@@ -95,82 +91,9 @@ def test_canonical_ok_fast_matches_loop_twin():
         assert list(fast) == list(loop)
 
 
-def test_device_transcript_matches_host_coefficients():
-    """The on-device Fiat–Shamir chain (leaf hashes → root assembled from
-    device-resident digests → zᵢ = H(root‖i)[:16]) must reproduce
-    ``_transcript_coefficients`` byte-for-byte — run eagerly so the parity
-    pin costs no big jit compile."""
-    from consensus_tpu.models.fused import (
-        _aggregate_constants,
-        _byte_rows,
-        _frame,
-        _pack_blocks,
-    )
-
-    msgs, sigs, keys = _batch(5, seed=3, msg_len=40)
-    n = 5
-    (
-        root_prefix, root_trailer, root_blocks, z_trailer, idx_rows
-    ) = _aggregate_constants(_Z_TAG, n, n)
-    leaf_blocks, leaf_nblocks = _pack_blocks(
-        [
-            _frame(m) + _frame(s) + _frame(a)
-            for m, s, a in zip(msgs, sigs, keys)
-        ]
-    )
-    leaves = sh.digest_bytes(
-        sh.sha512_blocks(jnp.asarray(leaf_blocks), jnp.asarray(leaf_nblocks))
-    )
-    root_rows = jnp.concatenate(
-        [
-            jnp.asarray(root_prefix, jnp.int32),
-            leaves[:, :n].T.reshape(64 * n, 1),
-            jnp.asarray(root_trailer, jnp.int32),
-        ],
-        axis=0,
-    )
-    root = sh.digest_bytes(
-        sh.sha512_blocks(
-            sh.pack_bytes_device(root_rows),
-            jnp.full((1,), root_blocks, jnp.int32),
-        )
-    )
-    z_rows = jnp.concatenate(
-        [
-            jnp.broadcast_to(root, (64, n)),
-            jnp.asarray(idx_rows, jnp.int32),
-            jnp.asarray(z_trailer, jnp.int32),
-        ],
-        axis=0,
-    )
-    z_digest = np.asarray(
-        sh.digest_bytes(
-            sh.sha512_blocks(
-                sh.pack_bytes_device(z_rows), jnp.ones((n,), jnp.int32)
-            )
-        )
-    )
-    got = [
-        int.from_bytes(bytes(z_digest[:16, i].astype(np.uint8)), "little") or 1
-        for i in range(n)
-    ]
-    assert got == _transcript_coefficients(msgs, sigs, keys)
-    # And the leaf stage alone matches hashlib (framing included).
-    import hashlib
-
-    leaf0 = bytes(np.asarray(leaves)[:, 0].astype(np.uint8))
-    assert leaf0 == hashlib.sha512(
-        _frame(msgs[0]) + _frame(sigs[0]) + _frame(keys[0])
-    ).digest()
-    assert _byte_rows([b"\x01\x02"], 2).tolist() == [[1, 2]]
-
-
 def test_engine_for_config_device_prep_routing():
     from consensus_tpu.models.verifier import engine_for_config
-    from consensus_tpu.parallel import (
-        ShardedFusedEd25519RandomizedVerifier,
-        ShardedFusedEd25519Verifier,
-    )
+    from consensus_tpu.parallel import ShardedFusedEd25519Verifier
 
     class Cfg:
         crypto_pad_pow2 = True
@@ -179,33 +102,41 @@ def test_engine_for_config_device_prep_routing():
         device_prep = True
         mesh_shards = 1
 
-    assert isinstance(engine_for_config(Cfg()), FusedEd25519BatchVerifier)
-    Cfg.batch_verify_mode = True
     eng = engine_for_config(Cfg())
-    assert isinstance(eng, FusedEd25519RandomizedBatchVerifier)
+    assert isinstance(eng, FusedEd25519BatchVerifier)
     assert eng._min_device_batch == 4
     Cfg.mesh_shards = 2
-    assert isinstance(engine_for_config(Cfg()), ShardedFusedEd25519RandomizedVerifier)
-    Cfg.batch_verify_mode = False
     assert isinstance(engine_for_config(Cfg()), ShardedFusedEd25519Verifier)
     with pytest.raises(ValueError, match="Ed25519-only"):
         engine_for_config(Cfg(), curve="p256")
+    # device_prep x randomized was removed (one compile per live wave size):
+    # the registry refuses it on either topology, with the reason.
+    Cfg.batch_verify_mode = True
+    for shards in (1, 2):
+        Cfg.mesh_shards = shards
+        with pytest.raises(ValueError, match="device_prep is strict-only"):
+            engine_for_config(Cfg())
     # device_prep off: bit-for-bit the previous engine classes.
     Cfg.device_prep = False
     Cfg.mesh_shards = 1
-    eng = engine_for_config(Cfg())
-    assert type(eng) is Ed25519BatchVerifier
-    Cfg.batch_verify_mode = True
     assert type(engine_for_config(Cfg())) is Ed25519RandomizedBatchVerifier
+    Cfg.batch_verify_mode = False
+    assert type(engine_for_config(Cfg())) is Ed25519BatchVerifier
 
 
-def test_halfagg_inherits_device_prep_from_engine():
-    fused_engine = FusedEd25519BatchVerifier(min_device_batch=10**9)
-    legacy_engine = Ed25519BatchVerifier(min_device_batch=10**9)
-    assert HalfAggregator(engine=fused_engine)._device_prep
-    assert not HalfAggregator(engine=legacy_engine)._device_prep
-    assert not HalfAggregator(engine=fused_engine, device_prep=False)._device_prep
-    assert HalfAggregator(engine=legacy_engine, device_prep=True)._device_prep
+def test_halfagg_over_a_fused_engine_keeps_the_host_transcript():
+    """A device_prep deployment's cert verifies inherit the engine's padding
+    and routing knobs, and run the host-derived transcript in front of the
+    device MSM — there is no fused half-agg graph."""
+    fused_engine = FusedEd25519BatchVerifier(min_device_batch=10**9, pad_to=8)
+    agg = HalfAggregator(engine=fused_engine)
+    assert agg._min_device_batch == 10**9 and agg._pad_to == 8
+    msgs, sigs, keys = _batch(4, seed=8)
+    cert, bad = agg.aggregate(msgs, sigs, keys)  # host twin (10**9 floor)
+    assert cert is not None and bad == ()
+    rs, s_agg = cert
+    assert agg.verify(msgs, list(rs), s_agg, keys)
+    assert not agg.verify(msgs, list(rs), _flip(s_agg, 1), keys)
 
 
 def test_config_knob_validates():
@@ -252,82 +183,16 @@ def test_fused_strict_rejection_matrix_bit_identical():
 
 
 @pytest.mark.slow
-def test_fused_randomized_parity_and_single_launch():
-    rkw = dict(min_device_batch=1, pad_to=8, min_randomized=8)
-    legacy = Ed25519RandomizedBatchVerifier(**rkw)
-    fused = FusedEd25519RandomizedBatchVerifier(**rkw)
-
-    msgs, sigs, keys = _batch(8, seed=6)
-    before = _launches()
-    got = fused.verify_batch(msgs, sigs, keys)
-    assert _delta(before, _launches()) == {"ed25519.fused_batch_verify": 1}
-    assert list(got) == list(legacy.verify_batch(msgs, sigs, keys)) == [True] * 8
-
-    # One forged lane: the aggregate fails, bisection halves fall to the
-    # strict floor — identical verdicts lane-for-lane.
-    sigs = list(sigs)
-    sigs[5] = _flip(sigs[5], 3)
-    assert list(fused.verify_batch(msgs, sigs, keys)) == list(
-        legacy.verify_batch(msgs, sigs, keys)
-    )
-
-
-@pytest.mark.slow
-def test_fused_halfagg_parity_and_single_launch():
-    legacy = HalfAggregator(min_device_batch=1, pad_to=8, device_prep=False)
-    fused = HalfAggregator(min_device_batch=1, pad_to=8, device_prep=True)
-    msgs, sigs, keys = _batch(8, seed=8)
-    agg, bad = legacy.aggregate(msgs, sigs, keys)
-    assert agg is not None and bad == ()
-    rs, s_agg = agg
-
-    before = _launches()
-    assert fused.verify(msgs, list(rs), s_agg, keys)
-    assert _delta(before, _launches()) == {"ed25519.fused_halfagg_verify": 1}
-
-    cases = []
-    bad_rs = list(rs)
-    bad_rs[3] = _flip(rs[3], 0)
-    cases.append((msgs, bad_rs, s_agg, keys))
-    bad_msgs = list(msgs)
-    bad_msgs[5] = _flip(msgs[5], 10)
-    cases.append((bad_msgs, list(rs), s_agg, keys))
-    cases.append((msgs, list(rs), _flip(s_agg, 1), keys))
-    bad_keys = list(keys)
-    bad_keys[0] = keys[1]  # lane 0 is the fixed z=1 lane
-    cases.append((msgs, list(rs), s_agg, bad_keys))
-    for m, r, u, k in cases:
-        lv = legacy.verify(m, r, u, k)
-        fv = fused.verify(m, r, u, k)
-        assert (not lv) and (not fv)
-
-
-@pytest.mark.slow
 def test_sharded_fused_parity():
-    from consensus_tpu.parallel import (
-        ShardedFusedEd25519RandomizedVerifier,
-        ShardedFusedEd25519Verifier,
-        mesh_for_shards,
-    )
+    from consensus_tpu.parallel import ShardedFusedEd25519Verifier, mesh_for_shards
 
     mesh = mesh_for_shards(2)
-    waves = _adversarial_waves()
     host = Ed25519BatchVerifier(**_KW)
     shard = ShardedFusedEd25519Verifier(mesh, **_KW)
-    for msgs, sigs, keys in waves:
+    for msgs, sigs, keys in _adversarial_waves():
         assert list(shard.verify_batch(msgs, sigs, keys)) == list(
             host.verify_batch(msgs, sigs, keys)
         )
-
-    rkw = dict(min_device_batch=1, pad_to=8, min_randomized=8)
-    legacy = Ed25519RandomizedBatchVerifier(**rkw)
-    rshard = ShardedFusedEd25519RandomizedVerifier(mesh, **rkw)
-    msgs, sigs, keys = _batch(8, seed=6)
-    sigs = list(sigs)
-    sigs[2] = _flip(sigs[2], 4)
-    assert list(rshard.verify_batch(msgs, sigs, keys)) == list(
-        legacy.verify_batch(msgs, sigs, keys)
-    )
 
 
 @pytest.mark.slow

@@ -244,19 +244,12 @@ def test_2d_topology_strict_parity_on_2x4_host_mesh():
     assert list(np.flatnonzero(~sharded)) == [3, 9]
 
 
-def test_config_validates_mesh_topology_and_compile_cache():
-    from consensus_tpu.config import CompileCacheConfig
-
+def test_config_validates_mesh_topology():
     Configuration(self_id=1, mesh_shards=8, mesh_topology=(2, 4)).validate()
     with pytest.raises(ValueError, match="axes product must equal"):
         Configuration(self_id=1, mesh_shards=4, mesh_topology=(2, 4)).validate()
     with pytest.raises(ValueError, match="axes must all be >= 1"):
         Configuration(self_id=1, mesh_topology=(2, 0)).validate()
-    with pytest.raises(ValueError, match="min_compile_time_secs"):
-        Configuration(
-            self_id=1,
-            compile_cache=CompileCacheConfig(min_compile_time_secs=-1.0),
-        ).validate()
 
 
 # --- engine registry: every advertised key resolves or fails loud ------------
@@ -275,8 +268,8 @@ def test_engine_registry_completeness_and_loud_failures():
         assert key in ENGINE_REGISTRY
         assert callable(ENGINE_REGISTRY.builder(key))
     # Every cell of the advertised matrix — the mxu axis included — is
-    # either registered or refuses with the curve-specific reason (the
-    # Ed25519-only lanes; P-256 × mxu has no MXU Straus/MSM kernel).
+    # either registered or refuses with its named reason (the Ed25519-only
+    # lanes; device_prep × randomized, removed in PR 22).
     for curve in ENGINE_REGISTRY.curves():
         for mode in MODES:
             for topo in TOPOLOGIES:
@@ -287,7 +280,12 @@ def test_engine_registry_completeness_and_loud_failures():
                             continue
                         with pytest.raises(UnknownEngineError) as exc:
                             ENGINE_REGISTRY.builder(key)
-                        assert "Ed25519-only" in str(exc.value)
+                        want = (
+                            "strict-only"
+                            if curve == "ed25519" and prep and mode == "randomized"
+                            else "Ed25519-only"
+                        )
+                        assert want in str(exc.value)
     with pytest.raises(UnknownEngineError, match="unknown curve"):
         ENGINE_REGISTRY.builder(EngineKey(curve="ed448"))
     with pytest.raises(ValueError, match="already registered"):
@@ -298,7 +296,7 @@ def test_engine_registry_completeness_and_loud_failures():
 
 def test_engine_registry_mxu_axis(monkeypatch):
     """The mxu key axis mirrors the CTPU_MXU_LIMBS environment: every
-    ed25519 cell exists under mxu=True but refuses to BUILD unless the
+    registered ed25519 cell exists under mxu=True but refuses to BUILD unless the
     env var actually selects the lane (the traced graph would otherwise be
     VPU under an MXU label), `engine_key_for` derives the axis from the
     env, and the degrade ladder preserves it."""
@@ -325,7 +323,7 @@ def test_engine_registry_mxu_axis(monkeypatch):
 
     # The degrade ladder never silently switches lanes: every rung of an
     # mxu key's ladder keeps mxu=True (and stays registered).
-    fused_mesh = EngineKey("ed25519", "randomized", "mesh", True, True)
+    fused_mesh = EngineKey("ed25519", "strict", "mesh", True, True)
     ladder = ENGINE_REGISTRY.degrade_keys(fused_mesh)
     assert len(ladder) == 3  # mesh -> single, fused -> host prep
     assert all(k.mxu for k in ladder)

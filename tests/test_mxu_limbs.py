@@ -1,9 +1,8 @@
 """Parity gate for the MXU field-arithmetic lane (``CTPU_MXU_LIMBS=1``).
 
 The lane (ISSUE 18) re-expresses limb-product field multiplication as two
-integer ``dot_general`` contractions (ops/mxu_limbs.py) and swaps the XLA
-Straus/MSM scan for a VMEM-resident Pallas kernel (ops/pallas_scan.py).
-Neither rewrite is allowed to move a single bit:
+integer ``dot_general`` contractions (ops/mxu_limbs.py).  The rewrite is
+not allowed to move a single bit:
 
 * ``mul``/``square`` outputs are bit-exact against the VPU lane across the
   full relaxed-limb operand ranges the curve kernels actually feed them;
@@ -11,31 +10,27 @@ Neither rewrite is allowed to move a single bit:
   byte-identical flag-on vs flag-off across every rejection class, on a
   single device AND on the 8-way virtual host mesh (conftest forces
   ``xla_force_host_platform_device_count=8``);
-* the MSM kernel's accumulator equals the XLA scan's as a group element
-  (different projective representatives are expected and fine — verdict
-  checks are scaling-invariant), and a batch that cannot tile fails loud
-  rather than silently falling back to XLA;
 * the counting shim records ``dot_general`` work (dense MACs — the MXU
   does not skip structural zeros) instead of VPU muls, never both, so the
-  BASELINE.md denominators stay honest.
+  counted denominators in PERF.md stay honest.
+
+(The VMEM-resident Straus/MSM Pallas kernel that used to ride this flag was
+removed in PR 22: Mosaic refused its ``(32, 1)`` output block on the chip.
+Flag-on, the randomized MSM is the XLA scan with MXU field contractions.)
 
 Lane selection happens at TRACE time, so every A/B below jits (or traces)
 fresh under an explicit ``force_mxu_limbs``/``suppress_mxu_limbs`` context
 — reusing one jit cache across lanes would silently replay the first
 lane's graph and turn the gate into a tautology.
 
-Mosaic lowering and the speed verdict run on the real device
-(benchmarks/run_device_suite.sh priority 7); interpret mode keeps
-correctness CI-gated on the CPU backend.  Every engine-level A/B
-(single-device strict/randomized, both mesh variants, the direct-MSM
-drive) compiles its full verify graph twice — fresh trace per lane, no
-kernel memo — which on this single-core CI host does not fit the tier-1
-wall-clock budget alongside the pre-existing suite; those gates ride the
-slow lane with the batch-512 pins (``-m slow`` and the device suite run
-them).  Tier-1 keeps the operand-range field parity, the jitted mul
-chain, the anti-tautology distinct-graph pin, lane-selection precedence,
-MSM config selection, the fail-loud tiling check, and the counting
-semantics.
+The chip verdict for the lane is ``chip_smoke.py``'s census (strict and
+randomized under the flag).  Every engine-level A/B here (single-device
+strict/randomized, both mesh variants) compiles its full verify graph twice
+— fresh trace per lane, no kernel memo — which does not fit the tier-1
+wall-clock budget; those gates ride the slow lane with the batch-512 pins.
+Tier-1 keeps the operand-range field parity, the jitted mul chain, the
+anti-tautology distinct-graph pin, lane-selection precedence, and the
+counting semantics.
 """
 
 import numpy as np
@@ -50,7 +45,7 @@ from consensus_tpu.models.verifier import Ed25519Signer
 from consensus_tpu.ops import ed25519 as ed
 from consensus_tpu.ops import field25519 as fe
 from consensus_tpu.ops import field_p256 as fp
-from consensus_tpu.ops import limbs, mxu_limbs, pallas_scan
+from consensus_tpu.ops import limbs, mxu_limbs
 
 _LANES = (
     ("vpu", mxu_limbs.suppress_mxu_limbs),
@@ -187,8 +182,8 @@ def test_lane_selection_precedence(monkeypatch):
     assert not mxu_limbs.lane_active()
     monkeypatch.setenv("CTPU_MXU_LIMBS", "1")
     assert mxu_limbs.lane_active()
-    # Suppression wins over both the env flag and an explicit force: the
-    # sharded MSM seam and the kernel-injection windows rely on it.
+    # Suppression wins over both the env flag and an explicit force (the
+    # bench A/B's control arm relies on it).
     with mxu_limbs.suppress_mxu_limbs():
         assert not mxu_limbs.lane_active()
         with mxu_limbs.force_mxu_limbs():
@@ -251,10 +246,9 @@ def test_strict_verdict_parity_single_device(monkeypatch):
 
 @pytest.mark.slow
 def test_randomized_verdict_parity_single_device(monkeypatch):
-    """Flag-on the randomized verifier's MSM goes through the VMEM Pallas
-    kernel (batch 8 -> tile 8, interpret on CPU) and its reject-bisection
-    localizes every bad lane — verdicts must still match the flag-off run
-    bit for bit.  min_device_batch=5 keeps the bisection's sub-batches on
+    """Flag-on the randomized verifier's MSM runs its field arithmetic on
+    the MXU lane and its reject-bisection localizes every bad lane —
+    verdicts must still match the flag-off run bit for bit.  min_device_batch=5 keeps the bisection's sub-batches on
     the strict kernel compiled once per lane (a 2-lane A/B that also
     compiled 4- and 2-lane aggregate kernels would double tier-1's bill
     for no extra coverage — the slow mesh test exercises those tiles)."""
@@ -284,9 +278,9 @@ def test_halfagg_verdict_parity(monkeypatch):
     msgs = [b"halfagg-%d" % i for i in range(4)]
     sigs = [s.sign_raw(m) for s, m in zip(signers, msgs)]
     keys = [s.public_bytes for s in signers]
-    cert, bad = agg.HalfAggregator(
-        min_device_batch=1, device_prep=False
-    ).aggregate(msgs, sigs, keys)
+    cert, bad = agg.HalfAggregator(min_device_batch=1).aggregate(
+        msgs, sigs, keys
+    )
     assert cert is not None and bad == ()
     rs, s_agg = cert
     rs = list(rs)
@@ -302,7 +296,7 @@ def test_halfagg_verdict_parity(monkeypatch):
             monkeypatch.setattr(
                 agg, "_halfagg_verify_kernel", _fresh_jit(agg.batch_verify_impl)
             )
-            ver = agg.HalfAggregator(min_device_batch=1, device_prep=False)
+            ver = agg.HalfAggregator(min_device_batch=1)
             out[lane] = {
                 name: ver.verify(*case) for name, case in cases.items()
             }
@@ -344,10 +338,8 @@ def test_strict_verdict_parity_8way_mesh():
 
 @pytest.mark.slow
 def test_randomized_verdict_parity_8way_mesh():
-    """The sharded randomized engine traces under suppress_pallas_scan (no
-    pallas_call under shard_map), so flag-on it runs the XLA MSM with MXU
-    field contractions — exactly the combination msm_config's suppression
-    rule promises.  Verdicts must not move."""
+    """Flag-on, the sharded randomized engine runs the XLA MSM with MXU
+    field contractions.  Verdicts must not move."""
     _mesh_or_skip()
     from consensus_tpu.parallel.sharding import ShardedEd25519RandomizedVerifier
 
@@ -361,90 +353,6 @@ def test_randomized_verdict_parity_8way_mesh():
             out[lane] = np.asarray(eng.verify_batch(msgs, sigs, keys))
     assert out["vpu"].tolist() == _EXPECTED
     assert np.array_equal(out["vpu"], out["mxu"])
-
-
-# --- the VMEM Straus/MSM kernel ---------------------------------------------
-
-def _walk_points(n, step_seed):
-    """n distinct points: multiples of the base point, offset by seed."""
-    base = (ed._BX, (4 * pow(5, fe.P - 2, fe.P)) % fe.P)
-    pts, cur = [], base
-    for _ in range(step_seed):
-        cur = ed._edwards_add_int(cur, base)
-    for _ in range(n):
-        pts.append(cur)
-        cur = ed._edwards_add_int(cur, base)
-    return pts
-
-
-def _point_limbs(points_xy):
-    xs = np.stack([fe.int_to_limbs(x) for x, _ in points_xy], axis=1)
-    ys = np.stack([fe.int_to_limbs(y) for _, y in points_xy], axis=1)
-    ts = np.stack(
-        [fe.int_to_limbs(x * y % fe.P) for x, y in points_xy], axis=1
-    )
-    ones = np.stack([fe.int_to_limbs(1)] * len(points_xy), axis=1)
-    return ed.Point(
-        jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(ones), jnp.asarray(ts)
-    )
-
-
-def _msm_digits(scalars, windows):
-    d = np.array(
-        [model._signed_digits_int(v, windows) for v in scalars],
-        dtype=np.int16,
-    ).T
-    return jnp.asarray((d + 8).astype(np.int32))
-
-
-@pytest.mark.slow
-def test_msm_kernel_matches_xla_lane():
-    """Same dispatch seam the engines use: straus_shared_msm flag-on (the
-    Pallas kernel, seeded from the tables' entry-1 base points) vs the
-    same call under suppress_pallas_scan (the XLA scan).  The two build
-    different projective REPRESENTATIVES by design — equality is the
-    group-element check the verdict path itself uses."""
-    n = 8
-    rng = np.random.default_rng(17)
-    ell = 2**252 + 27742317777372353535851937790883648493
-    zk = [int.from_bytes(rng.bytes(32), "little") % ell for _ in range(n)]
-    zs = [int.from_bytes(rng.bytes(16), "little") or 1 for _ in range(n)]
-    a_table = ed.multiples_table9(ed.negate(_point_limbs(_walk_points(n, 1))))
-    r_table = ed.multiples_table9(ed.negate(_point_limbs(_walk_points(n, 50))))
-    zk_digits = _msm_digits(zk, model._WINDOWS)
-    z_digits = _msm_digits(zs, model._Z_WINDOWS)
-
-    with mxu_limbs.force_mxu_limbs():
-        assert pallas_scan.msm_config(n) == (n, True)  # tile=batch, interpret
-        got = ed.straus_shared_msm(a_table, r_table, zk_digits, z_digits)
-        with pallas_scan.suppress_pallas_scan():
-            assert pallas_scan.msm_config(n) is None
-            want = ed.straus_shared_msm(a_table, r_table, zk_digits, z_digits)
-    assert np.asarray(ed.equal(got, want)).all()
-    assert not np.asarray(ed.is_identity(got)).all()
-
-
-def test_msm_config_selection_rules(monkeypatch):
-    monkeypatch.delenv("CTPU_MXU_LIMBS", raising=False)
-    monkeypatch.delenv("CTPU_MXU_MSM", raising=False)
-    monkeypatch.delenv("CTPU_MXU_MSM_TILE", raising=False)
-    assert pallas_scan.msm_config(256) is None  # flag off: XLA scan
-    with mxu_limbs.force_mxu_limbs():
-        assert pallas_scan.msm_config(256) == (pallas_scan.DEFAULT_TILE, True)
-        assert pallas_scan.msm_config(8) == (8, True)  # sub-tile batch
-        with pallas_scan.suppress_pallas_scan():
-            # The sharded engines trace under suppression: mesh lanes keep
-            # the XLA MSM while the MXU field lane stays active.
-            assert pallas_scan.msm_config(256) is None
-        monkeypatch.setenv("CTPU_MXU_MSM", "0")
-        assert pallas_scan.msm_config(256) is None  # explicit kernel opt-out
-
-
-def test_misconfigured_msm_tile_fails_loud(monkeypatch):
-    monkeypatch.setenv("CTPU_MXU_MSM_TILE", "5")
-    with mxu_limbs.force_mxu_limbs():
-        with pytest.raises(ValueError, match="does not tile"):
-            pallas_scan.msm_config(8)
 
 
 # --- counting-shim semantics -------------------------------------------------
@@ -474,11 +382,11 @@ def test_counting_records_dots_not_muls():
 
 @pytest.mark.slow
 def test_batch512_op_counts_pinned_both_lanes():
-    """The measured BASELINE.md denominators at the batch-512 acceptance
+    """The measured PERF.md denominators at the batch-512 acceptance
     point, pinned exactly for BOTH lanes (abstract tracing only — big
     graphs, hence slow).  The MXU column is honest dense-MAC accounting:
     ~77x the VPU m-equiv, the bet being that MXU throughput covers it.
-    Any drift here means the arithmetic (and thus BASELINE.md) changed."""
+    Any drift here means the arithmetic (and thus PERF.md §5) changed."""
     b = 512
     strict_args = (
         jnp.zeros((32, b), jnp.uint8), jnp.zeros((b,), jnp.uint8),
@@ -508,9 +416,8 @@ def test_batch512_op_counts_pinned_both_lanes():
     assert (strict.muls, strict.squares) == (0, 0)
     assert (strict.dots, strict.dot_macs) == (3393536, 111199387648)
     assert strict.m_equiv == pytest.approx(108593152.0)
-    # The counted randomized trace keeps the XLA MSM (a fori_loop body
-    # traces once without the scan-weight stack, so the Pallas kernel
-    # would undercount) — MXU contractions, XLA scheduling.
+    # The counted randomized trace: the XLA MSM — MXU contractions, XLA
+    # scheduling.
     assert (rand.muls, rand.squares) == (0, 0)
     assert (rand.dots, rand.dot_macs) == (1582226, 51846381568)
     assert rand.m_equiv == pytest.approx(50631232.0)

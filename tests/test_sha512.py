@@ -97,25 +97,6 @@ def test_reduce_bytes_mod_l_full_512bit_range():
         assert got == want
 
 
-def test_mul_and_sum_mod_l_match_bigint():
-    rng = np.random.default_rng(5)
-    a = rng.integers(0, 256, size=(16, 6), dtype=np.uint8)  # 128-bit z's
-    b = rng.integers(0, 256, size=(32, 6), dtype=np.uint8)
-    prod = np.asarray(sc.mul_mod_l(a.astype(np.int32), b.astype(np.int32)))
-    vals = []
-    for i in range(6):
-        ai = int.from_bytes(bytes(a[:, i]), "little")
-        bi = int.from_bytes(bytes(b[:, i]), "little")
-        want = (ai * bi) % L
-        got = int.from_bytes(bytes(prod[:, i].astype(np.uint8)), "little")
-        assert got == want
-        vals.append(want)
-    total = np.asarray(sc.sum_mod_l(prod))
-    assert int.from_bytes(
-        bytes(total[:, 0].astype(np.uint8)), "little"
-    ) == sum(vals) % L
-
-
 def test_lt_l_on_the_boundary():
     rows = np.stack(
         [

@@ -167,6 +167,46 @@ def test_dead_sidecar_falls_back_to_local_engine():
     assert local.calls == [2]
 
 
+def test_client_books_every_signature_by_verdict_source():
+    """``counts()`` is what lets a run prove the device did the work: sent /
+    served by the sidecar, bypassed by size, FALLEN BACK to the host — a
+    fallback is a count, not only a log line."""
+    served_by = FakeEngine()
+    server = VerifySidecarServer(("127.0.0.1", 0), served_by, auth_secret=SECRET)
+    server.start()
+    local = FakeEngine()
+    client = SidecarVerifierClient(
+        server.address, local_engine=local, bypass_below=2,
+        auth_secret=SECRET, connect_timeout=0.5,
+    )
+    try:
+        assert client.counts() == {
+            "sent": 0, "served": 0, "bypassed": 0, "fallen_back": 0,
+            "suspect": False,
+        }
+        client.verify_batch([b"m"], [b"good"], [b"k"])             # by size
+        client.verify_batch([b"m"] * 3, [b"good"] * 3, [b"k"] * 3)  # served
+        assert client.counts() == {
+            "sent": 3, "served": 3, "bypassed": 1, "fallen_back": 0,
+            "suspect": False,
+        }
+        assert served_by.calls == [3] and local.calls == [1]
+        server.stop()
+        client.close()
+        # The sidecar is gone: the batch is still answered, and it SHOWS.
+        dead = SidecarVerifierClient(
+            ("127.0.0.1", 1), local_engine=local, connect_timeout=0.2
+        )
+        out = dead.verify_batch([b"m"] * 2, [b"good", b"bad"], [b"k"] * 2)
+        assert list(out) == [True, False]
+        assert dead.counts() == {
+            "sent": 2, "served": 0, "bypassed": 0, "fallen_back": 2,
+            "suspect": False,
+        }
+    finally:
+        server.stop()
+
+
 def test_dead_sidecar_without_local_engine_raises():
     client = SidecarVerifierClient(("127.0.0.1", 1), connect_timeout=0.2)
     with pytest.raises(OSError):

@@ -196,7 +196,7 @@ def test_supervisor_appends_host_twin_and_delegates_shape_attrs():
 @pytest.mark.parametrize(
     "exc,reason",
     [
-        (LaunchTimeout("wedged tunnel"), "launch_timeout"),
+        (LaunchTimeout("hung device call"), "launch_timeout"),
         (RuntimeError("XLA launch failed"), "launch_raise"),
     ],
 )

@@ -1,0 +1,166 @@
+"""CPU rehearsal of ``chip_smoke.py``'s served-path phases (rig, traffic,
+"the device did it", verdicts) at n=4 / batch 64 with a DEVICE-PATH sidecar
+on the CPU backend — the same code the chip runs at n=7 / batch 1000.
+
+Nothing here is a chip result: the records say ``dry_run`` and
+``platform: cpu``.  What the rehearsal pins is the check itself — it passes
+when the sidecar's backend did every wave, and it FAILS (while the ledger
+still grows on the replicas' host fallback) when the sidecar is taken away.
+
+Subprocess-heavy and compiles one 512-lane kernel on the CPU: named to sort
+last so it never displaces the rest of the tier-1 suite inside its budget.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import chip_smoke
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(tmp_path, **kw):
+    records = []
+    result = chip_smoke.run_served_path(
+        chip_smoke.DRY, str(tmp_path), 7, dry_run=True,
+        emit=records.append, **kw,
+    )
+    return result, {r["phase"]: r for r in records}
+
+
+def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(tmp_path):
+    result, phases = _run(tmp_path)
+    assert result["ok"], phases
+    assert result["device"]["platform"] == "cpu"
+    assert all(r["dry_run"] for r in phases.values())
+    size = chip_smoke.DRY
+    rig, traffic, device, verdicts = (
+        phases[k] for k in ("rig", "traffic", "device", "verdicts")
+    )
+    assert rig["sidecar_lanes"] == 512 and rig["compiles_at_ready"] == 1
+    assert traffic["replicas_delivered_all_exactly_once"]
+    assert traffic["ledgers_identical"]
+    assert traffic["decisions"] * size["batch"] >= size["requests"]
+    assert traffic["leader_rotations_seen"] >= 3
+    # Every signature the replicas sent was launched by the sidecar's
+    # backend; nothing compiled after ready; nobody fell back.
+    assert device["compiles_after_ready"] == 0
+    assert (
+        device["device_signatures"]
+        == device["replica_signatures_sent"]
+        == device["replica_signatures_served"]
+        >= device["floor_committed_x_2f"]
+    )
+    assert device["client_fallbacks"] == 0 and device["suspect_clients"] == []
+    assert device["degrade_count"] == 0 and device["sidecar_restarts"] == 0
+    assert device["launches"] >= 1
+    # The full-width wave with every rejection class, through the socket.
+    assert verdicts["signatures"] == 512 and verdicts["rejected"] == 8
+    assert verdicts["mismatches"] == [] and verdicts["launches"] == 1
+    assert phases["teardown"]["ok"]
+
+
+def test_rehearsal_fails_when_the_sidecar_is_killed_before_traffic(tmp_path):
+    """Replicas fall back to the host, the ledger still grows, every request
+    still commits — and the check must fail all the same."""
+
+    def kill_sidecar(launcher):
+        # SIGSTOP-free, restart-free: the sidecar is simply gone.
+        launcher.sidecars["sc-0"].restart_enabled = False
+        launcher.kill_sidecar("sc-0")
+        deadline = time.monotonic() + 10.0
+        while launcher.sidecars["sc-0"].alive and time.monotonic() < deadline:
+            time.sleep(0.05)
+
+    result, phases = _run(tmp_path, before_traffic=kill_sidecar)
+    assert not result["ok"]
+    # The protocol never noticed ...
+    assert phases["traffic"]["replicas_delivered_all_exactly_once"], phases
+    assert phases["traffic"]["decisions"] >= 12
+    # ... but the processes' own reports did.
+    assert not phases["device"]["ok"]
+    assert phases["device"]["client_fallbacks"] > 0
+    assert phases["device"]["device_signatures"] != (
+        phases["device"]["replica_signatures_sent"]
+    ) or phases["device"]["platform"] is None
+
+
+def test_chip_smoke_refuses_the_cpu(tmp_path):
+    """``JAX_PLATFORMS=cpu python chip_smoke.py`` exits non-zero and prints
+    no result; so does a dry run that is not pinned to the CPU."""
+    for env, argv in (
+        (dict(os.environ, JAX_PLATFORMS="cpu"), []),
+        ({k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"},
+         ["--dry-run"]),
+    ):
+        proc = subprocess.run(
+            [sys.executable, "chip_smoke.py", "--out", str(tmp_path), *argv],
+            cwd=_REPO, env=env, capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode != 0, proc.stdout + proc.stderr
+        assert proc.stdout.strip() == ""
+
+
+def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
+    """The script with nothing else of the repo beside it cannot run."""
+    import shutil
+
+    shutil.copy(os.path.join(_REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "PYTHONPATH")}
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=tmp_path, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert not [l for l in proc.stdout.splitlines() if '"ok": true' in l]
+
+
+def test_last_line_is_exactly_ok_and_device():
+    """The driver parses the last stdout line: ``ok`` and ``device`` with
+    ``platform`` / ``kind`` (text) and ``count`` (whole number), no other
+    key; and no line at all when no process reported a device."""
+    line = chip_smoke.contract_line(
+        True, {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    )
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    assert json.loads(chip_smoke.contract_line(
+        False, {"platform": "cpu", "kind": "cpu", "count": 8, "extra": 1}
+    )) == {"ok": False,
+           "device": {"platform": "cpu", "kind": "cpu", "count": 8}}
+    assert chip_smoke.contract_line(False, None) is None
+    assert chip_smoke.contract_line(
+        False, {"platform": None, "kind": None, "count": None}) is None
+
+
+def test_census_lane_table_covers_the_registry():
+    """Every single-device key the engine registry builds has a census lane
+    (strict / randomized x host-prep / device-prep, P-256), the half-agg
+    certs ride both Ed25519 front-ends, and the default lane comes first."""
+    from consensus_tpu.models.registry import ENGINE_REGISTRY
+
+    lanes = chip_smoke.LANES
+    assert next(iter(lanes)) == "ed25519.strict"
+    covered = set()
+    for env, curve, knobs, _check in lanes.values():
+        covered.add((
+            curve,
+            "randomized" if knobs.get("batch_verify_mode") else "strict",
+            bool(knobs.get("device_prep")),
+            env.get("CTPU_MXU_LIMBS") == "1",
+        ))
+    for key in ENGINE_REGISTRY.keys():
+        if key.topology != "single":
+            continue
+        if key.mxu and key.device_prep:
+            continue  # the MXU flag lanes are censused on host-prep engines
+        assert (key.curve, key.mode, key.device_prep, key.mxu) in covered, key
+    assert {c for *_x, c in lanes.values()} == {"verdicts", "halfagg"}
+    json.dumps({k: v[0] for k, v in lanes.items()})  # env flags are plain data

@@ -36,6 +36,11 @@ from consensus_tpu.net import TcpComm
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+#: These rigs prove process lifecycles, not the device path: their sidecars
+#: are declared host-only (no backend opened, nothing compiled).  The
+#: device-path rig is tests/test_zz_chip_smoke.py.
+_HOST_ONLY = {"crypto_tpu_min_batch": 10**9}
+
 #: The ingress driver's transport id (outside the replica id range).
 _CLIENT_ID = 900
 
@@ -75,7 +80,8 @@ def test_cluster_smoke_orders_decisions(tmp_path):
     through real sockets; teardown leaves zero orphans / leaked ports."""
     spec = ClusterSpec.generate(
         3, 1, str(tmp_path),
-        config_overrides={"request_batch_max_count": 1},  # 1 request = 1 decision
+        # 1 request = 1 decision
+        config_overrides={"request_batch_max_count": 1, **_HOST_ONLY},
     )
     launcher = ClusterLauncher(spec)
     injector = None
@@ -116,6 +122,7 @@ def test_acceptance_kill9_leader_sidecar_and_rejoin(tmp_path):
             "view_change_resend_interval": 1.0,
             "leader_heartbeat_timeout": 2.0,
             "leader_heartbeat_count": 8,
+            **_HOST_ONLY,
         },
     )
     # Supervisor backoff well past the view-change window: the killed

@@ -128,7 +128,11 @@ def test_two_groups_share_one_fleet_as_processes(tmp_path):
     and zero leaked ports in EVERY group."""
     sharded = ShardedDeploySpec.generate(
         2, 3, 1, str(tmp_path),
-        config_overrides={"request_batch_max_count": 1},
+        # Host-only shared sidecar: this rig proves process sharing, not
+        # the device path (no backend opened, nothing compiled).
+        config_overrides={
+            "request_batch_max_count": 1, "crypto_tpu_min_batch": 10**9,
+        },
     )
     # Shared fleet, disjoint identities: same sidecar addresses + auth
     # secret everywhere, per-group key namespaces.
