@@ -33,7 +33,9 @@ The control socket's ``health`` reports what a run needs to prove the
 device did the work: ``platform`` / ``device_kind`` / ``device_count``, the
 kernel ledger (launches, compiles, compiles since ready), signatures and
 lanes launched on the device, signatures served from the host, the
-coalescer's ``device_suspect`` flag and its degrade count — plus the wave
+coalescer's ``device_suspect`` flag and its degrade count, the flusher
+thread's phase ledger (``flusher``: nanoseconds per phase, queue wait and
+flushes by fill, :mod:`consensus_tpu.obs.kernels`) — plus the wave
 counters (offered/rejected) and ``engine_degraded`` the autoscaler reads,
 and a ``degrade`` chaos arm that makes the engine wrapper report degraded
 without changing verdicts.
@@ -137,7 +139,7 @@ def main() -> int:
 
     from consensus_tpu.models import ThreadCoalescingVerifier, engine_for_config
     from consensus_tpu.net.sidecar import VerifySidecarServer
-    from consensus_tpu.obs.kernels import KERNELS
+    from consensus_tpu.obs.kernels import FLUSHER, KERNELS
 
     # --- the engine the spec selects, at the one shape it implies ---------
     config = spec.make_configuration(spec.node_ids()[0])
@@ -254,6 +256,9 @@ def main() -> int:
             "host_signatures": counts["host_signatures"],
             "device_suspect": coalescer.device_suspect,
             "degrade_count": coalescer.health.suspect_marks,
+            # What the flusher thread did with its time, and how full its
+            # flushes were: cumulative since process start.
+            "flusher": FLUSHER.snapshot(),
             # Autoscaler signals.
             "offered": counts["offered"],
             "rejected": 0,

@@ -30,7 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from consensus_tpu.obs.kernels import instrumented_jit, kernel_lane_suffix
+from consensus_tpu.obs.kernels import instrumented_jit, kernel_lane_suffix, phase
 
 from consensus_tpu.ops import ed25519 as ed
 from consensus_tpu.ops import field25519 as fe
@@ -322,30 +322,36 @@ class Ed25519BatchVerifier:
         if n < self._min_device_batch:
             return self._verify_host(messages, signatures, public_keys)
 
-        y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok = self._prepare(
-            messages, signatures, public_keys
-        )
+        # Four phases of the calling thread, in the sidecar its flusher
+        # (obs/kernels.py FLUSHER_PHASES).
+        with phase("verify.prepare", cpu=True):
+            y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok = self._prepare(
+                messages, signatures, public_keys
+            )
 
-        if self._pad_to >= n:
-            padded = self._pad_to
-        else:
-            padded = _next_pow2(n) if self._pad_pow2 else n
-        if padded != n:
-            pad = padded - n
-            y_r = np.pad(y_r, ((0, pad), (0, 0)))
-            y_a = np.pad(y_a, ((0, pad), (0, 0)))
-            sign_r = np.pad(sign_r, (0, pad))
-            sign_a = np.pad(sign_a, (0, pad))
-            s_bits = np.pad(s_bits, ((0, pad), (0, 0)))
-            k_bits = np.pad(k_bits, ((0, pad), (0, 0)))
-            host_ok_padded = np.pad(host_ok, (0, pad))
-        else:
-            host_ok_padded = host_ok
+        with phase("verify.layout"):
+            if self._pad_to >= n:
+                padded = self._pad_to
+            else:
+                padded = _next_pow2(n) if self._pad_pow2 else n
+            if padded != n:
+                pad = padded - n
+                y_r = np.pad(y_r, ((0, pad), (0, 0)))
+                y_a = np.pad(y_a, ((0, pad), (0, 0)))
+                sign_r = np.pad(sign_r, (0, pad))
+                sign_a = np.pad(sign_a, (0, pad))
+                s_bits = np.pad(s_bits, ((0, pad), (0, 0)))
+                k_bits = np.pad(k_bits, ((0, pad), (0, 0)))
+                host_ok = np.pad(host_ok, (0, pad))
+            kernel_inputs = to_kernel_layout(
+                y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok
+            )
 
-        result = _verify_kernel(*to_kernel_layout(
-            y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok_padded
-        ))
-        return np.asarray(result)[:n]
+        with phase("verify.dispatch"):
+            result = _verify_kernel(*kernel_inputs)
+        with phase("verify.await"):
+            verdicts = np.asarray(result)
+        return verdicts[:n]
 
     @staticmethod
     def _canonical_ok(signatures, public_keys) -> np.ndarray:

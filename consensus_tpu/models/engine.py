@@ -20,6 +20,7 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from consensus_tpu.models.supervisor import ENGINE_HEALTH, EngineHealth
+from consensus_tpu.obs.kernels import FLUSHER, phase
 from consensus_tpu.runtime.scheduler import Scheduler, TimerHandle
 
 logger = logging.getLogger("consensus_tpu.models.engine")
@@ -104,6 +105,7 @@ class BatchCoalescer:
 class _Pending:
     __slots__ = (
         "messages", "signatures", "keys", "done", "result", "error", "waiterless",
+        "queued_ns",
     )
 
     def __init__(self, messages, signatures, keys, *, waiterless: bool = False):
@@ -116,6 +118,8 @@ class _Pending:
         # Recovery probes have no waiter: nobody consumes their results, so
         # failure paths shouldn't burn host CPU computing them.
         self.waiterless = waiterless
+        # Stamped when the submission joins the flusher's queue.
+        self.queued_ns = 0
 
 
 def _slice_wave_target(engine, cap: int) -> int:
@@ -314,7 +318,9 @@ class ThreadCoalescingVerifier:
         with self._cv:
             if self._closed:
                 raise RuntimeError("coalescer is closed")
+            now = time.monotonic_ns()  # wallclock-ok
             for item in items:
+                item.queued_ns = now
                 self._pending.append(item)
                 self._count += len(item.messages)
             self._cv.notify_all()
@@ -366,6 +372,7 @@ class ThreadCoalescingVerifier:
                 list(public_keys[:cap]),
                 waiterless=True,
             )
+            item.queued_ns = time.monotonic_ns()  # wallclock-ok
             self._pending.append(item)
             self._count += cap
             self._cv.notify_all()
@@ -419,8 +426,11 @@ class ThreadCoalescingVerifier:
     # -- flusher thread ----------------------------------------------------
 
     def _take_batch(self) -> list[_Pending]:
-        """Pop whole pending submissions up to ``hard_cap`` signatures."""
-        taken, total = [], 0
+        """Pop whole pending submissions up to ``hard_cap`` signatures, and
+        book (under the ``_cv`` the caller holds) how long each waited in
+        the queue and how full of ``hard_cap`` the flush is."""
+        taken, total, waited = [], 0, 0
+        now = time.monotonic_ns()  # wallclock-ok
         while self._pending:
             nxt = len(self._pending[0].messages)
             if taken and total + nxt > self._hard_cap:
@@ -428,68 +438,67 @@ class ThreadCoalescingVerifier:
             item = self._pending.pop(0)
             taken.append(item)
             total += nxt
+            waited += now - item.queued_ns
         self._count -= total
+        if taken:
+            FLUSHER.add("queue_wait_ns", waited)
+            FLUSHER.add("submissions", len(taken))
+            FLUSHER.add("flushes", 1)
+            quarter = min(4, max(1, -(-4 * total // self._hard_cap)))
+            FLUSHER.add(f"fill_le_{25 * quarter}", 1)
         return taken
 
     def _loop(self) -> None:
+        # This thread's life, cut into exclusive phases (obs/kernels.py
+        # FLUSHER_PHASES): the four ``wave.*`` ones here, ``engine_ns``
+        # around the engine call, whose device path cuts itself into the
+        # ``verify.*`` ones.
         while True:
             with self._cv:
-                while not self._pending and not self._closed:
-                    self._cv.wait()
+                with phase("wave.wait_work"):
+                    while not self._pending and not self._closed:
+                        self._cv.wait()
                 if not self._pending and self._closed:
                     return
-                deadline = time.monotonic() + self._window  # wallclock-ok
-                while self._count < self._flush_target and not self._closed:
-                    remaining = deadline - time.monotonic()  # wallclock-ok
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(remaining)
-                batch = self._take_batch()
+                with phase("wave.wait_window"):
+                    deadline = time.monotonic() + self._window  # wallclock-ok
+                    while self._count < self._flush_target and not self._closed:
+                        remaining = deadline - time.monotonic()  # wallclock-ok
+                        if remaining <= 0:
+                            break
+                        self._cv.wait(remaining)
+                with phase("wave.take"):
+                    batch = self._take_batch()
             if not batch:
                 continue
-            messages: list = []
-            signatures: list = []
-            keys: list = []
-            for item in batch:
-                messages.extend(item.messages)
-                signatures.extend(item.signatures)
-                keys.extend(item.keys)
+            with phase("wave.take"):
+                messages: list = []
+                signatures: list = []
+                keys: list = []
+                for item in batch:
+                    messages.extend(item.messages)
+                    signatures.extend(item.signatures)
+                    keys.extend(item.keys)
+            # Counted, not annotated: the engine's own phases lie inside.
+            t0 = time.monotonic_ns()  # wallclock-ok
             try:
                 results = np.asarray(self._engine.verify_batch(messages, signatures, keys))
-                slices = _split_results(results, [len(i.messages) for i in batch])
+                error = None
             except BaseException as exc:
-                if self._host_fallback is not None:
-                    # Device call failed fast (not hung): serve this flush
-                    # from the host path so waiters complete, and mark the
-                    # device suspect so new submissions skip the queue.
-                    logger.error(
-                        "device verify flush failed (%r) — serving %d "
-                        "signatures via HOST fallback; device suspect",
-                        exc,
-                        len(messages),
-                    )
-                    self._health.mark_suspect("launch_raise")
-                    for item in batch:
-                        if item.waiterless:
-                            item.done.set()  # failed probe: nothing to serve
-                            continue
-                        try:
-                            item.result = np.asarray(
-                                self._host_fallback(
-                                    item.messages, item.signatures, item.keys
-                                )
-                            )
-                        except BaseException as host_exc:
-                            # The host path failing too (e.g. malformed
-                            # inputs) must not kill the flusher thread —
-                            # deliver it as this waiter's error.
-                            item.error = host_exc
-                        item.done.set()
-                    continue
-                for item in batch:  # no host path: propagate to every waiter
-                    item.error = exc
-                    item.done.set()
-                continue
+                results, error = None, exc
+            FLUSHER.add("engine_ns", time.monotonic_ns() - t0)  # wallclock-ok
+            with phase("wave.deliver"):
+                self._deliver(batch, results, error)
+
+    def _deliver(self, batch, results, error) -> None:
+        """Hand each waiter of a flush its slice of the verdicts; a flush
+        that failed is served from the host path, or fails every waiter."""
+        if error is None:
+            try:
+                slices = _split_results(results, [len(i.messages) for i in batch])
+            except Exception as exc:  # a short result fails the flush
+                error = exc
+        if error is None:
             if self._health.clear():
                 logger.warning(
                     "device verify flush succeeded — clearing suspect flag, "
@@ -498,6 +507,36 @@ class ThreadCoalescingVerifier:
             for item, piece in zip(batch, slices):
                 item.result = piece
                 item.done.set()
+            return
+        if self._host_fallback is None:
+            for item in batch:  # no host path: propagate to every waiter
+                item.error = error
+                item.done.set()
+            return
+        # Device call failed fast (not hung): serve this flush from the
+        # host path so waiters complete, and mark the device suspect so new
+        # submissions skip the queue.
+        logger.error(
+            "device verify flush failed (%r) — serving %d signatures via "
+            "HOST fallback; device suspect",
+            error,
+            sum(len(item.messages) for item in batch),
+        )
+        self._health.mark_suspect("launch_raise")
+        for item in batch:
+            if item.waiterless:
+                item.done.set()  # failed probe: nothing to serve
+                continue
+            try:
+                item.result = np.asarray(
+                    self._host_fallback(item.messages, item.signatures, item.keys)
+                )
+            except BaseException as host_exc:
+                # The host path failing too (e.g. malformed inputs) must
+                # not kill the flusher thread — deliver it as this waiter's
+                # error.
+                item.error = host_exc
+            item.done.set()
 
 
 class AdmissionReject(Exception):

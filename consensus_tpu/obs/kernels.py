@@ -20,10 +20,21 @@ the live and structured-skip paths.
 jax is imported lazily inside the wrapper so importing consensus_tpu.obs
 never drags in the accelerator stack (the sim plane must stay importable
 on boxes without jax).
+
+:func:`phase` and the :data:`FLUSHER` ledger say what the thread that feeds
+the device does between launches: the sidecar's flusher thread
+(``models/engine.py::ThreadCoalescingVerifier._loop`` and, under it,
+``models/ed25519.py::Ed25519BatchVerifier.verify_batch``) cuts its life
+into named phases, each a ``jax.profiler.TraceAnnotation`` on the
+profiler's own clock (so a device trace names its idle gaps by phase) and
+a sum of nanoseconds in the ledger, which the sidecar's ``health``
+returns under ``flusher``.
 """
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Optional
 
 
@@ -158,6 +169,101 @@ class CompileCacheStats:
 COMPILE_CACHE = CompileCacheStats()
 
 
+#: The flusher thread's phases: exclusive, sequential, never nested.  The
+#: ledger holds the nanoseconds spent in each, by ``time.monotonic_ns``.
+FLUSHER_PHASES = (
+    "wave.wait_work",    # _cv.wait() with nothing pending (starved)
+    "wave.wait_window",  # the coalescing window
+    "wave.take",         # _take_batch and the list joins
+    "wave.deliver",      # split, done.set() (a failed flush's host serving)
+    "verify.prepare",    # Ed25519BatchVerifier._prepare
+    "verify.layout",     # np.pad + to_kernel_layout: the host->device copies
+    "verify.dispatch",   # the _verify_kernel(...) call
+    "verify.await",      # np.asarray(result): the wait for the launch
+)
+#: Counted beside them: thread CPU time inside ``verify.prepare``, the whole
+#: of ``engine.verify_batch`` as the flusher sees it, what submissions waited
+#: between ``_enqueue`` and ``_take_batch``, and each flush by how full it
+#: was of ``hard_cap`` (<= 25%, <= 50%, <= 75%, <= 100%).
+FLUSHER_COUNTERS = (
+    "verify.prepare_cpu", "engine_ns", "queue_wait_ns", "submissions",
+    "flushes", "fill_le_25", "fill_le_50", "fill_le_75", "fill_le_100",
+)
+
+
+class PhaseLedger:
+    """Process-wide sums under a fixed set of keys: integers, cumulative
+    since process start, never decreasing."""
+
+    def __init__(self, keys) -> None:
+        self._lock = threading.Lock()
+        self._sums = dict.fromkeys(keys, 0)
+
+    def add(self, key: str, amount: int) -> None:
+        with self._lock:
+            self._sums[key] += amount
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._sums)
+
+
+#: What the sidecar's flusher thread did with its time (sidecar ``health``
+#: returns it under ``flusher``).
+FLUSHER = PhaseLedger(FLUSHER_PHASES + FLUSHER_COUNTERS)
+
+#: ``jax.profiler.TraceAnnotation``, resolved at the first :func:`phase`
+#: (``None``: not yet; ``False``: no jax here).
+_ANNOTATION = None
+
+
+def _annotation_type():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class phase:
+    """``with phase(name):`` — one phase of the flusher thread.  Opens a
+    ``jax.profiler.TraceAnnotation(name)`` (the bare name: a trace's idle
+    gaps are grouped by it) and adds the elapsed ``time.monotonic_ns()`` to
+    :data:`FLUSHER` under ``name``; with ``cpu=True`` also the elapsed
+    ``time.thread_time_ns()`` under ``name + "_cpu"``, so the share of the
+    phase in which the thread held no core can be read.  Without jax, or
+    with no profiler running, it is the clock reads.  Use it on the thread
+    that feeds the device only: a span on a thread that merely waits for
+    that one would cover the same gaps and hide the phases."""
+
+    __slots__ = ("_name", "_cpu", "_annotation", "_t0", "_cpu0")
+
+    def __init__(self, name: str, *, cpu: bool = False) -> None:
+        self._name = name
+        self._cpu = cpu
+
+    def __enter__(self) -> "phase":
+        annotation = _annotation_type()
+        self._annotation = annotation(self._name) if annotation else None
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._cpu:
+            self._cpu0 = time.thread_time_ns()
+        self._t0 = time.monotonic_ns()  # wallclock-ok: a duration, not a timestamp
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        elapsed = time.monotonic_ns() - self._t0  # wallclock-ok
+        if self._cpu:
+            FLUSHER.add(self._name + "_cpu", time.thread_time_ns() - self._cpu0)
+        FLUSHER.add(self._name, elapsed)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
+
+
 def _cache_size(jitted) -> int:
     try:
         return int(jitted._cache_size())
@@ -225,11 +331,16 @@ def instrumented_jit(
 __all__ = [
     "COMPILE_CACHE",
     "CompileCacheStats",
+    "FLUSHER",
+    "FLUSHER_COUNTERS",
+    "FLUSHER_PHASES",
     "KERNELS",
     "KernelRegistry",
     "KernelStats",
+    "PhaseLedger",
     "TENANT_KERNELS",
     "TenantAccounting",
     "instrumented_jit",
     "kernel_lane_suffix",
+    "phase",
 ]

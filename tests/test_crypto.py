@@ -245,6 +245,38 @@ class TestBatchVerifier:
         swapped = keys[1:] + keys[:1]
         assert not v.verify_batch(msgs, sigs, swapped).any()
 
+    def test_the_four_verify_phases_fire_once_per_device_launch_only(self, monkeypatch):
+        """The device path books ``verify.prepare`` / ``.layout`` /
+        ``.dispatch`` / ``.await`` in the flusher ledger, once each and in
+        that order per ``verify_batch``; the host path books none.  Same
+        8-lane shape as the test above: no compile of its own."""
+        from consensus_tpu.models import ed25519 as model
+        from consensus_tpu.obs.kernels import FLUSHER, phase
+
+        opened = []
+
+        class spy(phase):
+            def __init__(self, name, **kw):
+                opened.append((name, kw))
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(model, "phase", spy)
+        names = ("verify.prepare", "verify.layout", "verify.dispatch", "verify.await")
+        msgs, sigs, keys = make_sigs(8)
+        before = FLUSHER.snapshot()
+        assert Ed25519BatchVerifier().verify_batch(msgs, sigs, keys).all()
+        assert opened == [(names[0], {"cpu": True})] + [(n, {}) for n in names[1:]]
+        after = FLUSHER.snapshot()
+        moved = {k for k in after if after[k] != before[k]}
+        assert moved == set(names) | {"verify.prepare_cpu"}
+        # prepare is Python and numpy on this thread: it held a core
+        assert 0 < after["verify.prepare_cpu"] - before["verify.prepare_cpu"]
+        del opened[:]
+        host = Ed25519BatchVerifier(min_device_batch=100)
+        assert host.verify_batch(msgs, sigs, keys).all()
+        assert Ed25519BatchVerifier().verify_host(msgs, sigs, keys).all()
+        assert opened == [] and FLUSHER.snapshot() == after
+
     def test_high_s_rejected(self):
         # S >= L must be rejected even if the curve equation would hold.
         from consensus_tpu.models.ed25519 import L

@@ -1,0 +1,291 @@
+"""The flusher thread's phase ledger (obs/kernels.py ``phase`` / ``FLUSHER``)
+and the coalescer that fills it (models/engine.py
+``ThreadCoalescingVerifier._loop``): a fake engine with a planted sleep in
+every phase, so each phase's nanoseconds must land under its own name and
+the phases together must account for the thread's whole life.
+
+No kernel is compiled here; the four ``verify.*`` phases of the real device
+path are tested where the strict kernel is compiled anyway
+(tests/test_crypto.py).  The ledger is process-wide, so every test reads
+differences of two snapshots.
+"""
+
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from consensus_tpu.models import ThreadCoalescingVerifier
+from consensus_tpu.obs import kernels
+from consensus_tpu.obs.kernels import (
+    FLUSHER,
+    FLUSHER_COUNTERS,
+    FLUSHER_PHASES,
+    PhaseLedger,
+    phase,
+)
+
+MS = 1_000_000
+WAVE = ("wave.wait_work", "wave.wait_window", "wave.take", "wave.deliver")
+VERIFY = ("verify.prepare", "verify.layout", "verify.dispatch", "verify.await")
+
+
+def _since(before: dict) -> dict:
+    after = FLUSHER.snapshot()
+    return {k: after[k] - before[k] for k in after}
+
+
+class _PhasedEngine:
+    """Stands in for the device path: sleeps ``planted[name]`` seconds in
+    each ``verify.*`` phase, and ``outside`` seconds in none of them."""
+
+    def __init__(self, planted: dict, outside: float = 0.0) -> None:
+        self.planted, self.outside, self.calls = planted, outside, []
+
+    def verify_batch(self, msgs, sigs, keys):
+        self.calls.append(len(msgs))
+        for name in VERIFY:
+            with phase(name, cpu=(name == "verify.prepare")):
+                time.sleep(self.planted.get(name, 0.0))
+        time.sleep(self.outside)
+        return np.ones(len(msgs), dtype=bool)
+
+
+class _SlowEdges(ThreadCoalescingVerifier):
+    """A coalescer whose take and deliver steps are slow, so that their
+    phases have something to show."""
+
+    take_s = deliver_s = 0.0
+
+    def _take_batch(self):
+        time.sleep(self.take_s)
+        return super()._take_batch()
+
+    def _deliver(self, *args):
+        time.sleep(self.deliver_s)
+        return super()._deliver(*args)
+
+
+def _wave(n: int):
+    return [b"m"] * n, [b"s"] * n, [b"k"] * n
+
+
+def test_every_phase_lands_under_its_own_name_and_they_close_the_threads_life():
+    planted = {"wave.wait_work": 0.12, "wave.wait_window": 0.06,
+               "wave.take": 0.04, "wave.deliver": 0.05,
+               "verify.prepare": 0.05, "verify.layout": 0.04,
+               "verify.dispatch": 0.04, "verify.await": 0.08}
+    outside, rounds, slack = 0.04, 2, 30 * MS
+    engine = _PhasedEngine(planted, outside)
+    before = FLUSHER.snapshot()
+    t_born = time.monotonic_ns()
+    v = _SlowEdges(engine, window=planted["wave.wait_window"], max_batch=64,
+                   hard_cap=64)
+    v.take_s, v.deliver_s = planted["wave.take"], planted["wave.deliver"]
+    for _ in range(rounds):
+        time.sleep(planted["wave.wait_work"])  # the flusher has nothing to do
+        assert v.verify_batch(*_wave(10)).all()
+    v.close()
+    lifetime = time.monotonic_ns() - t_born
+    assert not v._thread.is_alive()
+    got = _since(before)
+
+    # Each phase holds its own planted sleep, rounds times over, and not
+    # its neighbours': the least planted sleep is 40 ms, the slack 30 ms a
+    # round (a sleep never returns early; a loaded machine wakes late).
+    for name, seconds in planted.items():
+        low = rounds * seconds * 1e9
+        assert low <= got[name] < low + rounds * slack, (name, got[name])
+    # The engine as the flusher sees it = its four phases + what it does
+    # outside them.
+    inside = sum(got[name] for name in VERIFY)
+    assert got["engine_ns"] >= inside + rounds * outside * 1e9
+    assert got["engine_ns"] < inside + rounds * (outside * 1e9 + slack)
+    # Closure: the wave phases and the engine call account for the
+    # thread's life, from before its birth to after its death.
+    accounted = sum(got[name] for name in WAVE) + got["engine_ns"]
+    assert 0.98 * lifetime <= accounted <= lifetime, (accounted, lifetime)
+    # prepare slept: the thread held no core for nearly all of it.
+    assert got["verify.prepare_cpu"] < 0.2 * got["verify.prepare"]
+    # What was submitted: one submission a flush, each under a quarter full, each
+    # waited out the window and the slow take.
+    assert got["submissions"] == got["flushes"] == rounds == len(engine.calls)
+    assert (got["fill_le_25"], got["fill_le_50"], got["fill_le_75"],
+            got["fill_le_100"]) == (rounds, 0, 0, 0)
+    waited = rounds * (planted["wave.wait_window"] + planted["wave.take"]) * 1e9
+    assert waited <= got["queue_wait_ns"] < waited + rounds * slack
+
+
+@pytest.mark.parametrize("signatures, bucket", [
+    (1, 25), (25, 25), (26, 50), (50, 50), (51, 75), (75, 75), (76, 100),
+    (100, 100),
+])
+def test_a_flush_is_counted_by_how_full_of_hard_cap_it_is(signatures, bucket):
+    engine = _PhasedEngine({})
+    v = ThreadCoalescingVerifier(engine, window=0.001, max_batch=100,
+                                 hard_cap=100)
+    before = FLUSHER.snapshot()
+    assert v.verify_batch(*_wave(signatures)).all()
+    v.close()
+    got = _since(before)
+    assert engine.calls == [signatures]
+    assert {k: got[f"fill_le_{k}"] for k in (25, 50, 75, 100)} == {
+        k: int(k == bucket) for k in (25, 50, 75, 100)}
+    assert got["flushes"] == got["submissions"] == 1
+
+
+def test_submissions_that_share_a_flush_each_count_their_own_wait():
+    """Three callers, 40 ms apart, ride ONE flush (the window is 200 ms):
+    three submissions, one flush over half full, and a queue wait that is
+    the sum of theirs (about 200 + 160 + 120 ms), not the flush's."""
+    engine = _PhasedEngine({})
+    v = ThreadCoalescingVerifier(engine, window=0.2, max_batch=1000,
+                                 hard_cap=100)
+    before = FLUSHER.snapshot()
+    threads = []
+    for _ in range(3):
+        t = threading.Thread(target=lambda: v.verify_batch(*_wave(20)))
+        t.start()
+        threads.append(t)
+        time.sleep(0.04)
+    for t in threads:
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+    v.close()
+    got = _since(before)
+    assert engine.calls == [60]
+    assert (got["submissions"], got["flushes"]) == (3, 1)
+    assert got["fill_le_75"] == 1
+    assert 400 * MS <= got["queue_wait_ns"] < 560 * MS
+    assert 190 * MS <= got["wave.wait_window"] < 240 * MS
+
+
+def test_a_flush_that_raises_is_still_counted_and_served_from_the_host():
+    class Boom:
+        def verify_batch(self, msgs, sigs, keys):
+            time.sleep(0.02)
+            raise RuntimeError("planted")
+
+        def verify_host(self, msgs, sigs, keys):
+            time.sleep(0.03)
+            return np.ones(len(msgs), dtype=bool)
+
+    v = ThreadCoalescingVerifier(Boom(), window=0.001, max_batch=8)
+    before = FLUSHER.snapshot()
+    assert v.verify_batch(*_wave(4)).all()
+    v.close()
+    got = _since(before)
+    assert v.device_suspect
+    assert got["flushes"] == 1 and 20 * MS <= got["engine_ns"] < 60 * MS
+    assert got["wave.deliver"] >= 30 * MS  # the host serving is booked here
+
+
+def test_phase_with_the_profiler_off_records_time_and_passes_errors_on():
+    before = FLUSHER.snapshot()
+    with phase("wave.take"):
+        time.sleep(0.02)
+    with pytest.raises(KeyError):
+        with phase("wave.deliver"):
+            time.sleep(0.01)
+            raise KeyError("planted")
+    got = _since(before)
+    assert 20 * MS <= got["wave.take"] < 40 * MS
+    assert 10 * MS <= got["wave.deliver"] < 30 * MS
+
+
+def test_phase_without_jax_records_time_and_raises_nothing(monkeypatch):
+    monkeypatch.setattr(kernels, "_ANNOTATION", None)  # resolve again
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)  # import fails
+    before = FLUSHER.snapshot()
+    with phase("verify.layout"):
+        time.sleep(0.02)
+    assert kernels._ANNOTATION is False
+    assert 20 * MS <= _since(before)["verify.layout"] < 40 * MS
+
+
+def test_phase_cpu_tells_a_thread_that_computes_from_one_that_waits():
+    before = FLUSHER.snapshot()
+    with phase("verify.prepare", cpu=True):
+        time.sleep(0.05)
+    slept = _since(before)
+    assert slept["verify.prepare_cpu"] < 0.2 * slept["verify.prepare"]
+    before = FLUSHER.snapshot()
+    with phase("verify.prepare", cpu=True):
+        end = time.thread_time_ns() + 50 * MS
+        while time.thread_time_ns() < end:
+            pass
+    spun = _since(before)
+    assert spun["verify.prepare_cpu"] >= 50 * MS
+    assert spun["verify.prepare_cpu"] <= spun["verify.prepare"] + MS
+
+
+def test_the_ledger_has_a_fixed_set_of_keys_and_loses_no_update():
+    assert set(FLUSHER.snapshot()) == set(FLUSHER_PHASES + FLUSHER_COUNTERS)
+    assert len(set(FLUSHER_PHASES + FLUSHER_COUNTERS)) == 17
+    ledger = PhaseLedger(("a", "b"))
+    with pytest.raises(KeyError):
+        ledger.add("c", 1)  # a name nobody declared is a bug, not a new key
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(20_000):
+                ledger.add("a", 1)
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert ledger.snapshot() == {"a": 160_000, "b": 0}
+
+
+def test_a_profiler_trace_names_the_flusher_threads_phases(tmp_path):
+    """With a profiler running (as the benchmark's traced run has it:
+    python tracer off, host tracer level 2) the phases are host events under
+    their bare names, all on ONE thread's line: that is what lets a device
+    trace name its idle gaps by phase."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    engine = _PhasedEngine({"verify.prepare": 0.004, "verify.await": 0.006})
+    v = ThreadCoalescingVerifier(engine, window=0.003, max_batch=64, hard_cap=64)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(4):
+            assert v.verify_batch(*_wave(10)).all()
+            time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+        v.close()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name in FLUSHER_PHASES:
+                    lines.setdefault((plane.name, n), []).append(
+                        (ev.name, ev.start_ns, ev.duration_ns))
+    assert len(lines) == 1, list(lines)  # the flusher thread, and no other
+    (events,) = lines.values()
+    seen = {name for name, _, _ in events}
+    assert seen >= {"wave.wait_window", "wave.take", "wave.deliver",
+                    "verify.prepare", "verify.await"}
+    # exclusive and sequential: no phase begins before the last one ended
+    events.sort(key=lambda e: e[1])
+    for (_, s0, d0), (_, s1, _) in zip(events, events[1:]):
+        assert s1 >= s0 + d0
+    awaits = [d for name, _, d in events if name == "verify.await"]
+    assert len(awaits) >= 3 and min(awaits) >= 6 * MS

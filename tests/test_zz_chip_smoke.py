@@ -17,22 +17,48 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 import chip_smoke
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(tmp_path, **kw):
+def _run(tmp_path, emit=None, **kw):
     records = []
+
+    def keep(record):
+        records.append(record)
+        if emit is not None:
+            emit(record)
+
     result = chip_smoke.run_served_path(
-        chip_smoke.DRY, str(tmp_path), 7, dry_run=True,
-        emit=records.append, **kw,
+        chip_smoke.DRY, str(tmp_path), 7, dry_run=True, emit=keep, **kw,
     )
     return result, {r["phase"]: r for r in records}
 
 
-def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(tmp_path):
-    result, phases = _run(tmp_path)
+@pytest.fixture(scope="module")
+def rehearsal(tmp_path_factory):
+    """ONE sound rehearsal for the tests below, with the sidecar's ``health``
+    read before the traffic and again once the verdict wave is through."""
+    rig, health = [], []
+
+    def before_traffic(launcher):
+        rig.append(launcher)
+        health.append(launcher.sidecars["sc-0"].probe())
+
+    def emit(record):
+        if record["phase"] == "verdicts":
+            health.append(rig[0].sidecars["sc-0"].probe())
+
+    result, phases = _run(tmp_path_factory.mktemp("chip_smoke"), emit=emit,
+                          before_traffic=before_traffic)
+    return result, phases, health
+
+
+def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(rehearsal):
+    result, phases, _ = rehearsal
     assert result["ok"], phases
     assert result["device"]["platform"] == "cpu"
     assert all(r["dry_run"] for r in phases.values())
@@ -61,6 +87,35 @@ def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(tmp_path):
     assert verdicts["signatures"] == 512 and verdicts["rejected"] == 8
     assert verdicts["mismatches"] == [] and verdicts["launches"] == 1
     assert phases["teardown"]["ok"]
+
+
+def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
+    """``health["flusher"]``: every phase and counter of obs/kernels.py, as
+    integers, cumulative (the warm-up wave is in the first reading already)
+    and never decreasing; across the traffic every one of them moved but the
+    fill buckets no flush fell into, and the launches the kernel ledger
+    counts are the flushes the coalescer counts."""
+    from consensus_tpu.obs.kernels import FLUSHER_COUNTERS, FLUSHER_PHASES
+
+    result, _, health = rehearsal
+    assert result["ok"] and len(health) == 2
+    first, last = (h["flusher"] for h in health)
+    assert set(first) == set(last) == set(FLUSHER_PHASES + FLUSHER_COUNTERS)
+    assert all(type(v) is int for v in list(first.values()) + list(last.values()))
+    assert all(last[k] >= first[k] >= 0 for k in first)
+    assert first["flushes"] == 1 == first["fill_le_25"]  # the warm-up wave
+    assert first["verify.dispatch"] > 0  # ... which compiled inside dispatch
+    buckets = [k for k in first if k.startswith("fill_le_")]
+    assert all(last[k] > first[k] for k in first if k not in buckets)
+    flushes = last["flushes"] - first["flushes"]
+    assert flushes == sum(last[k] - first[k] for k in buckets)
+    assert flushes == (health[1]["launches_after_ready"]
+                       - health[0]["launches_after_ready"])
+    assert last["submissions"] - first["submissions"] >= flushes
+    # The engine call is its four phases and little else.
+    inside = sum(last[k] - first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
+    engine = last["engine_ns"] - first["engine_ns"]
+    assert inside <= engine <= 1.05 * inside
 
 
 def test_rehearsal_fails_when_the_sidecar_is_killed_before_traffic(tmp_path):
