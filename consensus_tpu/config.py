@@ -140,7 +140,11 @@ class Configuration:
 
     # --- TPU crypto engine (no reference counterpart) -------------------
     # Minimum number of pending verifications before the engine prefers the
-    # TPU path over the CPU fallback, and the micro-batch coalescing window.
+    # TPU path over the CPU fallback, and the micro-batch coalescing window:
+    # the FLOOR of the wave former's adaptive hold (models/engine.py
+    # ThreadCoalescingVerifier waits at least this long for company, and
+    # past it, up to a quarter of the launch time it measures, for the rest
+    # of the burst of submissions it has learned to expect).
     crypto_tpu_min_batch: int = 16
     crypto_batch_window: float = 0.002
     # Pad verification batches up to the next power of two (stable XLA shapes,

@@ -12,6 +12,7 @@ network RTT to not hurt p50 commit latency (SURVEY §7 hard part 3).
 
 from __future__ import annotations
 
+import collections
 import logging
 import threading
 import time
@@ -140,6 +141,23 @@ def _slice_wave_target(engine, cap: int) -> int:
     return min(cap, preferred)
 
 
+#: How long the flusher may hold a flush for the rest of a burst, as a share
+#: of the launch time it measures.  Holding h to save a launch of L pays only
+#: while h is well under L: a hold that succeeds saves L - h, one that runs
+#: out costs h on top of the two launches it did not merge.  At a quarter
+#: a success saves three quarters of a launch and a failure adds an eighth
+#: to the two.  Measured on the chip (PERF.md section 6, PR 28): a
+#: 7-replica burst is all in 9-13 ms after its first (p95 ~17 ms) against
+#: the 26-28 ms a ~106 ms launch at 8,192 lanes allows, a 4-replica one in
+#: 2-4 ms against the 3.2-3.9 ms of a ~13 ms launch at 512 lanes.
+_HOLD_SHARE_OF_LAUNCH = 0.25
+#: Bursts (and launch times) the expectation is read from: the last few.
+#: The medians of 8 shrug off one lone submitter among the bursts (the
+#: once-a-second verdict wave, a compile-length launch) and follow a
+#: lasting change (a replica down, a tenant gone) within 5 of them.
+_RECENT_BURSTS = 8
+
+
 class ThreadCoalescingVerifier:
     """Thread-safe verify coalescer for replicas *sharing one device*.
 
@@ -148,9 +166,23 @@ class ThreadCoalescingVerifier:
     the same proposal's signatures — n device launches per decision, each
     paying the fixed dispatch/transfer overhead.  This wrapper merges
     concurrent ``verify_batch`` calls from any thread into one kernel
-    launch: submissions wait up to ``window`` seconds (or until
+    launch: submissions wait at least ``window`` seconds (or until
     ``max_batch`` signatures are pending) and ride a single padded device
     call, then each caller gets its own slice of the results.
+
+    ``window`` is the FLOOR of an adaptive hold.  Replicas of one cluster
+    submit in bursts (one submission each per decision) spread over more
+    than the floor, and a burst cut in two pays two launches.  So the
+    flusher learns the burst it serves — how many submissions arrive
+    within the hold's reach of a burst's first, the median over the last
+    few bursts that found it idle — and, woken from idle with fewer than
+    that pending, keeps waiting past the floor until they are, or no
+    further one could fit under ``hard_cap``, or a quarter of the launch
+    time it measures has run out (``_HOLD_SHARE_OF_LAUNCH``).  A hold that
+    runs out counts as a burst of what did come, so a lone submitter or a
+    cluster that lost a replica un-learns within a few flushes.
+    Submissions that queued while a launch ran never wait for a hold: they
+    go with the floor at most, at once if the expected burst is there.
 
     The per-replica semantics are unchanged — every replica still checks
     exactly the signatures it chose to check; only the *execution* is
@@ -236,6 +268,9 @@ class ThreadCoalescingVerifier:
             self._probe_clock = time.monotonic  # wallclock-ok
         self._probe_interval = 30.0
         self._last_probe = -float("inf")
+        # (submissions of a burst, ns in the engine) of the last few flushes
+        # that found the flusher idle.  The flusher thread's alone.
+        self._recent: collections.deque = collections.deque(maxlen=_RECENT_BURSTS)
         self._thread = threading.Thread(target=self._loop, daemon=True, name=name)
         self._thread.start()
 
@@ -448,11 +483,51 @@ class ThreadCoalescingVerifier:
             FLUSHER.add(f"fill_le_{25 * quarter}", 1)
         return taken
 
+    def _expectation(self) -> tuple[int, float]:
+        """(submissions a burst is expected to bring, seconds a hold may
+        last past its start): the upper median of the recent bursts, and a
+        share of the lower median of the launch times measured with them —
+        never under the floor.  With no burst seen yet: 1, the floor."""
+        if not self._recent:
+            return 1, self._window
+        bursts = sorted(burst for burst, _ in self._recent)
+        launches = sorted(ns for _, ns in self._recent)
+        reach = _HOLD_SHARE_OF_LAUNCH * launches[(len(launches) - 1) // 2] / 1e9
+        return bursts[len(bursts) // 2], max(self._window, reach)
+
+    def _room_for_another(self) -> bool:
+        """Would one more submission, as large as the pending ones are on
+        average, still fit the flush?  (Called under ``_cv``.)"""
+        return self._count + self._count // len(self._pending) <= self._hard_cap
+
+    def _wait_window(self, idle: bool, expected: int, reach: float) -> None:
+        """The ``wave.wait_window`` phase (under ``_cv``): wait out the
+        floor; woken from ``idle`` with less than the ``expected`` burst
+        pending, hold on for the rest up to ``reach`` seconds from now."""
+        start = time.monotonic()  # wallclock-ok
+        floor = start + self._window
+        limit = start + reach if idle else floor
+        while self._count < self._flush_target and not self._closed:
+            short = 0 < len(self._pending) < expected and self._room_for_another()
+            if expected > 1 and not short:
+                break  # the burst is in, or its next one could not ride: go
+            remaining = (limit if short else floor) - time.monotonic()  # wallclock-ok
+            if remaining <= 0:
+                break
+            self._cv.wait(remaining)
+        now = time.monotonic()  # wallclock-ok
+        if expected > 1 and now > floor and limit > floor:  # it held on
+            if len(self._pending) >= expected:
+                FLUSHER.add("hold_met", 1)
+            elif now >= limit:
+                FLUSHER.add("hold_expired", 1)
+
     def _loop(self) -> None:
         # This thread's life, cut into exclusive phases (obs/kernels.py
         # FLUSHER_PHASES): the four ``wave.*`` ones here, ``engine_ns``
         # around the engine call, whose device path cuts itself into the
         # ``verify.*`` ones.
+        returned_ns = 0  # when the last launch came back
         while True:
             with self._cv:
                 with phase("wave.wait_work"):
@@ -460,13 +535,16 @@ class ThreadCoalescingVerifier:
                         self._cv.wait()
                 if not self._pending and self._closed:
                     return
+                # Idle = nothing queued while the last launch ran: what is
+                # there now is the head of a burst, and may be held for.
+                # What a launch left behind has waited already: it never
+                # waits for a hold (measured, PERF.md section 6: holding
+                # the once-a-second lone wave at a launch's return cost
+                # more latency than it saved launches).
+                idle = self._pending[0].queued_ns >= returned_ns
+                expected, reach = self._expectation()
                 with phase("wave.wait_window"):
-                    deadline = time.monotonic() + self._window  # wallclock-ok
-                    while self._count < self._flush_target and not self._closed:
-                        remaining = deadline - time.monotonic()  # wallclock-ok
-                        if remaining <= 0:
-                            break
-                        self._cv.wait(remaining)
+                    self._wait_window(idle, expected, reach)
                 with phase("wave.take"):
                     batch = self._take_batch()
             if not batch:
@@ -486,9 +564,24 @@ class ThreadCoalescingVerifier:
                 error = None
             except BaseException as exc:
                 results, error = None, exc
-            FLUSHER.add("engine_ns", time.monotonic_ns() - t0)  # wallclock-ok
+            returned_ns = time.monotonic_ns()  # wallclock-ok
+            engine_ns = returned_ns - t0
+            FLUSHER.add("engine_ns", engine_ns)
             with phase("wave.deliver"):
                 self._deliver(batch, results, error)
+                if idle:
+                    self._learn(batch, reach, engine_ns)
+
+    def _learn(self, batch, reach: float, engine_ns: int) -> None:
+        """Book the burst a flush from idle was the head of: its riders and
+        whoever queued behind them within the hold's ``reach`` of the first
+        — the rest of the burst, who would have ridden along had the flush
+        been held that long.  Later arrivals are not counted, so a hold
+        that ran out on them lowers the expectation."""
+        horizon = batch[0].queued_ns + int(reach * 1e9)
+        with self._cv:
+            tail = sum(1 for item in self._pending if item.queued_ns <= horizon)
+        self._recent.append((len(batch) + tail, engine_ns))
 
     def _deliver(self, batch, results, error) -> None:
         """Hand each waiter of a flush its slice of the verdicts; a flush

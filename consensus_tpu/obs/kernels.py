@@ -173,7 +173,7 @@ COMPILE_CACHE = CompileCacheStats()
 #: ledger holds the nanoseconds spent in each, by ``time.monotonic_ns``.
 FLUSHER_PHASES = (
     "wave.wait_work",    # _cv.wait() with nothing pending (starved)
-    "wave.wait_window",  # the coalescing window
+    "wave.wait_window",  # the coalescing window, and a hold past it
     "wave.take",         # _take_batch and the list joins
     "wave.deliver",      # split, done.set() (a failed flush's host serving)
     "verify.prepare",    # Ed25519BatchVerifier._prepare
@@ -183,11 +183,15 @@ FLUSHER_PHASES = (
 )
 #: Counted beside them: thread CPU time inside ``verify.prepare``, the whole
 #: of ``engine.verify_batch`` as the flusher sees it, what submissions waited
-#: between ``_enqueue`` and ``_take_batch``, and each flush by how full it
-#: was of ``hard_cap`` (<= 25%, <= 50%, <= 75%, <= 100%).
+#: between ``_enqueue`` and ``_take_batch``, each flush by how full it was
+#: of ``hard_cap`` (<= 25%, <= 50%, <= 75%, <= 100%), and the flushes the
+#: wave former held past its window for the rest of a burst: those the
+#: burst then filled (``hold_met``) and those the hold ran out on
+#: (``hold_expired``).  The held time itself is ``wave.wait_window``'s.
 FLUSHER_COUNTERS = (
     "verify.prepare_cpu", "engine_ns", "queue_wait_ns", "submissions",
     "flushes", "fill_le_25", "fill_le_50", "fill_le_75", "fill_le_100",
+    "hold_met", "hold_expired",
 )
 
 

@@ -162,6 +162,186 @@ def test_submissions_that_share_a_flush_each_count_their_own_wait():
     assert 190 * MS <= got["wave.wait_window"] < 240 * MS
 
 
+# -- the adaptive hold: ``window`` is its floor ------------------------------
+#
+# A loaded machine stretches every gap below, so a hold may last several
+# times what the stragglers nominally take, and no test reads more from the
+# learning bursts than their sums.
+
+
+def _burst(v, k: int, gap: float, size: int = 10) -> None:
+    """``k`` submitters, ``gap`` seconds apart, each on a thread of its own;
+    returns once every one has its verdicts."""
+    threads = []
+    for _ in range(k):
+        t = threading.Thread(target=lambda: v.verify_batch(*_wave(size)))
+        t.start()
+        threads.append(t)
+        time.sleep(gap)
+    for t in threads:
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+
+
+def _learned(k: int, launch: float, *, window: float = 0.002, gap: float = 0.0,
+             hard_cap: int = 1000, size: int = 10):
+    """A coalescer over an engine whose launch takes ``launch`` seconds,
+    that has seen a lone wave (as the sidecar's warm-up is: it measures the
+    launch time) and then two bursts of ``k``.  Where the floor cut the
+    first, its tail was counted with it, so the second was held for."""
+    engine = _PhasedEngine({"verify.await": launch})
+    v = ThreadCoalescingVerifier(engine, window=window, max_batch=hard_cap,
+                                 hard_cap=hard_cap)
+    assert v.verify_batch(*_wave(size)).all()
+    _burst(v, k, gap, size)
+    _burst(v, k, gap, size)
+    assert sum(engine.calls) == (1 + 2 * k) * size
+    return v, engine
+
+
+@pytest.mark.parametrize("k", [4, 7])
+def test_a_staggered_burst_rides_one_flush_once_learned(k):
+    """k submitters 5 ms apart — two and a half floor windows between
+    neighbours, 15 and 30 ms in all — against the 150 ms that a 600 ms
+    launch lets a hold last."""
+    before = FLUSHER.snapshot()
+    v, engine = _learned(k, launch=0.6, gap=0.005)
+    # Unlearned, the floor cut the first burst; the second rode ONE flush.
+    lone, *cut, held = engine.calls
+    assert (lone, sum(cut), held) == (10, 10 * k, 10 * k) and len(cut) >= 2
+    learning = _since(before)
+    assert (learning["hold_met"], learning["hold_expired"]) == (1, 0)
+    n = len(engine.calls)
+    before = FLUSHER.snapshot()
+    _burst(v, k, 0.005)
+    v.close()
+    got = _since(before)
+    assert engine.calls[n:] == [10 * k]
+    assert (got["flushes"], got["submissions"]) == (1, k)
+    assert (got["hold_met"], got["hold_expired"]) == (1, 0)
+    # It held for the stragglers, and not to the end of what it may.
+    assert 5 * (k - 1) * MS <= got["wave.wait_window"] < 140 * MS
+
+
+def test_a_lone_submitter_pays_the_cap_a_bounded_number_of_times():
+    """After bursts of 4, lone submissions: the first is released when the
+    cap (a quarter of the 300 ms launch) runs out; each such hold counts as
+    a burst of one, so the expectation decays and the later ones wait only
+    the floor."""
+    v, engine = _learned(4, launch=0.3, window=0.005)
+    n = len(engine.calls)
+    waits, expired = [], []
+    for _ in range(6):
+        before = FLUSHER.snapshot()
+        assert v.verify_batch(*_wave(10)).all()
+        got = _since(before)
+        waits.append(got["wave.wait_window"])
+        expired.append(got["hold_expired"])
+        assert got["hold_met"] == 0 and got["flushes"] == 1
+    v.close()
+    assert 75 * MS <= waits[0] < 105 * MS and expired[0] == 1
+    # Bounded: at most 5 of the last 8 bursts have to be lone ones.
+    assert 1 <= sum(expired) <= 5 and expired == sorted(expired, reverse=True)
+    assert expired[-3:] == [0, 0, 0] and max(waits[-3:]) < 35 * MS
+    assert engine.calls[n:] == [10] * 6
+
+
+@pytest.mark.parametrize("launch", [0.001, 0.2, 0.4])
+def test_the_cap_follows_the_measured_launch_time(launch):
+    """A lone submitter after bursts of 3 waits a quarter of the launch
+    time the flusher measured and never less than the floor: 10 ms (the
+    floor) at a 1 ms launch, 50 ms at 200 ms, 100 ms at 400 ms."""
+    v, engine = _learned(3, launch=launch, window=0.01)
+    before = FLUSHER.snapshot()
+    assert v.verify_batch(*_wave(10)).all()
+    v.close()
+    got = _since(before)
+    cap = max(0.01, 0.25 * launch) * 1e9
+    assert cap <= got["wave.wait_window"] < cap + 40 * MS
+    # Under the floor there is nothing to hold for: no hold is counted.
+    assert got["hold_expired"] == (1 if launch > 0.04 else 0)
+    assert got["hold_met"] == 0
+
+
+@pytest.mark.parametrize("k, size, hard_cap, flushes", [
+    (4, 40, 100, [80, 80]), (5, 10, 30, [30, 20])])
+def test_a_burst_that_cannot_fit_hard_cap_flushes_without_holding(
+        k, size, hard_cap, flushes):
+    """Bursts the flusher has learned to expect, of which hard_cap holds
+    only 2 (or 3) submissions: with that many pending the next is not waited
+    for, and what the launch left behind goes with the floor."""
+    v, engine = _learned(k, launch=0.4, gap=0.005, hard_cap=hard_cap, size=size)
+    assert max(engine.calls) <= hard_cap
+    n = len(engine.calls)
+    before = FLUSHER.snapshot()
+    _burst(v, k, 0.005, size=size)
+    v.close()
+    got = _since(before)
+    assert engine.calls[n:] == flushes
+    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+    # The head waited for its neighbours (5 ms each), the tail not at all:
+    # neither the 100 ms a hold could have lasted.
+    assert got["wave.wait_window"] < 60 * MS
+
+
+@pytest.mark.parametrize("queued, floor_waits", [(3, 0), (1, 1)])
+def test_submissions_queued_during_a_launch_never_wait_for_a_hold(
+        queued, floor_waits):
+    """Expecting bursts of 3, with a 50 ms floor and a 600 ms launch (a hold
+    may last 150 ms): what queues while a launch runs goes at once on its
+    return if the expected burst is there, and with the floor if not."""
+    v, engine = _learned(3, launch=0.6, window=0.05)
+    n = len(engine.calls)
+    before = FLUSHER.snapshot()
+    head = threading.Thread(target=_burst, args=(v, 3, 0.0))
+    head.start()
+    time.sleep(0.3)  # the head's launch is running
+    _burst(v, queued, 0.0)
+    head.join(timeout=10.0)
+    assert not head.is_alive()
+    v.close()
+    got = _since(before)
+    assert engine.calls[n:] == [30, 10 * queued]
+    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+    # The head was all there within the floor and went at once, too.
+    waited = floor_waits * 50 * MS
+    assert waited <= got["wave.wait_window"] < waited + 45 * MS
+
+
+def test_close_ends_a_hold_at_once():
+    v, engine = _learned(3, launch=0.6, window=0.01)
+    n = len(engine.calls)
+    before = FLUSHER.snapshot()
+    lone = threading.Thread(target=_burst, args=(v, 1, 0.0))
+    lone.start()
+    time.sleep(0.04)  # held: 150 ms is what the hold may last
+    v.close()
+    lone.join(timeout=10.0)
+    got = _since(before)
+    assert not lone.is_alive() and not v._thread.is_alive()
+    assert engine.calls[n:] == [10]  # served all the same
+    assert 35 * MS <= got["wave.wait_window"] < 110 * MS
+    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+
+
+def test_the_phases_close_the_threads_life_with_holds_in_it():
+    """Held time is ``wave.wait_window``'s: with a hold that was met and
+    one that ran out, the wave phases and the engine call still account
+    for the thread's life."""
+    before = FLUSHER.snapshot()
+    t_born = time.monotonic_ns()
+    v, engine = _learned(4, launch=0.4, gap=0.005)
+    assert v.verify_batch(*_wave(10)).all()  # lone: its hold runs out
+    v.close()
+    lifetime = time.monotonic_ns() - t_born
+    assert not v._thread.is_alive()
+    got = _since(before)
+    assert got["hold_met"] >= 1 and got["hold_expired"] == 1
+    assert got["wave.wait_window"] >= (15 + 100) * MS
+    accounted = sum(got[name] for name in WAVE) + got["engine_ns"]
+    assert 0.98 * lifetime <= accounted <= lifetime, (accounted, lifetime)
+
+
 def test_a_flush_that_raises_is_still_counted_and_served_from_the_host():
     class Boom:
         def verify_batch(self, msgs, sigs, keys):
@@ -223,7 +403,7 @@ def test_phase_cpu_tells_a_thread_that_computes_from_one_that_waits():
 
 def test_the_ledger_has_a_fixed_set_of_keys_and_loses_no_update():
     assert set(FLUSHER.snapshot()) == set(FLUSHER_PHASES + FLUSHER_COUNTERS)
-    assert len(set(FLUSHER_PHASES + FLUSHER_COUNTERS)) == 17
+    assert len(set(FLUSHER_PHASES + FLUSHER_COUNTERS)) == 19
     ledger = PhaseLedger(("a", "b"))
     with pytest.raises(KeyError):
         ledger.add("c", 1)  # a name nobody declared is a bug, not a new key

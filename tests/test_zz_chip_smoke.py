@@ -93,7 +93,8 @@ def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
     """``health["flusher"]``: every phase and counter of obs/kernels.py, as
     integers, cumulative (the warm-up wave is in the first reading already)
     and never decreasing; across the traffic every one of them moved but the
-    fill buckets no flush fell into, and the launches the kernel ledger
+    fill buckets no flush fell into and the two counts of held flushes
+    (never more of those than flushes), and the launches the kernel ledger
     counts are the flushes the coalescer counts."""
     from consensus_tpu.obs.kernels import FLUSHER_COUNTERS, FLUSHER_PHASES
 
@@ -106,12 +107,15 @@ def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
     assert first["flushes"] == 1 == first["fill_le_25"]  # the warm-up wave
     assert first["verify.dispatch"] > 0  # ... which compiled inside dispatch
     buckets = [k for k in first if k.startswith("fill_le_")]
-    assert all(last[k] > first[k] for k in first if k not in buckets)
+    holds = ["hold_met", "hold_expired"]  # only a learned burst is held for
+    assert set(holds) < set(first)
+    assert all(last[k] > first[k] for k in first if k not in buckets + holds)
     flushes = last["flushes"] - first["flushes"]
     assert flushes == sum(last[k] - first[k] for k in buckets)
     assert flushes == (health[1]["launches_after_ready"]
                        - health[0]["launches_after_ready"])
     assert last["submissions"] - first["submissions"] >= flushes
+    assert sum(last[k] - first[k] for k in holds) <= flushes
     # The engine call is its four phases and little else.
     inside = sum(last[k] - first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
     engine = last["engine_ns"] - first["engine_ns"]
