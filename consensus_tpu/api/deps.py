@@ -318,9 +318,12 @@ class RequestInspector(abc.ABC):
 
 class Synchronizer(abc.ABC):
     """Application-level catch-up: fetch and deliver decided proposals from
-    peers, returning the latest decision reached.
+    peers, returning the latest decision reached and, in
+    ``SyncResponse.synced``, every decision this call added to the ledger
+    (the controller removes their requests from its pool).
 
-    Parity: reference pkg/api/dependencies.go:86-90.
+    Parity: reference pkg/api/dependencies.go:86-90; ``synced`` stands in for
+    the pool pruning the reference leaves to the embedder's commit hook.
     """
 
     @abc.abstractmethod

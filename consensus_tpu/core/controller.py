@@ -16,6 +16,10 @@ deviations, all consequences of the single-threaded runtime:
   ``_propose`` continuation; the batcher hands batches back via callback.
 * ``sync()`` (controller.go:576-680) becomes a state-machine step chain:
   synchronizer → state-fetch window (collector callback) → view math.
+* What a sync brought into the ledger leaves the pool here
+  (``_forget_synced``; the reference leaves it to the embedder), and
+  three-phase traffic ahead of a view that is replaced — by a rotation or a
+  sync — is handed to its successor (``_keep_ahead``; the reference drops it).
 """
 
 from __future__ import annotations
@@ -41,7 +45,14 @@ from consensus_tpu.core.view import Phase, View
 from consensus_tpu.metrics import Metrics
 from consensus_tpu.runtime.scheduler import Scheduler
 from consensus_tpu.trace.tracer import NOOP_TRACER
-from consensus_tpu.types import Checkpoint, Proposal, Reconfig, RequestInfo, Signature
+from consensus_tpu.types import (
+    Checkpoint,
+    Proposal,
+    Reconfig,
+    RequestInfo,
+    Signature,
+    SyncResponse,
+)
 from consensus_tpu.utils.leader import get_leader_id
 from consensus_tpu.utils.quorum import compute_quorum
 from consensus_tpu.wire import (
@@ -63,6 +74,10 @@ from consensus_tpu.wire import (
 )
 
 logger = logging.getLogger("consensus_tpu.controller")
+
+#: How far past the running view's sequence three-phase messages are kept for
+#: the view that replaces it (``Controller._keep_ahead``).
+_AHEAD_WINDOW = 8
 
 #: TEST-ONLY seeded bug: when True, a replica IGNORES a decision that carried
 #: a reconfiguration — no rebuild, no eviction, no epoch advance — so the
@@ -175,6 +190,19 @@ class Controller:
         self._fence_release: Optional[int] = None
         self._fence_resync_timer = None
         self._wal_degraded = False
+        #: Cumulative, for ``health``: calls of the synchronizer, decisions
+        #: they brought, requests of those decisions taken out of the pool,
+        #: and rotations of the leader (a reconfiguration builds a new
+        #: controller, which counts from 0 again).
+        #: seq -> [(sender, message)] in arrival order: three-phase traffic
+        #: the running view could not take (it was stopped for a sync, or the
+        #: message is for a later sequence), kept for the view that replaces
+        #: it.  See :meth:`_keep_ahead`.
+        self._ahead: dict[int, list] = {}
+        self.syncs = 0
+        self.synced_decisions = 0
+        self.sync_pool_removed = 0
+        self.leader_handovers = 0
 
     # ------------------------------------------------------------ identity
 
@@ -232,6 +260,10 @@ class Controller:
             "epoch": self.membership_epoch,
             "fenced": self.fence_required(),
             "wal_degraded": self._wal_degraded,
+            "syncs": self.syncs,
+            "synced_decisions": self.synced_decisions,
+            "sync_pool_removed": self.sync_pool_removed,
+            "leader_handovers": self.leader_handovers,
         }
 
     # ----------------------------------------------------------- lifecycle
@@ -294,6 +326,11 @@ class Controller:
         )
         self.curr_view = view
         view.start()
+        if self._ahead:
+            # After this step, in order with whatever the network queued.
+            self._sched.post(
+                lambda: self._replay_ahead(view), name="controller-replay-ahead"
+            )
         if self.i_am_the_leader():
             if init_phase in (Phase.COMMITTED, Phase.ABORT):
                 self._acquire_leader_token()
@@ -375,6 +412,7 @@ class Controller:
                         sender, HeartBeat(view=msg.view, seq=msg.seq)
                     )
                 return
+            self._keep_ahead(sender, msg)
             if self.curr_view is not None:
                 self.curr_view.handle_message(sender, msg)
             if self.view_changer is not None:
@@ -406,6 +444,66 @@ class Controller:
             self.collector.handle_response(sender, msg)
         else:
             logger.warning("%d: unknown message %s from %d", self.id, msg, sender)
+
+    def _keep_ahead(self, sender: int, msg) -> None:
+        """Keep three-phase traffic the running view cannot use for the view
+        that replaces it, and hand it over when that view starts.
+
+        A View dies with everything it buffered: at every rotation, and at
+        every sync.  So the next leader's first pre-prepare, when it arrives
+        before this replica decided the turn's last sequence, was dropped
+        (it is not from the running view's leader), and a replica whose sync
+        ended while the cluster was mid-decision started its view without
+        that decision's pre-prepare, could not decide it, fell two sequences
+        behind and synced again; if its own turn to lead came first, nobody
+        proposed, no vote told it, and the cluster waited out the request
+        and heartbeat timers for a view change.  Holding the few messages
+        ahead of the view and replaying them into its successor is a delay
+        the network could have imposed itself: the new View checks each as
+        if it had just arrived.  Bounded: sequences within ``_AHEAD_WINDOW``
+        of the view's, 4n messages a sequence.
+
+        Parity: none — the reference loses them the same way and leans on
+        the heartbeat timeout."""
+        view = self.curr_view
+        if view is None:
+            return
+        if not view.stopped and not self._config.leader_rotation:
+            # A static leader's view lives on from decision to decision and
+            # buffers its next sequence itself: nothing would be lost.
+            return
+        here = view.proposal_sequence
+        # A stopped view takes nothing: keep its own sequence's traffic too.
+        lowest = here if view.stopped else here + 1
+        if not lowest <= msg.seq <= here + _AHEAD_WINDOW:
+            return
+        for seq in [seq for seq in self._ahead if seq < lowest]:
+            del self._ahead[seq]
+        kept = self._ahead.setdefault(msg.seq, [])
+        if len(kept) < 4 * self.n:
+            kept.append((sender, msg))
+
+    def _replay_ahead(self, view: View) -> None:
+        """Hand the new view what arrived for its sequence and the next one
+        before it existed (see :meth:`_keep_ahead`)."""
+        if view is not self.curr_view or view.stopped or self._voting_suspended():
+            return
+        here = view.proposal_sequence
+        replayed = 0
+        for seq in (here, here + 1):
+            for sender, msg in list(self._ahead.get(seq, ())):
+                if msg.view == view.number and not view.stopped:
+                    view.handle_message(sender, msg)
+                    replayed += 1
+        # The next sequence's stay kept: the view after this one (a rotation
+        # away) will want them again.
+        for seq in [seq for seq in self._ahead if seq <= here]:
+            del self._ahead[seq]
+        if replayed:
+            logger.info(
+                "%d: replayed %d message(s) kept for seq %d-%d into the new view",
+                self.id, replayed, here, here + 1,
+            )
 
     # --------------------------------------------------------- requests
 
@@ -682,6 +780,15 @@ class Controller:
         )
         if self._check_if_rotate(md.black_list):
             logger.info("%d: rotating leader after seq %d", self.id, md.latest_sequence)
+            self.leader_handovers += 1
+            if self._tracer.enabled:
+                self._tracer.instant(
+                    "controller",
+                    "rotate",
+                    seq=md.latest_sequence,
+                    view=self.curr_view_number,
+                    leader=self.leader_id(),
+                )
             self.change_view(
                 self.curr_view_number, md.latest_sequence + 1, self.curr_decisions_in_view
             )
@@ -704,6 +811,7 @@ class Controller:
                 self.id, md.latest_sequence, latest,
             )
             response = self._synchronizer.sync()
+            self._forget_synced(response)
             if response.latest is not None:
                 self.checkpoint.set(
                     response.latest.proposal, response.latest.signatures
@@ -825,6 +933,7 @@ class Controller:
         response = self._synchronizer.sync()
         if self._tracer.enabled:
             self._tracer.end("controller", "sync")
+        self._forget_synced(response)
         if response.reconfig.in_latest_decision:
             self._sync_in_progress = False
             self._reconfig_pending = True
@@ -847,6 +956,15 @@ class Controller:
                 "%d: sync advanced us to seq %d (was %d)",
                 self.id, latest_md.latest_sequence, controller_seq,
             )
+            unreported = (
+                latest_md.latest_sequence - controller_seq - len(response.synced)
+            )
+            if unreported > 0:
+                logger.warning(
+                    "%d: the synchronizer advanced %d decision(s) it did not "
+                    "report in SyncResponse.synced; their requests stay pooled",
+                    self.id, unreported,
+                )
             self.checkpoint.set(latest.proposal, latest.signatures)
             self._verification_sequence = latest.proposal.verification_sequence
             new_seq = latest_md.latest_sequence + 1
@@ -915,6 +1033,52 @@ class Controller:
 
         self.collector.begin(on_state)
         self.broadcast(StateTransferRequest())
+
+    def _forget_synced(self, response: SyncResponse) -> None:
+        """Take the requests of every decision a sync brought into the ledger
+        out of the pool, as :meth:`decide` does for the one it delivers.
+
+        Without this a replica that caught up by sync still pools what the
+        cluster delivered without it; with leader rotation it leads within
+        ``decisions_per_leader * (n - 1)`` decisions, proposes those requests
+        again, and — no follower holds a proposal against the ledger — every
+        replica delivers them twice.  Runs in the same step as the
+        synchronizer's return, so nothing is sealed or accepted in between,
+        and goes through ``pool.remove_requests``: the identities are
+        remembered as deleted for the pool's retention horizon, so a copy
+        still on its way (a listener that was paused) is refused as well.
+
+        Parity: the reference leaves this to the embedder — Fabric's orderer
+        prunes the pool from its synchronizer's per-block commit hook; here
+        ``SyncResponse.synced`` carries the blocks to the pool's owner."""
+        self.syncs += 1
+        if not response.synced:
+            return
+        tracing = self._tracer.enabled
+        if tracing:
+            self._tracer.begin(
+                "controller", "sync.forget", decisions=len(response.synced)
+            )
+        infos: list[RequestInfo] = []
+        for decision in response.synced:
+            try:
+                infos.extend(self._verifier.requests_from_proposal(decision.proposal))
+            except Exception:
+                logger.exception(
+                    "%d: could not read the requests of a synced proposal; "
+                    "they stay pooled", self.id,
+                )
+        removed = self.pool.remove_requests(infos)
+        self.synced_decisions += len(response.synced)
+        self.sync_pool_removed += removed
+        logger.info(
+            "%d: sync brought %d decision(s); %d of their %d request(s) left the pool",
+            self.id, len(response.synced), removed, len(infos),
+        )
+        if tracing:
+            self._tracer.end(
+                "controller", "sync.forget", removed=removed, requests=len(infos)
+            )
 
     def _finish_sync(
         self,

@@ -136,6 +136,7 @@ class LedgerSynchronizer(Synchronizer):
 
     def sync(self) -> SyncResponse:
         begin = self._now()
+        height_before = self.store.height()
         reconfig = Reconfig()
         banned: Set[int] = set()  # served-forged-data, this call
         failures: Dict[int, int] = {}
@@ -221,8 +222,12 @@ class LedgerSynchronizer(Synchronizer):
                 plan.crash("sync.client.chunk_boundary")
 
         self.metrics.latency_catchup.observe(self._now() - begin)
-        latest = self.store.last()
-        return SyncResponse(latest=latest, reconfig=reconfig)
+        # What this call appended (whole chunks, in chain order): the
+        # controller removes these decisions' requests from its pool.
+        synced = tuple(self.store.read(height_before + 1, self.store.height()))
+        return SyncResponse(
+            latest=self.store.last(), reconfig=reconfig, synced=synced
+        )
 
     # --- verification ------------------------------------------------------
 

@@ -296,14 +296,15 @@ class TestApp(Application, Assembler, Signer, Verifier, Synchronizer):
         best = self.cluster.longest_ledger(exclude=self.node_id)
         mine = len(self.ledger)
         reconfig = Reconfig()
-        for decision in best[mine:]:
+        synced = tuple(best[mine:])
+        for decision in synced:
             self.ledger.append(decision)
             r = self.cluster.reconfig_of(decision.proposal)
             if r.in_latest_decision:
                 reconfig = r
         if not self.ledger:
             return SyncResponse(latest=None, reconfig=reconfig)
-        return SyncResponse(latest=self.ledger[-1], reconfig=reconfig)
+        return SyncResponse(latest=self.ledger[-1], reconfig=reconfig, synced=synced)
 
 
 class Node:

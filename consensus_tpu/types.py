@@ -174,13 +174,26 @@ class Reconfig:
 
 @dataclass(frozen=True)
 class SyncResponse:
-    """Result of Synchronizer.sync(): the latest decision plus any reconfig.
+    """Result of Synchronizer.sync(): the latest decision, any reconfig, and
+    every decision THIS call added to the ledger.
 
-    Parity: reference pkg/types/types.go:113-116.
+    Parity: reference pkg/types/types.go:113-116, widened by ``synced``.  The
+    reference leaves the request pool to the embedder: Fabric's orderer prunes
+    it from its synchronizer's per-block commit hook through the pool the
+    library exposes.  Here the controller owns the pool, so the synchronizer
+    says what it fetched and the controller removes those decisions' requests
+    (``Controller._forget_synced``) before it seals or accepts anything else:
+    a replica that caught up by sync and then leads must not propose what the
+    cluster already delivered.  A synchronizer that advances the ledger and
+    leaves ``synced`` empty breaks exactly-once delivery under leader
+    rotation; the controller logs it.
     """
 
     latest: Optional[Decision] = None
     reconfig: Reconfig = field(default_factory=Reconfig)
+    #: The decisions this call appended to the ledger, oldest first (empty
+    #: when it fetched nothing).
+    synced: tuple[Decision, ...] = ()
 
 
 @dataclass(frozen=True)

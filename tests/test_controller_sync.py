@@ -14,7 +14,7 @@ from consensus_tpu.core.pool import PoolOptions, RequestPool
 from consensus_tpu.core.state import InFlightData, PersistedState, ProposalMaker
 from consensus_tpu.runtime import SimScheduler
 from consensus_tpu.testing import MemWAL
-from consensus_tpu.testing.app import ByteInspector
+from consensus_tpu.testing.app import ByteInspector, pack_batch
 from consensus_tpu.testing.app import TestApp as PortsApp
 from consensus_tpu.types import Checkpoint, Decision, Proposal, Reconfig, SyncResponse
 from consensus_tpu.wire import (
@@ -28,9 +28,12 @@ from consensus_tpu.wire import (
 NODES = (1, 2, 3, 4)
 
 
-def proposal_at(view, seq, decisions=0):
+def proposal_at(view, seq, decisions=0, requests=None):
+    """A decided proposal's shell; with ``requests`` its payload is a real
+    batch, otherwise a placeholder no verifier can unpack."""
     md = ViewMetadata(view_id=view, latest_sequence=seq, decisions_in_view=decisions)
-    return Proposal(payload=b"p%d" % seq, metadata=encode_view_metadata(md))
+    payload = b"p%d" % seq if requests is None else pack_batch(requests)
+    return Proposal(payload=payload, metadata=encode_view_metadata(md))
 
 
 class ScriptedSynchronizer:
@@ -554,3 +557,192 @@ def test_stray_state_response_without_sync_is_ignored():
     assert h.controller.curr_view is before
     assert h.controller.curr_view_number == 0
     assert h.vc.informed == []
+
+
+# --- what a sync brought into the ledger leaves the pool -------------------
+#
+# A replica that caught up by sync still pools the requests of the decisions
+# it skipped; with leader rotation it soon leads and would propose them
+# again (tests/test_sync_then_lead.py has the whole scenario).  Both sync
+# entries hand SyncResponse.synced to Controller._forget_synced.
+
+def scripted_catch_up(h, first_seq, k, per_decision=3):
+    """Pool the requests of ``k`` decisions from ``first_seq`` on plus two
+    nobody ordered yet, and script a sync that brings those decisions."""
+    requests = [make_request("cli", i) for i in range(k * per_decision + 2)]
+    for raw in requests:
+        h.controller.pool.submit(raw)
+    synced = tuple(
+        Decision(proposal=proposal_at(
+            0, first_seq + j, first_seq + j - 1,
+            requests[j * per_decision:(j + 1) * per_decision]))
+        for j in range(k)
+    )
+    h.synchronizer.response = SyncResponse(
+        latest=synced[-1] if synced else None, synced=synced)
+    return requests[:k * per_decision], requests[k * per_decision:]
+
+
+def enter_do_sync(h):
+    h.controller.sync()
+    h.sched.advance(0.05)
+
+
+def enter_deliver_checked(h):
+    proposal, signatures = h.checkpoint.get()
+    h.controller.deliver(proposal, signatures)
+
+
+SYNC_ENTRIES = [("do_sync", enter_do_sync), ("deliver_checked", enter_deliver_checked)]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize(
+    "enter", [e[1] for e in SYNC_ENTRIES], ids=[e[0] for e in SYNC_ENTRIES])
+def test_sync_removes_the_synced_decisions_requests_from_the_pool(enter, k):
+    h = Harness()
+    h.checkpoint.set(proposal_at(view=0, seq=5, decisions=4), ())
+    h.start(view=0, seq=6, dec=5)
+    ordered, waiting = scripted_catch_up(h, first_seq=6, k=k)
+    assert h.controller.pool.count == len(ordered) + len(waiting)
+
+    enter(h)
+
+    # At once: before the state fetch ends, before any view is started.
+    assert h.controller.latest_seq() == 5 + k
+    assert h.controller.pool.next_requests(100, 10**6) == waiting
+    refused = []
+    for raw in ordered:
+        h.controller.pool.submit(raw, refused.append)
+    assert refused == ["request already exists"] * len(ordered)
+    health = h.controller.health()
+    assert (health["syncs"], health["synced_decisions"],
+            health["sync_pool_removed"]) == (1, k, len(ordered))
+
+
+@pytest.mark.parametrize(
+    "enter", [e[1] for e in SYNC_ENTRIES], ids=[e[0] for e in SYNC_ENTRIES])
+def test_sync_that_advanced_nothing_removes_nothing(enter):
+    h = Harness()
+    h.checkpoint.set(proposal_at(view=0, seq=5, decisions=4), ())
+    h.start(view=0, seq=6, dec=5)
+    _, waiting = scripted_catch_up(h, first_seq=6, k=0)
+    h.synchronizer.response = SyncResponse(
+        latest=Decision(proposal=proposal_at(view=0, seq=5, decisions=4)))
+
+    enter(h)
+
+    assert h.controller.latest_seq() == 5
+    assert h.controller.pool.next_requests(100, 10**6) == waiting
+    health = h.controller.health()
+    assert (health["syncs"], health["synced_decisions"],
+            health["sync_pool_removed"]) == (1, 0, 0)
+
+
+def test_synced_request_that_was_never_pooled_is_refused_when_it_arrives():
+    # The listener was paused: the client's copy is still on its way when the
+    # sync brings the decision that ordered it.
+    h = Harness()
+    h.start()
+    late = make_request("cli", 77)
+    brought = Decision(proposal=proposal_at(0, 1, requests=[late]))
+    h.synchronizer.response = SyncResponse(latest=brought, synced=(brought,))
+    enter_do_sync(h)
+    refused = []
+    h.controller.pool.submit(late, refused.append)
+    assert refused == ["request already exists"]
+    assert h.controller.health()["sync_pool_removed"] == 0
+
+
+def test_unreadable_synced_proposal_keeps_the_others_out_of_the_pool(caplog):
+    h = Harness()
+    h.start()
+    good = [make_request("cli", 1), make_request("cli", 2)]
+    for raw in good:
+        h.controller.pool.submit(raw)
+    readable = Decision(proposal=proposal_at(0, 2, 1, requests=good))
+    h.synchronizer.response = SyncResponse(
+        latest=readable,
+        synced=(Decision(proposal=proposal_at(view=0, seq=1)), readable))  # 1: no batch
+    enter_do_sync(h)
+    assert h.controller.pool.count == 0
+    assert h.controller.health()["synced_decisions"] == 2
+
+
+def test_synchronizer_that_does_not_report_what_it_fetched_is_logged(caplog):
+    import logging
+
+    h = Harness()
+    h.start()
+    h.synchronizer.response = SyncResponse(
+        latest=Decision(proposal=proposal_at(view=0, seq=3, decisions=2)))
+    with caplog.at_level(logging.WARNING, logger="consensus_tpu.controller"):
+        enter_do_sync(h)
+    assert any("did not report" in r.getMessage() for r in caplog.records)
+
+
+def test_rotation_is_counted_in_health():
+    h = Harness()
+    h.cfg = Configuration(
+        self_id=2, leader_rotation=True, decisions_per_leader=1, collect_timeout=1.0)
+    h.controller._config = h.cfg
+    h.start()
+    assert h.controller.health()["leader_handovers"] == 0
+    h.controller.decide(proposal_at(0, 1, requests=[make_request("cli", 1)]), (), ())
+    assert h.controller.health()["leader_handovers"] == 1
+
+
+# --- three-phase traffic ahead of the view is kept for its successor ------
+
+
+def _commit(seq, sender):
+    return Commit(view=0, seq=seq, digest="d", signature=Signature(id=sender, value=b"s"))
+
+
+def test_keep_ahead_is_bounded_by_window_and_per_sequence_cap():
+    from consensus_tpu.core.controller import _AHEAD_WINDOW
+
+    h = Harness()
+    h.start(view=0, seq=10, dec=9)
+    # A static leader's running view buffers its next sequence itself.
+    h.controller.process_message(3, _commit(11, 3))
+    assert h.controller._ahead == {}
+    h.controller._config = Configuration(
+        self_id=2, leader_rotation=True, decisions_per_leader=3, collect_timeout=1.0)
+    # With rotation the view is replaced every few decisions.  It takes its
+    # own sequence: only later ones are kept.
+    for seq in (9, 10, 11, 10 + _AHEAD_WINDOW, 11 + _AHEAD_WINDOW, 10**9):
+        h.controller.process_message(3, _commit(seq, 3))
+    assert sorted(h.controller._ahead) == [11, 10 + _AHEAD_WINDOW]
+    for _ in range(100):  # one sender repeating itself cannot grow a bucket
+        h.controller.process_message(4, _commit(11, 4))
+    assert len(h.controller._ahead[11]) == 4 * len(NODES)
+    # A stopped view (a sync is out) keeps its own sequence's traffic too.
+    h.controller.curr_view.abort()
+    h.controller.process_message(1, Prepare(view=0, seq=10, digest="d"))
+    assert 10 in h.controller._ahead
+
+
+def test_kept_messages_reach_the_view_that_replaces_the_stopped_one():
+    h = Harness()
+    h.start(view=0, seq=10, dec=9)
+    h.controller.curr_view.abort()  # as _discover_if_sync_needed does
+    early = [(1, PrePrepare(view=0, seq=12, proposal=proposal_at(0, 12, 11))),
+             (3, Prepare(view=0, seq=12, digest="d")),
+             (4, _commit(13, 4)),
+             (3, Prepare(view=1, seq=12, digest="other-view"))]
+    for sender, msg in early:
+        h.controller.process_message(sender, msg)
+    seen = []
+    h.controller.change_view(0, 12, 11)  # the sync ended at 11
+    h.controller.curr_view.handle_message = lambda s, m: seen.append((s, m))
+    h.sched.advance(0.01)
+    assert seen == early[:3]  # its sequence and the next, this view's only
+    assert sorted(h.controller._ahead) == [13]  # kept for the view after it
+    # Nothing is replayed into a view that was itself replaced meanwhile.
+    h.controller.change_view(0, 13, 12)
+    stale = h.controller.curr_view
+    h.controller.change_view(0, 13, 0)
+    stale.handle_message = lambda s, m: seen.append("stale")
+    h.sched.advance(0.01)
+    assert "stale" not in seen

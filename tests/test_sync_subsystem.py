@@ -390,3 +390,82 @@ def test_tcp_listener_rejects_garbage_and_keeps_serving():
         assert isinstance(reply, SyncSnapshotMeta) and reply.height == 3
     finally:
         listener.close()
+
+
+# --- what a sync appended is reported, and leaves the controller's pool ------
+#
+# SyncResponse.synced carries every decision this call applied through
+# _verify_and_apply, in chain order; Controller._forget_synced removes their
+# requests from the pool (tests/test_sync_then_lead.py: why).
+
+import pytest
+
+
+@pytest.mark.parametrize(
+    "have,total", [(0, 50), (12, 20), (7, 7), (0, 1)],
+    ids=["empty-50", "tail-8", "current", "one"])
+def test_sync_reports_exactly_the_decisions_it_appended(have, total):
+    chain = build_chain(total)
+    _, transport = _wire_setup(chain)
+    ledger = list(chain[:have])
+    response = _client(LedgerDecisionStore(ledger), transport).sync()
+    assert [d.proposal.digest() for d in response.synced] == [
+        d.proposal.digest() for d in chain[have:]
+    ]
+    assert list(response.synced) == ledger[have:]
+    # a second call has nothing left to report
+    assert _client(LedgerDecisionStore(ledger), transport).sync().synced == ()
+
+
+@pytest.mark.parametrize(
+    "server_cls", [ForgingServer, OmittingServer, UndersignedServer],
+    ids=["forged", "omitted", "undersigned"])
+def test_rejected_chunks_are_not_reported_as_synced(server_cls):
+    chain = build_chain(40)
+    _, transport = _wire_setup(chain, server_cls=server_cls, byzantine_peer=1)
+    ledger = []
+    response = _client(LedgerDecisionStore(ledger), transport).sync()
+    assert list(response.synced) == ledger and len(ledger) == 40
+    assert [d.proposal.digest() for d in response.synced] == [
+        d.proposal.digest() for d in chain
+    ]
+    # every peer byzantine: nothing applied, nothing reported
+    servers = {p: server_cls(LedgerDecisionStore(list(chain))) for p in (1, 3, 4)}
+    only_bad = InProcessSyncTransport(2, _OpenNetwork(), servers)
+    assert _client(LedgerDecisionStore([]), only_bad).sync().synced == ()
+
+
+@pytest.mark.parametrize("k", [0, 1, 40])
+@pytest.mark.parametrize("entry", ["do_sync", "deliver_checked"])
+def test_controller_pool_forgets_what_the_ledger_synchronizer_fetched(entry, k):
+    from test_controller_sync import Harness
+
+    have = 3
+    chain = build_chain(have + k)
+    _, transport = _wire_setup(chain)
+    h = Harness()
+    h.app.ledger.extend(chain[:have])
+    h.checkpoint.set(chain[have - 1].proposal, chain[have - 1].signatures)
+    h.controller._synchronizer = _client(LedgerDecisionStore(h.app.ledger), transport)
+    h.start(view=0, seq=have + 1, dec=have + 1)
+    fetched = [make_request("chain", seq) for seq in range(have + 1, have + k + 1)]
+    waiting = [make_request("later", 1), make_request("later", 2)]
+    for raw in fetched + waiting:
+        h.controller.pool.submit(raw)
+
+    if entry == "do_sync":
+        h.controller.sync()
+        h.sched.advance(0.05)
+    else:
+        h.controller.deliver(chain[have - 1].proposal, chain[have - 1].signatures)
+
+    assert len(h.app.ledger) == have + k
+    assert h.controller.latest_seq() == have + k
+    assert h.controller.pool.next_requests(100, 10**6) == waiting
+    refused = []
+    for raw in fetched:
+        h.controller.pool.submit(raw, refused.append)
+    assert refused == ["request already exists"] * k
+    health = h.controller.health()
+    assert (health["syncs"], health["synced_decisions"],
+            health["sync_pool_removed"]) == (1, k, k)

@@ -362,3 +362,57 @@ def test_net_injected_event_keys_pinned_and_mirror_trace_instants():
     for kind in INJECTED_EVENT_KINDS:
         assert dump[f"net_injected_{kind}"]["value"] == net.injected[kind]
         assert instants[f"net.{kind}"] == net.injected[kind]
+
+
+# --- rotation and the pool removal after a sync show in a decision trace ---
+
+
+def test_rotation_instants_and_sync_forget_span_in_a_decision_trace():
+    """With the source's rotation (every 3 decisions) each hand-over is a
+    ``controller`` / ``rotate`` instant naming the decision it followed and
+    the next leader; a replica that catches up by sync brackets the removal
+    of the synced requests from its pool in ``controller`` / ``sync.forget``,
+    inside neither ``sync`` (the synchronizer's call) nor a decision."""
+    tweaks = _traced_tweaks(decisions_per_leader=3)
+    cluster = Cluster(4, seed=11, leader_rotation=True, config_tweaks=tweaks)
+    cluster.start()
+
+    def decide(i, node_ids=None):
+        height = len(cluster.nodes[1].app.ledger)
+        cluster.submit_to_all(make_request("rot", i))
+        assert cluster.run_until_ledger(height + 1, node_ids=node_ids)
+
+    for i in range(12):  # the lagger's first turn (decisions 10-12) is over
+        decide(i)
+    lagger = cluster.nodes[4]
+    cluster.network.disconnect(4)
+    for i in range(12, 16):
+        decide(i, node_ids=[1, 2, 3])
+    cluster.network.connect(4)
+    decide(16, node_ids=[1, 2, 3])
+    assert cluster.scheduler.run_until(
+        lambda: len(lagger.app.ledger) == 17, max_time=30.0)
+
+    leader_events = [
+        ev for ev in cluster.nodes[1].consensus.tracer.events()
+        if ev[0] == "i" and ev[1] == "controller" and ev[2] == "rotate"
+    ]
+    # one hand-over after every third decision: 3 -> node 2, 6 -> 3, 9 -> 4, ...
+    assert [(ev[4], ev[6]["leader"]) for ev in leader_events] == [
+        (3, 2), (6, 3), (9, 4), (12, 1), (15, 2)]
+    assert cluster.nodes[1].consensus.controller.health()["leader_handovers"] == 5
+
+    events = lagger.consensus.tracer.events()
+    forget = [ev for ev in events if ev[1] == "controller" and ev[2] == "sync.forget"]
+    assert [ev[0] for ev in forget] == ["B", "E"]
+    assert forget[0][6] == {"decisions": 5}
+    assert forget[1][6] == {"removed": 5, "requests": 5}
+    order = [(ev[0], ev[2]) for ev in events
+             if ev[1] == "controller" and ev[2] in ("sync", "sync.forget")]
+    assert order == [("B", "sync"), ("E", "sync"),
+                     ("B", "sync.forget"), ("E", "sync.forget")]
+    health = lagger.consensus.controller.health()
+    assert (health["syncs"], health["synced_decisions"],
+            health["sync_pool_removed"]) == (1, 5, 5)
+    # the span streams still export
+    assert json.loads(to_chrome_json(events))["traceEvents"]

@@ -101,6 +101,14 @@ def test_cluster_smoke_orders_decisions(tmp_path):
         launcher.observe_invariants()
         launcher.monitor.assert_clean()
         assert len(launcher.monitor.agreed) >= 20
+        # The sync and hand-over counters ride every replica's health
+        # (cumulative; a static leader that nobody fell behind reads 0).
+        for i in spec.node_ids():
+            h = launcher.replicas[i].probe()
+            assert {"syncs", "synced_decisions", "sync_pool_removed",
+                    "leader_handovers"} <= set(h), sorted(h)
+            assert h["synced_decisions"] >= 0 and h["sync_pool_removed"] >= 0
+            assert h["leader_handovers"] == 0
         # The obs plane scrapes every replica over its control socket.
         bodies = launcher.scrape()
         assert set(bodies) == {f"replica-{i}" for i in spec.node_ids()}
