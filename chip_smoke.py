@@ -779,8 +779,8 @@ def main(argv=None) -> int:
         "phase_wall_secs": {
             r.get("lane") or r["phase"]: r.get("wall_secs") for r in records
         },
-        # Set-up seconds, not rates.  Sidecar: its warm-up wave, keyed by the
-        # launch shape.  Lanes: first call minus the same wave repeated
+        # Set-up seconds, not rates.  Sidecar: its warm-up waves (both launch
+        # widths), keyed by the full width.  Lanes: first call minus the same wave repeated
         # (half-agg lanes report their first call only).
         "cold_compile_secs": {
             **((served or {}).get("phases", {}).get("rig", {})

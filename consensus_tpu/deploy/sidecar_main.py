@@ -15,12 +15,21 @@ Boot order is the contract ``launcher.start`` relies on:
    non-zero exit — a sidecar that silently served from the host would let
    replicas commit happily while the chip did nothing;
 2. build the engine the spec selects (``engine_for_config`` over
-   ``spec.make_configuration``) behind the thread coalescer, padded to the
-   ONE launch shape the spec implies
-   (:meth:`~consensus_tpu.deploy.spec.ClusterSpec.sidecar_wave_lanes`);
-3. compile that shape by pushing a warm-up wave through the coalescer, so
-   the first compile — like every later launch — runs on the flusher
-   thread and no two threads of this process ever compile at once;
+   ``spec.make_configuration``) behind the thread coalescer, with the TWO
+   launch widths the spec implies: the full wave
+   (:meth:`~consensus_tpu.deploy.spec.ClusterSpec.sidecar_wave_lanes`) and
+   the half of it.  A wave launches at the narrower one it fits (the
+   device's time and the host's layout work follow the padded width, and
+   most waves are under half the full one); an engine that launches at one
+   width takes the full one, as before;
+3. compile each width by pushing a warm-up wave that selects it through
+   the coalescer, one after the other, so every compile — like every later
+   launch — runs on the flusher thread and no two threads of this process
+   ever compile at once.  Tracing and lowering are not compiling: with
+   two widths the flusher compiles both at the first wave, and while it
+   loads the first width's executable from the persistent cache (the
+   longest item of a warm start; no Python lock) a helper thread traces
+   and lowers the second;
 4. only then open the verify and control sockets and print ``ready``.
 
 Whether small waves go to the host is the spec's existing decision
@@ -32,7 +41,9 @@ above any wave: such a sidecar opens no backend, compiles nothing, reports
 The control socket's ``health`` reports what a run needs to prove the
 device did the work: ``platform`` / ``device_kind`` / ``device_count``, the
 kernel ledger (launches, compiles, compiles since ready), signatures and
-lanes launched on the device, signatures served from the host, the
+lanes launched on the device (``device_lanes``: the sum of the widths that
+ran; ``launches_by_lanes``: how many launches rode each width since
+ready), signatures served from the host, the
 coalescer's ``device_suspect`` flag and its degrade count, the flusher
 thread's phase ledger (``flusher``: nanoseconds per phase, queue wait and
 flushes by fill, :mod:`consensus_tpu.obs.kernels`) — plus the wave
@@ -54,16 +65,17 @@ import time
 #: Exit code of a sidecar that found no TPU and was not pinned to the CPU,
 #: or whose warm-up wave never came back from the device.
 EXIT_NO_DEVICE = 3
-#: Budget for the cold compile of the one launch shape (the launcher's own
+#: Budget for the cold compile of one launch shape (the launcher's own
 #: ``start`` deadline is usually the tighter bound).
 WARMUP_TIMEOUT = 900.0
 
 
 class _CountingEngine:
     """Engine wrapper under the coalescer: books every wave by where it
-    ran (device launch at the one padded shape, or the engine's host path)
-    and honors a chaos-armed degraded flag (verdicts never change —
-    degraded is a health report, not a correctness state)."""
+    ran (a device launch, at the width the engine says it pads that wave
+    to, or the engine's host path) and honors a chaos-armed degraded flag
+    (verdicts never change — degraded is a health report, not a
+    correctness state)."""
 
     def __init__(self, inner, *, min_device_batch: int, lanes: int) -> None:
         self._inner = inner
@@ -73,18 +85,45 @@ class _CountingEngine:
         self.device_waves = 0
         self.device_signatures = 0
         self.device_lanes = 0
+        self.launches_by_lanes: dict[int, int] = {}
         self.host_signatures = 0
         self.degraded = False
+        self._compile_ahead = None
         self._lock = threading.Lock()
 
+    def launch_width(self, n: int) -> int:
+        """The width a device wave of ``n`` launches at, asked of the
+        engine; one that does not say launches at the one width it was
+        built with."""
+        ask = getattr(self._inner, "launch_width", None)
+        return self._lanes if ask is None else ask(n)
+
+    def compile_ahead_of_next_wave(self, sizes) -> None:
+        """Have the thread that brings the next wave — the coalescer's
+        flusher — compile the width each wave of ``sizes`` rides before it
+        launches that wave (the engine's ``compile_ahead``)."""
+        self._compile_ahead = list(sizes)
+
     def verify_batch(self, messages, signatures, public_keys):
+        sizes, self._compile_ahead = self._compile_ahead, None
+        if sizes:
+            try:
+                self._inner.compile_ahead(sizes)
+            except Exception:  # only a head start: the waves compile too
+                logging.getLogger("consensus_tpu.deploy").exception(
+                    "compiling ahead of the warm-up waves failed"
+                )
         n = len(messages)
         with self._lock:
             self.offered += n
             if n >= self._min_device_batch:
+                width = self.launch_width(n)
                 self.device_waves += 1
                 self.device_signatures += n
-                self.device_lanes += self._lanes
+                self.device_lanes += width
+                self.launches_by_lanes[width] = (
+                    self.launches_by_lanes.get(width, 0) + 1
+                )
             else:
                 self.host_signatures += n
         return self._inner.verify_batch(messages, signatures, public_keys)
@@ -102,6 +141,7 @@ class _CountingEngine:
                 "device_waves": self.device_waves,
                 "device_signatures": self.device_signatures,
                 "device_lanes": self.device_lanes,
+                "launches_by_lanes": dict(self.launches_by_lanes),
                 "host_signatures": self.host_signatures,
             }
 
@@ -117,6 +157,21 @@ def _warm_wave(n: int):
     signer = Ed25519Signer(0, private_key_bytes=b"\x17" * 32)
     msgs = [b"ctpu/sidecar-warm/%d" % i for i in range(n)]
     return msgs, [signer.sign_raw(m) for m in msgs], [signer.public_bytes] * n
+
+
+def _warm(coalescer, n: int):
+    """One warm-up wave of ``n`` signatures through the flusher thread:
+    ``None``, or what went wrong."""
+    try:
+        ok = coalescer.warm(*_warm_wave(n), timeout=WARMUP_TIMEOUT)
+    except (TimeoutError, RuntimeError) as exc:
+        return repr(exc)
+    if ok.all() and not coalescer.device_suspect:
+        return None
+    return (
+        f"all valid: {bool(ok.all())}, "
+        f"device_suspect: {coalescer.device_suspect}"
+    )
 
 
 def main() -> int:
@@ -141,7 +196,7 @@ def main() -> int:
     from consensus_tpu.net.sidecar import VerifySidecarServer
     from consensus_tpu.obs.kernels import FLUSHER, KERNELS
 
-    # --- the engine the spec selects, at the one shape it implies ---------
+    # --- the engine the spec selects, at the widths it implies -------------
     config = spec.make_configuration(spec.node_ids()[0])
     lanes = spec.sidecar_wave_lanes()
     min_device_batch = config.crypto_tpu_min_batch
@@ -179,11 +234,11 @@ def main() -> int:
             return EXIT_NO_DEVICE
 
     engine = _CountingEngine(
-        engine_for_config(config, pad_to=lanes),
+        engine_for_config(config, pad_to=(lanes // 2, lanes)),
         min_device_batch=min_device_batch,
         lanes=lanes,
     )
-    # hard_cap = the compiled shape: no launch can need another one.
+    # hard_cap = the widest compiled shape: no launch can need another one.
     coalescer = ThreadCoalescingVerifier(
         engine,
         window=config.crypto_batch_window,
@@ -195,18 +250,26 @@ def main() -> int:
 
     # --- compile before ready, on the flusher thread ----------------------
     warm_secs = 0.0
+    warmed: list[int] = []
     if not host_only:
         t0 = time.monotonic()  # wallclock-ok
-        try:
-            ok = coalescer.warm(
-                *_warm_wave(max(min_device_batch, 8)), timeout=WARMUP_TIMEOUT
-            )
-            failure = None if ok.all() and not coalescer.device_suspect else (
-                f"all valid: {bool(ok.all())}, "
-                f"device_suspect: {coalescer.device_suspect}"
-            )
-        except (TimeoutError, RuntimeError) as exc:
-            failure = repr(exc)
+        # The warm-up waves: the smallest that needs the full width, then
+        # the smallest device wave there is.  Each proves the width it
+        # rides; a one-width engine rides the same twice: one wave.
+        sizes = [max(n, min_device_batch) for n in (lanes // 2 + 1, 1)]
+        if engine.launch_width(sizes[0]) == engine.launch_width(sizes[1]):
+            del sizes[1]
+        if len(sizes) > 1:
+            # Two widths: the first wave's flush compiles both before it
+            # launches, the second one's trace and lowering hidden under
+            # the first one's load (Ed25519BatchVerifier.compile_ahead).
+            engine.compile_ahead_of_next_wave(sizes)
+        failure = None
+        for n in sizes:
+            failure = _warm(coalescer, n)
+            if failure is not None:
+                break
+            warmed.append(engine.launch_width(n))
         warm_secs = time.monotonic() - t0  # wallclock-ok
         if failure is not None:
             print(
@@ -218,8 +281,8 @@ def main() -> int:
     ready_ledger = KERNELS.totals()
     ready_counts = engine.counts()
     logging.getLogger("consensus_tpu.deploy").info(
-        "backend %s up in %.1fs, %d-lane shape warm in %.1fs",
-        device_report, backend_secs, lanes, warm_secs,
+        "backend %s up in %.1fs, launch widths %s warm in %.1fs",
+        device_report, backend_secs, warmed, warm_secs,
     )
 
     server = VerifySidecarServer(
@@ -253,6 +316,11 @@ def main() -> int:
                 counts["device_signatures"] - ready_counts["device_signatures"]
             ),
             "device_lanes": counts["device_lanes"] - ready_counts["device_lanes"],
+            # How often a wave rode each compiled width, since ready.
+            "launches_by_lanes": {
+                str(width): n - ready_counts["launches_by_lanes"].get(width, 0)
+                for width, n in sorted(counts["launches_by_lanes"].items())
+            },
             "host_signatures": counts["host_signatures"],
             "device_suspect": coalescer.device_suspect,
             "degrade_count": coalescer.health.suspect_marks,

@@ -253,12 +253,13 @@ class ClusterSpec:
         return {s.sidecar_id: (s.host, s.port) for s in self.sidecars}
 
     def sidecar_wave_lanes(self) -> int:
-        """The ONE padded launch shape a sidecar of this rig compiles before
-        it reports ready: the largest wave the cluster can offer inside one
-        coalescing window — every replica verifying one full proposal
-        (``request_batch_max_count`` client signatures) fused with the
-        previous decision's commit cert (at most ``n``) — rounded up to a
-        power of two, 8-lane floor."""
+        """The FULL padded launch width a sidecar of this rig compiles
+        before it reports ready (it compiles the half of it too, and a wave
+        rides the narrower one it fits): the largest wave the cluster can
+        offer inside one coalescing window — every replica verifying one
+        full proposal (``request_batch_max_count`` client signatures) fused
+        with the previous decision's commit cert (at most ``n``) — rounded
+        up to a power of two, 8-lane floor."""
         config = self.make_configuration(1)
         cap = self.n * (config.request_batch_max_count + self.n)
         lanes = 8

@@ -23,7 +23,7 @@ so XLA compiles a handful of shapes once and reuses them forever.
 from __future__ import annotations
 
 import hashlib
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Union
 
 import numpy as np
 
@@ -199,19 +199,29 @@ def _bits_to_comb_digits8(bits: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(digits.T).astype(np.uint8)
 
 
-def to_kernel_layout(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
-    """Host row-major arrays -> device layout: limbs/digits leading (on the
-    sublanes), batch trailing (on the lanes); S as 8-bit comb digits, k as
-    MSB-first 4-bit Horner digits.  Everything ships as the narrowest
-    integer dtype (uint8/bool) — the kernel widens on device."""
+def _kernel_layout_np(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
+    """Host row-major arrays -> the kernel's layout, still on the host:
+    limbs/digits leading (on the sublanes), batch trailing (on the lanes);
+    S as 8-bit comb digits, k as MSB-first 4-bit Horner digits.  Everything
+    in the narrowest integer dtype (uint8/bool) — the kernel widens on
+    device."""
     return (
-        jnp.asarray(np.ascontiguousarray(y_r.T)),
-        jnp.asarray(sign_r),
-        jnp.asarray(np.ascontiguousarray(y_a.T)),
-        jnp.asarray(sign_a),
-        jnp.asarray(_bits_to_comb_digits8(s_bits)),
-        jnp.asarray(_bits_to_signed_window_digits(k_bits)),
-        jnp.asarray(host_ok),
+        np.ascontiguousarray(y_r.T),
+        sign_r,
+        np.ascontiguousarray(y_a.T),
+        sign_a,
+        _bits_to_comb_digits8(s_bits),
+        _bits_to_signed_window_digits(k_bits),
+        host_ok,
+    )
+
+
+def to_kernel_layout(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
+    """Host row-major arrays -> device layout (:func:`_kernel_layout_np`),
+    shipped to the device."""
+    return tuple(
+        jnp.asarray(a)
+        for a in _kernel_layout_np(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)
     )
 
 
@@ -228,6 +238,11 @@ class Ed25519BatchVerifier:
     ``verify_batch`` returns a boolean numpy array.  ``pad_pow2`` keeps the
     set of compiled kernel shapes small; ``min_device_batch`` routes tiny
     batches to the host path (kernel launch overhead dominates below it).
+    ``pad_to`` names the launch widths a deployment compiles before it
+    serves: one (every wave pads to it) or a ladder of them (a wave pads
+    to the narrowest that holds it, :meth:`launch_width`) — the device's
+    time and the host's layout work both follow the PADDED width, so a
+    half-empty wave in a half-width launch costs about half.
     """
 
     def __init__(
@@ -235,15 +250,29 @@ class Ed25519BatchVerifier:
         *,
         pad_pow2: bool = True,
         min_device_batch: int = 1,
-        pad_to: int = 0,
+        pad_to: Union[int, Sequence[int]] = 0,
     ) -> None:
         """``pad_to`` > 0 pads every device batch to that fixed size (one
         compiled kernel shape for the whole deployment — no mid-run compiles
-        on underfull batches); larger batches fall back to the pow-2
-        ladder."""
+        on underfull batches); a sequence of sizes pads each batch to the
+        smallest of them that holds it (one compiled shape per size);
+        batches larger than all of them fall back to the pow-2 ladder."""
         self._pad_pow2 = pad_pow2
         self._min_device_batch = min_device_batch
-        self._pad_to = pad_to
+        widths = (pad_to,) if isinstance(pad_to, int) else tuple(pad_to)
+        #: The launch widths, ascending; ``_pad_to`` is the widest (what
+        #: the one-width subclasses and the wave sizing read).
+        self._widths = tuple(sorted({int(w) for w in widths if w > 0}))
+        self._pad_to = self._widths[-1] if self._widths else 0
+
+    def launch_width(self, n: int) -> int:
+        """The padded width a device wave of ``n`` signatures launches at:
+        the narrowest ``pad_to`` width that holds it, else the pow-2
+        fallback (or ``n`` itself without ``pad_pow2``)."""
+        for width in self._widths:
+            if width >= n:
+                return width
+        return _next_pow2(n) if self._pad_pow2 else n
 
     @property
     def preferred_wave_size(self) -> int:
@@ -308,6 +337,52 @@ class Ed25519BatchVerifier:
         host_ok &= r_ok & a_ok
         return y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok
 
+    def _padded(self, prepared: tuple, n: int) -> tuple:
+        """The prepared rows padded to the width a wave of ``n`` launches
+        at."""
+        pad = self.launch_width(n) - len(prepared[-1])
+        if not pad:
+            return prepared
+        return tuple(
+            np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) for a in prepared
+        )
+
+    def compile_ahead(self, sizes: Sequence[int]) -> None:
+        """Compile, one after the other on the calling thread, the width
+        each wave of ``sizes`` signatures launches at — before any such wave
+        is there.  jax keeps trace, lowered module and executable, so the
+        first launch of a width then starts at the launch.
+
+        Why a server that warms several widths calls this instead of
+        sending one wave after the other: tracing and lowering are seconds
+        of Python a shape, while a compile — with a warm persistent cache
+        the load of an executable, the longest item of a start — holds no
+        Python lock.  So while this thread compiles one width a helper
+        thread traces and lowers the NEXT (it never compiles: no two
+        threads of a process compile at once)."""
+        import threading
+
+        jitted = _verify_kernel.__wrapped__
+
+        def lower(n: int):
+            arrays = _kernel_layout_np(*self._padded(self._prepare([], [], []), n))
+            return jitted.lower(
+                *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays)
+            )
+
+        lowered = lower(sizes[0])
+        for nxt in list(sizes[1:]) + [None]:
+            ahead = None
+            if nxt is not None:
+                ahead = threading.Thread(
+                    target=lower, args=(nxt,), name="lower-ahead", daemon=True
+                )
+                ahead.start()
+            lowered.compile()
+            if ahead is not None:
+                ahead.join()
+                lowered = lower(nxt)  # jax's own caches answer
+
     def verify_batch(
         self,
         messages: Sequence[bytes],
@@ -330,22 +405,9 @@ class Ed25519BatchVerifier:
             )
 
         with phase("verify.layout"):
-            if self._pad_to >= n:
-                padded = self._pad_to
-            else:
-                padded = _next_pow2(n) if self._pad_pow2 else n
-            if padded != n:
-                pad = padded - n
-                y_r = np.pad(y_r, ((0, pad), (0, 0)))
-                y_a = np.pad(y_a, ((0, pad), (0, 0)))
-                sign_r = np.pad(sign_r, (0, pad))
-                sign_a = np.pad(sign_a, (0, pad))
-                s_bits = np.pad(s_bits, ((0, pad), (0, 0)))
-                k_bits = np.pad(k_bits, ((0, pad), (0, 0)))
-                host_ok = np.pad(host_ok, (0, pad))
-            kernel_inputs = to_kernel_layout(
-                y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok
-            )
+            kernel_inputs = to_kernel_layout(*self._padded(
+                (y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok), n
+            ))
 
         with phase("verify.dispatch"):
             result = _verify_kernel(*kernel_inputs)

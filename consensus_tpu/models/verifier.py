@@ -47,7 +47,7 @@ def raw_message(data: bytes) -> bytes:
 
 
 def engine_for_config(
-    config, curve: str = "ed25519", *, metrics=None, pad_to: int = 0
+    config, curve: str = "ed25519", *, metrics=None, pad_to=0
 ):
     """The batch engine matching a ``Configuration``'s crypto knobs
     (``batch_verify_mode``, ``crypto_pad_pow2``, ``crypto_tpu_min_batch``,
@@ -83,7 +83,10 @@ def engine_for_config(
     ``pad_to`` > 0 pins every device launch to that ONE padded shape (the
     engines' ``pad_to``): a server that knows its largest wave — the rig
     sidecar derives it from the cluster spec — compiles once before it
-    serves and never mid-run."""
+    serves and never mid-run.  A sequence of widths is a ladder: the strict
+    single-device Ed25519 engine pads each wave to the narrowest width
+    that holds it; every other engine launches at one width and takes the
+    widest."""
     from consensus_tpu.obs.kernels import COMPILE_CACHE
 
     before = COMPILE_CACHE.snapshot()
@@ -135,15 +138,22 @@ def degrade_ladder_configs(config) -> list:
     return ladder
 
 
-def _engine_for_config(config, curve: str = "ed25519", pad_to: int = 0):
+def _engine_for_config(config, curve: str = "ed25519", pad_to=0):
     """The unsupervised engine routing (see :func:`engine_for_config`):
     config -> ``EngineKey`` -> registered builder."""
-    from consensus_tpu.models.registry import ENGINE_REGISTRY, engine_key_for
+    from consensus_tpu.models.registry import (
+        ENGINE_REGISTRY,
+        EngineKey,
+        engine_key_for,
+    )
     from consensus_tpu.parallel.topology import topology_for_config
 
     cache = getattr(config, "compile_cache", None)
+    key = engine_key_for(config, curve)
+    if not isinstance(pad_to, int) and key != EngineKey(mxu=key.mxu):
+        pad_to = max(pad_to)  # one launch width: the ladder's widest
     return ENGINE_REGISTRY.build(
-        engine_key_for(config, curve),
+        key,
         topology=topology_for_config(config),
         compile_cache=bool(getattr(cache, "enabled", True)),
         pad_pow2=config.crypto_pad_pow2,

@@ -10,9 +10,15 @@ on every call records into the process-wide :data:`KERNELS` registry:
 * ``compiles``   — jit cache growth observed across calls (via the private
   but long-stable ``_cache_size`` probe; gracefully 0 if it disappears);
 * ``retraces``   — compiles beyond the first, i.e. shape/dtype churn;
-* ``flops`` / ``bytes_accessed`` — XLA cost-analysis estimates captured at
-  first compile per kernel (``lower(...).cost_analysis()``; ``lower`` does
-  not populate the jit call cache, so the probe never double-compiles).
+* ``flops`` / ``bytes_accessed`` — XLA's cost estimates of the compiled
+  executable, captured at first compile per kernel.  The call that grew
+  the jit cache has traced, lowered and compiled the shape, and jax keeps
+  all three: ``lower(...).compile()`` right after it hands back that very
+  executable, so each shape is lowered ONCE.  (``Lowered.cost_analysis()``,
+  read here before, never lowered twice either, but on the TPU backend it
+  answers ``None`` — both numbers stayed empty on the chip — and on the
+  CPU backend it converts the whole module again, seconds for a verify
+  kernel.)
 
 The registry is surfaced as a ``kernels`` column family in bench.py on both
 the live and structured-skip paths.
@@ -320,7 +326,11 @@ def instrumented_jit(
             st.compiles += grew
             if st.flops is None:
                 try:
-                    analysis = jitted.lower(*args, **kwargs).cost_analysis()
+                    # Cache hits all the way: the executable the call
+                    # above built, and its own estimates.
+                    analysis = (
+                        jitted.lower(*args, **kwargs).compile().cost_analysis()
+                    )
                     st.flops = _cost_number(analysis, "flops")
                     st.bytes_accessed = _cost_number(analysis, "bytes accessed")
                 except Exception:

@@ -214,6 +214,36 @@ _COMB_WINDOWS = 32
 _COMB_BITS = 8
 
 
+def _extended_add_int(p1, p2):
+    """Host-side integer point addition in extended coordinates
+    (X, Y, Z, T), add-2008-hwcd-3: complete on this curve (identity and
+    doubling included) and free of inversions."""
+    x1, y1, z1, t1 = p1
+    x2, y2, z2, t2 = p2
+    P_ = fe.P
+    a = (y1 - x1) * (y2 - x2) % P_
+    b = (y1 + x1) * (y2 + x2) % P_
+    c = t1 * _D2 % P_ * t2 % P_
+    d = 2 * z1 * z2 % P_
+    e, f, g, h = b - a, d - c, d + c, b + a
+    return e * f % P_, g * h % P_, f * g % P_, e * h % P_
+
+
+def _batch_inverse_int(values: list[int]) -> list[int]:
+    """Modular inverses of ``values`` (none zero) for ONE exponentiation:
+    Montgomery's trick, prefix products up and back down."""
+    P_ = fe.P
+    prefix = [1]
+    for v in values:
+        prefix.append(prefix[-1] * v % P_)
+    inv = pow(prefix[-1], P_ - 2, P_)
+    out = [0] * len(values)
+    for i in range(len(values) - 1, -1, -1):
+        out[i] = inv * prefix[i] % P_
+        inv = inv * values[i] % P_
+    return out
+
+
 @functools.lru_cache(maxsize=1)
 def _comb_table_np() -> tuple["np.ndarray", "np.ndarray", "np.ndarray"]:
     """Fixed-base comb: affine (x, y, t=xy) limb arrays of shape
@@ -221,26 +251,34 @@ def _comb_table_np() -> tuple["np.ndarray", "np.ndarray", "np.ndarray"]:
 
     B is a compile-time constant, so [S]B needs NO doubles and NO per-batch
     table build: 32 constant-table lookups + 31 adds, vs riding the shared
-    Horner scan (64 table adds).  Host-side integer precompute (~0.2 s,
-    cached for the process; the arrays are baked into the jitted graph as
-    constants)."""
+    Horner scan (64 table adds).  Host-side integer precompute, cached for
+    the process (the arrays are baked into the jitted graph as constants):
+    the 8,192 entries are chained in extended coordinates and brought to
+    affine by one batched inversion — a modular inversion an entry, as
+    :func:`_edwards_add_int` would pay, is seconds of every process start
+    that traces a verify kernel."""
     import numpy as np
 
-    xs = np.zeros((_COMB_WINDOWS, 1 << _COMB_BITS, fe.LIMBS), dtype=np.float32)
+    entries = []
+    window_base = (_BX, _BY, 1, _BX * _BY % fe.P)  # 2^(8j) * B
+    for _ in range(_COMB_WINDOWS):
+        entry = (0, 1, 1, 0)  # identity
+        for _ in range(1 << _COMB_BITS):
+            entries.append(entry)
+            entry = _extended_add_int(entry, window_base)
+        for _ in range(_COMB_BITS):
+            window_base = _extended_add_int(window_base, window_base)
+    xs = np.zeros((len(entries), fe.LIMBS), dtype=np.float32)
     ys = np.zeros_like(xs)
     ts = np.zeros_like(xs)
-    window_base = (_BX, _BY)  # 2^(8j) * B
-    for j in range(_COMB_WINDOWS):
-        entry = (0, 1)  # identity
-        for d in range(1 << _COMB_BITS):
-            x, y = entry
-            xs[j, d] = fe.int_to_limbs(x)
-            ys[j, d] = fe.int_to_limbs(y)
-            ts[j, d] = fe.int_to_limbs(x * y % fe.P)
-            entry = _edwards_add_int(entry, window_base)
-        for _ in range(_COMB_BITS):
-            window_base = _edwards_add_int(window_base, window_base)
-    return xs, ys, ts
+    z_inverses = _batch_inverse_int([z for _, _, z, _ in entries])
+    for i, ((x, y, _, _), z_inv) in enumerate(zip(entries, z_inverses)):
+        x, y = x * z_inv % fe.P, y * z_inv % fe.P
+        xs[i] = fe.int_to_limbs(x)
+        ys[i] = fe.int_to_limbs(y)
+        ts[i] = fe.int_to_limbs(x * y % fe.P)
+    shape = (_COMB_WINDOWS, 1 << _COMB_BITS, fe.LIMBS)
+    return xs.reshape(shape), ys.reshape(shape), ts.reshape(shape)
 
 
 def add_affine(p: Point, q_x: jnp.ndarray, q_y: jnp.ndarray, q_t: jnp.ndarray) -> Point:

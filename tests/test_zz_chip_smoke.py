@@ -7,8 +7,9 @@ Nothing here is a chip result: the records say ``dry_run`` and
 when the sidecar's backend did every wave, and it FAILS (while the ledger
 still grows on the replicas' host fallback) when the sidecar is taken away.
 
-Subprocess-heavy and compiles one 512-lane kernel on the CPU: named to sort
-last so it never displaces the rest of the tier-1 suite inside its budget.
+Subprocess-heavy and compiles the sidecar's two launch widths (512 lanes and
+the half) on the CPU: named to sort last so it never displaces the rest of
+the tier-1 suite inside its budget.
 """
 
 import json
@@ -41,24 +42,40 @@ def _run(tmp_path, emit=None, **kw):
 @pytest.fixture(scope="module")
 def rehearsal(tmp_path_factory):
     """ONE sound rehearsal for the tests below, with the sidecar's ``health``
-    read before the traffic and again once the verdict wave is through."""
-    rig, health = [], []
+    read before the traffic and again once the verdict wave is through; then
+    one NARROW wave of 64 lanes with every rejection class, sent the way the
+    verdict wave was, with the verdicts it got and a third ``health``."""
+    rig, health, narrow = [], [], {}
 
     def before_traffic(launcher):
         rig.append(launcher)
         health.append(launcher.sidecars["sc-0"].probe())
 
     def emit(record):
-        if record["phase"] == "verdicts":
-            health.append(rig[0].sidecars["sc-0"].probe())
+        if record["phase"] != "verdicts":
+            return
+        health.append(rig[0].sidecars["sc-0"].probe())
+        from consensus_tpu.net.sidecar import SidecarVerifierClient
+
+        spec = rig[0].spec
+        wave, planted = chip_smoke._ed25519_wave(64, 11)
+        client = SidecarVerifierClient(
+            spec.sidecar_addresses()["sc-0"], auth_secret=spec.auth_secret,
+            request_timeout=120.0)
+        try:
+            got = client.verify_batch(*wave)
+        finally:
+            client.close()
+        narrow.update(wave=wave, planted=planted, got=got,
+                      health=rig[0].sidecars["sc-0"].probe())
 
     result, phases = _run(tmp_path_factory.mktemp("chip_smoke"), emit=emit,
                           before_traffic=before_traffic)
-    return result, phases, health
+    return result, phases, health, narrow
 
 
 def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(rehearsal):
-    result, phases, _ = rehearsal
+    result, phases, _, _ = rehearsal
     assert result["ok"], phases
     assert result["device"]["platform"] == "cpu"
     assert all(r["dry_run"] for r in phases.values())
@@ -66,7 +83,8 @@ def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(rehearsal):
     rig, traffic, device, verdicts = (
         phases[k] for k in ("rig", "traffic", "device", "verdicts")
     )
-    assert rig["sidecar_lanes"] == 512 and rig["compiles_at_ready"] == 1
+    # Both launch widths, 512 lanes and the half, compiled before ready.
+    assert rig["sidecar_lanes"] == 512 and rig["compiles_at_ready"] == 2
     assert traffic["replicas_delivered_all_exactly_once"]
     assert traffic["ledgers_identical"]
     assert traffic["decisions"] * size["batch"] >= size["requests"]
@@ -91,21 +109,28 @@ def test_rehearsal_passes_when_the_sidecar_backend_did_the_work(rehearsal):
 
 def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
     """``health["flusher"]``: every phase and counter of obs/kernels.py, as
-    integers, cumulative (the warm-up wave is in the first reading already)
+    integers, cumulative (the warm-up waves are in the first reading already)
     and never decreasing; across the traffic every one of them moved but the
     fill buckets no flush fell into and the two counts of held flushes
     (never more of those than flushes), and the launches the kernel ledger
     counts are the flushes the coalescer counts."""
     from consensus_tpu.obs.kernels import FLUSHER_COUNTERS, FLUSHER_PHASES
 
-    result, _, health = rehearsal
+    result, _, health, _ = rehearsal
     assert result["ok"] and len(health) == 2
     first, last = (h["flusher"] for h in health)
     assert set(first) == set(last) == set(FLUSHER_PHASES + FLUSHER_COUNTERS)
     assert all(type(v) is int for v in list(first.values()) + list(last.values()))
     assert all(last[k] >= first[k] >= 0 for k in first)
-    assert first["flushes"] == 1 == first["fill_le_25"]  # the warm-up wave
-    assert first["verify.dispatch"] > 0  # ... which compiled inside dispatch
+    # The two warm-up waves: 257 signatures for the full width, then the
+    # smallest device wave for the half.  Both widths were compiled by the
+    # flusher ahead of the first (the engine's compile_ahead): inside
+    # engine_ns, outside the four verify.* phases.
+    assert first["flushes"] == 2
+    assert first["fill_le_75"] == 1 == first["fill_le_25"]
+    assert first["verify.dispatch"] > 0
+    assert first["engine_ns"] > sum(
+        first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
     buckets = [k for k in first if k.startswith("fill_le_")]
     holds = ["hold_met", "hold_expired"]  # only a learned burst is held for
     assert set(holds) < set(first)
@@ -120,6 +145,44 @@ def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
     inside = sum(last[k] - first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
     engine = last["engine_ns"] - first["engine_ns"]
     assert inside <= engine <= 1.05 * inside
+
+
+def test_sidecar_books_follow_the_width_each_wave_rode(rehearsal):
+    """Two widths compiled before ready and none after.  Up to the verdict
+    wave: a burst cut short rode the half, a whole one (4 x 64 and the
+    certificates) and the full-width verdict wave the whole;
+    ``launches_by_lanes`` sums to the launches and ``device_lanes`` is the
+    sum of the widths launched, not launches x 512; at ready both were zero
+    though each width had run its warm-up.  Then the narrow wave: one launch
+    of 256 lanes more, nothing compiled, and the narrow executable rejects
+    exactly the planted lanes, as the host twin does."""
+    from consensus_tpu.models import Ed25519BatchVerifier
+
+    result, _, health, narrow = rehearsal
+    assert result["ok"]
+    ready, last = health
+    assert ready["compiles"] == last["compiles"] == 2
+    assert ready["compiles_after_ready"] == last["compiles_after_ready"] == 0
+    assert ready["launches_by_lanes"] == {"256": 0, "512": 0}
+    assert ready["device_lanes"] == 0 == ready["launches_after_ready"]
+    by_lanes = last["launches_by_lanes"]
+    assert set(by_lanes) == {"256", "512"} and by_lanes["512"] >= 1
+    assert sum(by_lanes.values()) == last["launches_after_ready"]
+    assert last["device_lanes"] == 256 * by_lanes["256"] + 512 * by_lanes["512"]
+    assert last["device_signatures"] <= last["device_lanes"]
+    assert last["lanes"] == 512  # the full width: what the harness sizes by
+
+    after = narrow["health"]
+    assert after["compiles"] == 2 and after["compiles_after_ready"] == 0
+    assert after["launches_by_lanes"] == {
+        "256": by_lanes["256"] + 1, "512": by_lanes["512"]}
+    assert after["launches_after_ready"] == last["launches_after_ready"] + 1
+    assert after["device_lanes"] == last["device_lanes"] + 256
+    assert after["device_signatures"] == last["device_signatures"] + 64
+    want = Ed25519BatchVerifier().verify_host(*narrow["wave"])
+    assert [bool(v) for v in narrow["got"]] == want.tolist()
+    assert sorted(narrow["planted"]) == [i for i, ok in enumerate(want) if not ok]
+    assert len(narrow["planted"]) == 8
 
 
 def test_rehearsal_fails_when_the_sidecar_is_killed_before_traffic(tmp_path):
