@@ -147,9 +147,6 @@ class Configuration:
     # of the burst of submissions it has learned to expect).
     crypto_tpu_min_batch: int = 16
     crypto_batch_window: float = 0.002
-    # Pad verification batches up to the next power of two (stable XLA shapes,
-    # avoids recompilation across batch sizes).
-    crypto_pad_pow2: bool = True
     # Randomized Ed25519 batch verification (one shared-doubling aggregate
     # check per batch, bisection fallback on failure — models/ed25519.py
     # Ed25519RandomizedBatchVerifier).  Default off: all replicas in a

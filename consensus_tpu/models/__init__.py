@@ -6,7 +6,7 @@ from consensus_tpu.models.ed25519 import (
     Ed25519RandomizedBatchVerifier,
     L,
 )
-from consensus_tpu.models.engine import BatchCoalescer, ThreadCoalescingVerifier
+from consensus_tpu.models.engine import ThreadCoalescingVerifier
 from consensus_tpu.models.supervisor import (
     ENGINE_HEALTH,
     FAULT_CLASSES,
@@ -37,7 +37,6 @@ __all__ = [
     "Ed25519RandomizedBatchVerifier",
     "FusedEd25519BatchVerifier",
     "L",
-    "BatchCoalescer",
     "ThreadCoalescingVerifier",
     "CircuitBreaker",
     "ENGINE_HEALTH",

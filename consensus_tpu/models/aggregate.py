@@ -135,19 +135,16 @@ class HalfAggregator:
         self,
         *,
         engine: Optional[object] = None,
-        pad_pow2: bool = True,
         min_device_batch: int = 1,
         pad_to: int = 0,
         min_bisect: int = 2,
     ) -> None:
         if engine is not None:
-            pad_pow2 = getattr(engine, "_pad_pow2", pad_pow2)
             min_device_batch = getattr(
                 engine, "_min_device_batch", min_device_batch
             )
             pad_to = getattr(engine, "_pad_to", pad_to)
         self._engine = engine
-        self._pad_pow2 = pad_pow2
         self._min_device_batch = min_device_batch
         self._pad_to = pad_to
         self._min_bisect = max(2, int(min_bisect))
@@ -306,7 +303,7 @@ class HalfAggregator:
         if self._pad_to >= m:
             padded = self._pad_to
         else:
-            padded = _next_pow2(m) if self._pad_pow2 else m
+            padded = _next_pow2(m)
         if padded != m:
             pad = padded - m
             y_r = np.pad(y_r, ((0, pad), (0, 0)))

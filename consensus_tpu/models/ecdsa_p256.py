@@ -188,7 +188,6 @@ class EcdsaP256BatchVerifier:
     def __init__(
         self,
         *,
-        pad_pow2: bool = True,
         min_device_batch: int = 1,
         pad_to: int = 0,
     ) -> None:
@@ -196,7 +195,6 @@ class EcdsaP256BatchVerifier:
         compiled kernel shape for the whole deployment — no mid-run compiles
         on underfull batches); batches larger than ``pad_to`` fall back to
         the pow-2 ladder."""
-        self._pad_pow2 = pad_pow2
         self._min_device_batch = min_device_batch
         self._pad_to = pad_to
 
@@ -210,7 +208,6 @@ class EcdsaP256BatchVerifier:
             max(1, self._min_device_batch),
             1,
             pad_to=self._pad_to,
-            pad_pow2=self._pad_pow2,
         )
 
     @staticmethod
@@ -300,7 +297,7 @@ class EcdsaP256BatchVerifier:
         if self._pad_to >= n:
             padded = self._pad_to
         else:
-            padded = _next_pow2(n) if self._pad_pow2 else n
+            padded = _next_pow2(n)
         result = _verify_kernel(*to_kernel_layout(*pad_prepared(prepped, padded)))
         return np.asarray(result)[:n]
 

@@ -16,7 +16,7 @@ Split of labor:
   batched on the trailing axis — one compiled kernel per padded batch size
   verifies the whole quorum.  Inputs ship as uint8 (4x less transfer).
 
-Batches are padded to the next power of two (``Configuration.crypto_pad_pow2``)
+Batches are padded to the next power of two
 so XLA compiles a handful of shapes once and reuses them forever.
 """
 
@@ -235,9 +235,10 @@ def _next_pow2(n: int, minimum: int = 8) -> int:
 class Ed25519BatchVerifier:
     """Verify many (message, signature, public key) triples at once.
 
-    ``verify_batch`` returns a boolean numpy array.  ``pad_pow2`` keeps the
-    set of compiled kernel shapes small; ``min_device_batch`` routes tiny
-    batches to the host path (kernel launch overhead dominates below it).
+    ``verify_batch`` returns a boolean numpy array.  Padding to a power of
+    two keeps the set of compiled kernel shapes small; ``min_device_batch``
+    routes tiny batches to the host path (kernel launch overhead dominates
+    below it).
     ``pad_to`` names the launch widths a deployment compiles before it
     serves: one (every wave pads to it) or a ladder of them (a wave pads
     to the narrowest that holds it, :meth:`launch_width`) — the device's
@@ -248,7 +249,6 @@ class Ed25519BatchVerifier:
     def __init__(
         self,
         *,
-        pad_pow2: bool = True,
         min_device_batch: int = 1,
         pad_to: Union[int, Sequence[int]] = 0,
     ) -> None:
@@ -257,7 +257,6 @@ class Ed25519BatchVerifier:
         on underfull batches); a sequence of sizes pads each batch to the
         smallest of them that holds it (one compiled shape per size);
         batches larger than all of them fall back to the pow-2 ladder."""
-        self._pad_pow2 = pad_pow2
         self._min_device_batch = min_device_batch
         widths = (pad_to,) if isinstance(pad_to, int) else tuple(pad_to)
         #: The launch widths, ascending; ``_pad_to`` is the widest (what
@@ -267,12 +266,12 @@ class Ed25519BatchVerifier:
 
     def launch_width(self, n: int) -> int:
         """The padded width a device wave of ``n`` signatures launches at:
-        the narrowest ``pad_to`` width that holds it, else the pow-2
-        fallback (or ``n`` itself without ``pad_pow2``)."""
+        the narrowest ``pad_to`` width that holds it, else the next power
+        of two."""
         for width in self._widths:
             if width >= n:
                 return width
-        return _next_pow2(n) if self._pad_pow2 else n
+        return _next_pow2(n)
 
     @property
     def preferred_wave_size(self) -> int:
@@ -286,7 +285,6 @@ class Ed25519BatchVerifier:
             max(1, self._min_device_batch),
             1,
             pad_to=self._pad_to,
-            pad_pow2=self._pad_pow2,
         )
 
     def _prepare(
@@ -634,13 +632,11 @@ class Ed25519RandomizedBatchVerifier(Ed25519BatchVerifier):
     def __init__(
         self,
         *,
-        pad_pow2: bool = True,
         min_device_batch: int = 1,
         pad_to: int = 0,
         min_randomized: int = 2,
     ) -> None:
         super().__init__(
-            pad_pow2=pad_pow2,
             min_device_batch=min_device_batch,
             pad_to=pad_to,
         )
@@ -745,7 +741,7 @@ class Ed25519RandomizedBatchVerifier(Ed25519BatchVerifier):
         if self._pad_to >= m:
             padded = self._pad_to
         else:
-            padded = _next_pow2(m) if self._pad_pow2 else m
+            padded = _next_pow2(m)
         if padded != m:
             pad = padded - m
             y_r = np.pad(y_r, ((0, pad), (0, 0)))

@@ -22,7 +22,7 @@ import numpy as np
 
 from consensus_tpu.models.supervisor import ENGINE_HEALTH, EngineHealth
 from consensus_tpu.obs.kernels import FLUSHER, phase
-from consensus_tpu.runtime.scheduler import Scheduler, TimerHandle
+from consensus_tpu.runtime.scheduler import Scheduler
 
 logger = logging.getLogger("consensus_tpu.models.engine")
 
@@ -41,66 +41,6 @@ def _split_results(results: Sequence, sizes: Sequence[int]):
         out.append(results[offset : offset + size])
         offset += size
     return out
-
-
-class BatchCoalescer:
-    """Generic (items -> results) coalescer on the replica scheduler.
-
-    ``run_batch`` receives the concatenated items of all pending
-    submissions and must return one result per item, in order.
-    """
-
-    def __init__(
-        self,
-        scheduler: Scheduler,
-        run_batch: Callable[[Sequence], Sequence],
-        *,
-        window: float = 0.002,
-        max_batch: int = 1024,
-    ) -> None:
-        self._sched = scheduler
-        self._run_batch = run_batch
-        self._window = window
-        self._max_batch = max_batch
-        self._pending: list[tuple[list, Callable[[Sequence], None]]] = []
-        self._pending_count = 0
-        self._timer: Optional[TimerHandle] = None
-
-    def submit(self, items: Sequence, on_results: Callable[[Sequence], None]) -> None:
-        """Queue ``items``; ``on_results`` fires with their results once the
-        batch they rode in completes."""
-        items = list(items)
-        if not items:
-            on_results([])
-            return
-        self._pending.append((items, on_results))
-        self._pending_count += len(items)
-        if self._pending_count >= self._max_batch:
-            self.flush()
-        elif self._timer is None:
-            self._timer = self._sched.call_later(
-                self._window, self.flush, name="crypto-batch-window"
-            )
-
-    def flush(self) -> None:
-        """Run everything pending as one batch."""
-        if self._timer is not None:
-            self._timer.cancel()
-            self._timer = None
-        pending, self._pending, self._pending_count = self._pending, [], 0
-        if not pending:
-            return
-        merged: list = []
-        for items, _ in pending:
-            merged.extend(items)
-        results = self._run_batch(merged)
-        slices = _split_results(results, [len(items) for items, _ in pending])
-        for (_, on_results), piece in zip(pending, slices):
-            on_results(piece)
-
-    @property
-    def pending_count(self) -> int:
-        return self._pending_count
 
 
 class _Pending:
@@ -898,7 +838,6 @@ class FairShareWaveFormer:
 
 __all__ = [
     "AdmissionReject",
-    "BatchCoalescer",
     "FairShareWaveFormer",
     "ThreadCoalescingVerifier",
 ]

@@ -219,7 +219,7 @@ class FusedEd25519BatchVerifier(Ed25519BatchVerifier):
         if self._pad_to >= n:
             padded = self._pad_to
         else:
-            padded = _next_pow2(n) if self._pad_pow2 else n
+            padded = _next_pow2(n)
         sig_rows, key_rows, n_blocks, host_ok = _pad_wave(
             [sig_rows, key_rows, n_blocks, host_ok], n, padded
         )

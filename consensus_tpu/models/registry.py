@@ -72,7 +72,7 @@ class EngineRegistry:
     ``topology`` is a :class:`~consensus_tpu.parallel.topology.MeshTopology`
     (ignored by single-device builders), ``compile_cache`` opts into the
     process-wide compiled-kernel memo, and ``kw`` carries the padding knobs
-    (``pad_pow2``, ``min_device_batch``).
+    (``min_device_batch``, ``pad_to``).
     """
 
     def __init__(self) -> None:

@@ -50,7 +50,7 @@ def engine_for_config(
     config, curve: str = "ed25519", *, metrics=None, pad_to=0
 ):
     """The batch engine matching a ``Configuration``'s crypto knobs
-    (``batch_verify_mode``, ``crypto_pad_pow2``, ``crypto_tpu_min_batch``,
+    (``batch_verify_mode``, ``crypto_tpu_min_batch``,
     ``mesh_shards`` / ``mesh_topology``, ``device_prep``), routed through
     the engine registry (:mod:`consensus_tpu.models.registry`): the config
     maps to an ``EngineKey`` and an unregistered key fails loudly with the
@@ -156,7 +156,6 @@ def _engine_for_config(config, curve: str = "ed25519", pad_to=0):
         key,
         topology=topology_for_config(config),
         compile_cache=bool(getattr(cache, "enabled", True)),
-        pad_pow2=config.crypto_pad_pow2,
         min_device_batch=config.crypto_tpu_min_batch,
         pad_to=pad_to,
     )

@@ -20,8 +20,9 @@ on every call records into the process-wide :data:`KERNELS` registry:
   CPU backend it converts the whole module again, seconds for a verify
   kernel.)
 
-The registry is surfaced as a ``kernels`` column family in bench.py on both
-the live and structured-skip paths.
+The registry is surfaced as the ``kernels`` block of the rig sidecar's
+``health`` (``deploy/sidecar_main.py``), which served_bench's readers and
+chip_smoke.py's lane census read.
 
 jax is imported lazily inside the wrapper so importing consensus_tpu.obs
 never drags in the accelerator stack (the sim plane must stay importable
@@ -101,7 +102,7 @@ class KernelRegistry:
         self._stats.clear()
 
 
-#: The process-wide registry bench.py snapshots.
+#: The process-wide registry the sidecar's ``health`` snapshots.
 KERNELS = KernelRegistry()
 
 

@@ -15,8 +15,7 @@ contractions the MXU can tile:
 2. a contraction of the flattened products against a constant (63, 1024)
    0/1 **column-assembly matrix** ``C[c, 32i+j] = [i + j == c]`` — the
    schoolbook convolution as one (63 x 1024) x (1024 x batch) integer
-   matmul with a shared constant operand (the shape
-   benchmarks/mxu_fieldmul.py's round-6 analysis said the MXU needs to
+   matmul with a shared constant operand (the shape the MXU needs to
    win: reuse across the batch, not per-lane elementwise work).  Column
    sums are <= 32 * 462,400 < 2^24 — the same bound the f32 lane proves.
 

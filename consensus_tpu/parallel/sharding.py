@@ -214,7 +214,6 @@ class _MeshEngine:
             self._n_shards * max(1, self._min_device_batch),
             self._n_shards,
             pad_to=self._pad_to,
-            pad_pow2=self._pad_pow2,
         )
 
     def _put_sharded(self, device_args):
@@ -272,7 +271,7 @@ class ShardedEd25519Verifier(_MeshEngine, Ed25519BatchVerifier):
         prepped = self._prepare(messages, signatures, public_keys)
         y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok = prepped
         padded = engine_padded_size(
-            n, self._n_shards, pad_to=self._pad_to, pad_pow2=self._pad_pow2
+            n, self._n_shards, pad_to=self._pad_to
         )
         if padded != n:
             pad = padded - n
@@ -355,7 +354,7 @@ class ShardedEcdsaP256Verifier(_MeshEngine, EcdsaP256BatchVerifier):
             return self._verify_host(messages, signatures, public_keys)
         prepped = self._prepare(messages, signatures, public_keys)
         padded = engine_padded_size(
-            n, self._n_shards, pad_to=self._pad_to, pad_pow2=self._pad_pow2
+            n, self._n_shards, pad_to=self._pad_to
         )
         device_args = to_kernel_layout(*pad_prepared(prepped, padded))
         ok, _total = self._fn(*self._put_sharded(device_args))
@@ -462,7 +461,7 @@ class ShardedEd25519RandomizedVerifier(_MeshEngine, Ed25519RandomizedBatchVerifi
         host_ok = np.ones(m, dtype=bool)
 
         padded = engine_padded_size(
-            m, self._n_shards, pad_to=self._pad_to, pad_pow2=self._pad_pow2
+            m, self._n_shards, pad_to=self._pad_to
         )
         if padded != m:
             pad = padded - m
@@ -575,7 +574,7 @@ class ShardedFusedEd25519Verifier(_MeshEngine, FusedEd25519BatchVerifier):
             messages, signatures, public_keys
         )
         padded = engine_padded_size(
-            n, self._n_shards, pad_to=self._pad_to, pad_pow2=self._pad_pow2
+            n, self._n_shards, pad_to=self._pad_to
         )
         sig_rows, key_rows, n_blocks, host_ok = _pad_wave(
             [sig_rows, key_rows, n_blocks, host_ok], n, padded

@@ -52,23 +52,15 @@ def engine_padded_size(
     n_shards: int,
     *,
     pad_to: int = 0,
-    pad_pow2: bool = True,
     minimum: int = 8,
 ) -> int:
     """Mesh-aligned padded batch size honouring the engine's padding knobs
-    (``pad_to`` pins one compiled shape, ``pad_pow2`` grows by doubling),
+    (``pad_to`` pins one compiled shape, anything wider grows by doubling),
     then rounded UP to a multiple of the mesh size so every shard gets an
     equal slice."""
     if pad_to >= n:
-        size = pad_to
-    elif pad_pow2:
-        size = minimum
-        while size < n:
-            size *= 2
-    else:
-        size = max(n, 1)
-    size += (-size) % n_shards
-    return size
+        return pad_to + (-pad_to) % n_shards
+    return mesh_padded_size(n, n_shards, minimum)
 
 
 def _default_axis_names(ndim: int) -> tuple:
@@ -217,8 +209,7 @@ def apply_compile_cache() -> str:
     """Turn on jax's persistent compilation cache — the ONE place this
     program decides where compiled kernels are kept, called by every entry
     point that compiles (the rig sidecar, tests/conftest.py,
-    ``__graft_entry__``, ``bench.py``, ``benchmarks/*``, ``chip_smoke.py``'s
-    lane children).
+    ``__graft_entry__``, ``chip_smoke.py``'s lane children).
 
     ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set jax reads it by
     itself and this function sets NO directory, so an operator (or the

@@ -15,8 +15,8 @@ Two implementations:
   replica cannot tunnel state through a side channel, and every byte a test
   syncs has survived encode→decode.
 * :class:`TcpSyncTransport` + :class:`SyncListener` — real sockets with
-  u32-length framing, for realtime deployments (benchmarks, the example
-  orderer).
+  u32-length framing, for realtime deployments (the ``deploy/`` rig, the
+  example orderer).
 
 Both honor an armed :class:`~consensus_tpu.testing.faults.FaultPlan` through
 the ``sync.fetch.io_error`` (survivable fetch failure) and

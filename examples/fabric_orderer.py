@@ -45,7 +45,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks._harness import start_feeder, start_replicas, teardown
+from examples._cluster import start_feeder, start_replicas, teardown
 from consensus_tpu.config import Configuration
 from consensus_tpu.models import Ed25519Signer, Ed25519VerifierMixin
 from consensus_tpu.models.ed25519 import Ed25519BatchVerifier

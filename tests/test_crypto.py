@@ -12,14 +12,12 @@ import pytest
 import jax.numpy as jnp
 
 from consensus_tpu.models import (
-    BatchCoalescer,
     Ed25519BatchVerifier,
     Ed25519Signer,
     Ed25519VerifierMixin,
 )
 from consensus_tpu.ops import ed25519 as ed
 from consensus_tpu.ops import field25519 as fe
-from consensus_tpu.runtime import SimScheduler
 from consensus_tpu.types import Proposal, Signature
 
 
@@ -290,7 +288,7 @@ class TestBatchVerifier:
 
     def test_pow2_padding_returns_exact_length(self):
         msgs, sigs, keys = make_sigs(5)
-        ok = Ed25519BatchVerifier(pad_pow2=True).verify_batch(msgs, sigs, keys)
+        ok = Ed25519BatchVerifier().verify_batch(msgs, sigs, keys)
         assert ok.shape == (5,) and ok.all()
 
     def test_host_fallback_matches_device(self):
@@ -400,42 +398,6 @@ class TestPowChain:
         got = np.asarray(fe.freeze(jax.jit(fe.pow_2_252_m3)(x)))
         for i, v in enumerate(vals):
             assert fe.limbs_to_int(got[:, i]) == pow(v, (fe.P - 5) // 8, fe.P)
-
-
-class TestCoalescer:
-    def test_merges_submissions_into_one_batch(self):
-        s = SimScheduler()
-        calls = []
-
-        def run(items):
-            calls.append(list(items))
-            return [x * 2 for x in items]
-
-        c = BatchCoalescer(s, run, window=0.002, max_batch=100)
-        got = {}
-        c.submit([1, 2], lambda r: got.update(a=list(r)))
-        c.submit([3], lambda r: got.update(b=list(r)))
-        assert calls == []  # window open, nothing flushed yet
-        s.advance(0.002)
-        assert calls == [[1, 2, 3]]
-        assert got == {"a": [2, 4], "b": [6]}
-
-    def test_max_batch_flushes_early(self):
-        s = SimScheduler()
-        calls = []
-        c = BatchCoalescer(s, lambda items: (calls.append(len(items)), items)[1],
-                           window=10.0, max_batch=4)
-        c.submit([1, 2], lambda r: None)
-        c.submit([3, 4], lambda r: None)
-        assert calls == [4]  # flushed without waiting for the window
-        assert s.now() == 0.0
-
-    def test_empty_submission_completes_immediately(self):
-        s = SimScheduler()
-        c = BatchCoalescer(s, lambda items: items, window=1.0)
-        out = []
-        c.submit([], out.append)
-        assert out == [[]]
 
 
 class TestThreadCoalescer:

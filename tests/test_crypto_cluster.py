@@ -101,8 +101,7 @@ def test_forged_commit_rejected_by_real_crypto():
 
 def test_signed_requests_batch_verified_per_proposal():
     """SignedRequestApp: client-request signatures are verified as ONE
-    engine batch per proposal (the integrated bench path,
-    benchmarks/chain_crypto_tps.py), and tampered requests are rejected."""
+    engine batch per proposal, and tampered requests are rejected."""
     import pytest
 
     from consensus_tpu.models import Ed25519Signer

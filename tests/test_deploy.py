@@ -497,8 +497,8 @@ def test_device_sidecar_refuses_to_serve_without_a_tpu(tmp_path):
 
 
 def test_orchestrator_side_opens_no_jax_backend(tmp_path):
-    """What chip_smoke.py, scripts/soak.py and ``bench.py deploy`` do in
-    their own process — import the rig, mint a spec, build a launcher, sign
+    """What chip_smoke.py and scripts/soak.py do in their own
+    process — import the rig, mint a spec, build a launcher, sign
     requests, verify on the host — initialises no jax backend: the chip
     stays free for the sidecar."""
     import subprocess
@@ -506,7 +506,7 @@ def test_orchestrator_side_opens_no_jax_backend(tmp_path):
     code = """
 import sys, tempfile
 sys.path.insert(0, %r)
-import bench, chip_smoke
+import chip_smoke
 import scripts.soak
 from consensus_tpu.deploy import ClusterLauncher, ClusterSpec
 from consensus_tpu.deploy.identity import make_client_keyring

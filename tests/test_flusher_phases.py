@@ -43,8 +43,10 @@ class _PhasedEngine:
 
     def __init__(self, planted: dict, outside: float = 0.0) -> None:
         self.planted, self.outside, self.calls = planted, outside, []
+        self.threads = []  # the thread each call ran on
 
     def verify_batch(self, msgs, sigs, keys):
+        self.threads.append(threading.get_ident())
         self.calls.append(len(msgs))
         for name in VERIFY:
             with phase(name, cpu=(name == "verify.prepare")):
@@ -66,6 +68,30 @@ class _SlowEdges(ThreadCoalescingVerifier):
     def _deliver(self, *args):
         time.sleep(self.deliver_s)
         return super()._deliver(*args)
+
+
+class _Seen(ThreadCoalescingVerifier):
+    """A coalescer that counts what a causal test waits to SEE before its
+    next step: submissions that have joined the queue, and windows the
+    flusher has opened (it woke, or came back from a launch, to work)."""
+
+    joined = windows = 0
+
+    def _enqueue(self, items):
+        super()._enqueue(items)
+        with self._cv:
+            self.joined += 1
+
+    def _wait_window(self, *args):
+        self.windows += 1  # the flusher's alone, under ``_cv``
+        return super()._wait_window(*args)
+
+
+def _until(seen, timeout: float = 30.0) -> None:
+    deadline = time.monotonic() + timeout
+    while not seen():
+        assert time.monotonic() < deadline, "never seen"
+        time.sleep(0.0005)
 
 
 def _wave(n: int):
@@ -137,63 +163,88 @@ def test_a_flush_is_counted_by_how_full_of_hard_cap_it_is(signatures, bucket):
 
 
 def test_submissions_that_share_a_flush_each_count_their_own_wait():
-    """Three callers, 40 ms apart, ride ONE flush (the window is 200 ms):
-    three submissions, one flush over half full, and a queue wait that is
-    the sum of theirs (about 200 + 160 + 120 ms), not the flush's."""
+    """Three callers, each started 40 ms after the one before it was seen in
+    the queue, ride ONE flush (the window is a second): three submissions,
+    one flush over half full, and a queue wait that is the sum of theirs
+    (each from its own arrival to the end of the window), not the flush's."""
+    window = 1.0
     engine = _PhasedEngine({})
-    v = ThreadCoalescingVerifier(engine, window=0.2, max_batch=1000,
-                                 hard_cap=100)
+    v = _Seen(engine, window=window, max_batch=1000, hard_cap=100)
     before = FLUSHER.snapshot()
-    threads = []
-    for _ in range(3):
+    born = time.monotonic_ns()  # before the first is queued: the window ends later
+    threads, seen = [], []
+    for n in range(3):
         t = threading.Thread(target=lambda: v.verify_batch(*_wave(20)))
         t.start()
         threads.append(t)
+        _until(lambda: v.joined > n)
+        seen.append(time.monotonic_ns())  # after it was queued
         time.sleep(0.04)
     for t in threads:
-        t.join(timeout=10.0)
+        t.join(timeout=30.0)
         assert not t.is_alive()
     v.close()
     got = _since(before)
     assert engine.calls == [60]
     assert (got["submissions"], got["flushes"]) == (3, 1)
     assert got["fill_le_75"] == 1
-    assert 400 * MS <= got["queue_wait_ns"] < 560 * MS
-    assert 190 * MS <= got["wave.wait_window"] < 240 * MS
+    assert got["wave.wait_window"] >= window * 1e9
+    # Each waited from its arrival (before it was seen) to the take (after
+    # the window, which opened after the first was born): nominally
+    # 1,000 + 960 + 920 ms, and under any load more than the flush's own.
+    owed = sum(born + int(window * 1e9) - at for at in seen)
+    assert got["queue_wait_ns"] >= owed
+    assert got["queue_wait_ns"] > got["wave.wait_window"]
 
 
 # -- the adaptive hold: ``window`` is its floor ------------------------------
 #
-# A loaded machine stretches every gap below, so a hold may last several
-# times what the stragglers nominally take, and no test reads more from the
-# learning bursts than their sums.
+# These tests are causal, not timed: a step follows from what the test has
+# SEEN (a submission in the queue, the flusher's window open, a launch
+# running), never from a sleep that was meant to be long enough.  The sleeps
+# left are the nominal gaps between a burst's submitters, which a loaded
+# machine may stretch: the launches are long enough that what a hold may
+# last (a quarter of one) is ten times and more what a burst nominally
+# takes.  Counts are exact; durations are bounded from below, and from
+# above only by what a hold could have lasted.
 
 
 def _burst(v, k: int, gap: float, size: int = 10) -> None:
-    """``k`` submitters, ``gap`` seconds apart, each on a thread of its own;
-    returns once every one has its verdicts."""
+    """``k`` submitters, each on a thread of its own and each started
+    ``gap`` seconds after the one before it was SEEN in the queue; returns
+    once every one has its verdicts."""
     threads = []
     for _ in range(k):
+        joined = v.joined
         t = threading.Thread(target=lambda: v.verify_batch(*_wave(size)))
         t.start()
         threads.append(t)
+        _until(lambda: v.joined > joined)
         time.sleep(gap)
     for t in threads:
-        t.join(timeout=10.0)
+        t.join(timeout=30.0)
         assert not t.is_alive()
 
 
 def _learned(k: int, launch: float, *, window: float = 0.002, gap: float = 0.0,
-             hard_cap: int = 1000, size: int = 10):
+             hard_cap: int = 1000, size: int = 10, cut: bool = False):
     """A coalescer over an engine whose launch takes ``launch`` seconds,
     that has seen a lone wave (as the sidecar's warm-up is: it measures the
     launch time) and then two bursts of ``k``.  Where the floor cut the
-    first, its tail was counted with it, so the second was held for."""
+    first (``cut``: always, the rest start once the head's launch runs), its
+    tail was counted with it, so the second was held for."""
     engine = _PhasedEngine({"verify.await": launch})
-    v = ThreadCoalescingVerifier(engine, window=window, max_batch=hard_cap,
-                                 hard_cap=hard_cap)
+    v = _Seen(engine, window=window, max_batch=hard_cap, hard_cap=hard_cap)
     assert v.verify_batch(*_wave(size)).all()
-    _burst(v, k, gap, size)
+    if cut:
+        head = threading.Thread(target=_burst, args=(v, 1, 0.0, size))
+        head.start()
+        _until(lambda: len(engine.calls) == 2)  # the head went alone
+        _burst(v, k - 1, gap, size)
+        head.join(timeout=30.0)
+        assert not head.is_alive()
+    else:
+        _burst(v, k, gap, size)
     _burst(v, k, gap, size)
     assert sum(engine.calls) == (1 + 2 * k) * size
     return v, engine
@@ -202,10 +253,10 @@ def _learned(k: int, launch: float, *, window: float = 0.002, gap: float = 0.0,
 @pytest.mark.parametrize("k", [4, 7])
 def test_a_staggered_burst_rides_one_flush_once_learned(k):
     """k submitters 5 ms apart — two and a half floor windows between
-    neighbours, 15 and 30 ms in all — against the 150 ms that a 600 ms
-    launch lets a hold last."""
+    neighbours, 15 and 30 ms in all — against the 500 ms that a 2 s launch
+    lets a hold last."""
     before = FLUSHER.snapshot()
-    v, engine = _learned(k, launch=0.6, gap=0.005)
+    v, engine = _learned(k, launch=2.0, gap=0.005, cut=True)
     # Unlearned, the floor cut the first burst; the second rode ONE flush.
     lone, *cut, held = engine.calls
     assert (lone, sum(cut), held) == (10, 10 * k, 10 * k) and len(cut) >= 2
@@ -213,22 +264,29 @@ def test_a_staggered_burst_rides_one_flush_once_learned(k):
     assert (learning["hold_met"], learning["hold_expired"]) == (1, 0)
     n = len(engine.calls)
     before = FLUSHER.snapshot()
-    _burst(v, k, 0.005)
+    windows = v.windows
+    head = threading.Thread(target=_burst, args=(v, 1, 0.005))
+    head.start()
+    _until(lambda: v.windows > windows)  # the flusher woke: it holds from here
+    _burst(v, k - 1, 0.005)
+    head.join(timeout=30.0)
+    assert not head.is_alive()
     v.close()
     got = _since(before)
     assert engine.calls[n:] == [10 * k]
     assert (got["flushes"], got["submissions"]) == (1, k)
     assert (got["hold_met"], got["hold_expired"]) == (1, 0)
-    # It held for the stragglers, and not to the end of what it may.
-    assert 5 * (k - 1) * MS <= got["wave.wait_window"] < 140 * MS
+    # It held for the stragglers (the last was started k - 2 gaps after the
+    # flusher was seen holding), and not to the end of what it may.
+    assert 5 * (k - 2) * MS <= got["wave.wait_window"] < 500 * MS
 
 
 def test_a_lone_submitter_pays_the_cap_a_bounded_number_of_times():
     """After bursts of 4, lone submissions: the first is released when the
-    cap (a quarter of the 300 ms launch) runs out; each such hold counts as
+    cap (a quarter of the 600 ms launch) runs out; each such hold counts as
     a burst of one, so the expectation decays and the later ones wait only
     the floor."""
-    v, engine = _learned(4, launch=0.3, window=0.005)
+    v, engine = _learned(4, launch=0.6, window=0.005)
     n = len(engine.calls)
     waits, expired = [], []
     for _ in range(6):
@@ -239,10 +297,12 @@ def test_a_lone_submitter_pays_the_cap_a_bounded_number_of_times():
         expired.append(got["hold_expired"])
         assert got["hold_met"] == 0 and got["flushes"] == 1
     v.close()
-    assert 75 * MS <= waits[0] < 105 * MS and expired[0] == 1
+    assert waits[0] >= 150 * MS and expired[0] == 1
     # Bounded: at most 5 of the last 8 bursts have to be lone ones.
     assert 1 <= sum(expired) <= 5 and expired == sorted(expired, reverse=True)
-    assert expired[-3:] == [0, 0, 0] and max(waits[-3:]) < 35 * MS
+    # The floor is 5 ms: thirty of them fit under the cap.
+    assert expired[-3:] == [0, 0, 0] and max(waits[-3:]) < 150 * MS
+    assert min(waits) >= 5 * MS
     assert engine.calls[n:] == [10] * 6
 
 
@@ -256,8 +316,7 @@ def test_the_cap_follows_the_measured_launch_time(launch):
     assert v.verify_batch(*_wave(10)).all()
     v.close()
     got = _since(before)
-    cap = max(0.01, 0.25 * launch) * 1e9
-    assert cap <= got["wave.wait_window"] < cap + 40 * MS
+    assert got["wave.wait_window"] >= max(0.01, 0.25 * launch) * 1e9
     # Under the floor there is nothing to hold for: no hold is counted.
     assert got["hold_expired"] == (1 if launch > 0.04 else 0)
     assert got["hold_met"] == 0
@@ -270,7 +329,7 @@ def test_a_burst_that_cannot_fit_hard_cap_flushes_without_holding(
     """Bursts the flusher has learned to expect, of which hard_cap holds
     only 2 (or 3) submissions: with that many pending the next is not waited
     for, and what the launch left behind goes with the floor."""
-    v, engine = _learned(k, launch=0.4, gap=0.005, hard_cap=hard_cap, size=size)
+    v, engine = _learned(k, launch=1.2, gap=0.005, hard_cap=hard_cap, size=size)
     assert max(engine.calls) <= hard_cap
     n = len(engine.calls)
     before = FLUSHER.snapshot()
@@ -280,47 +339,49 @@ def test_a_burst_that_cannot_fit_hard_cap_flushes_without_holding(
     assert engine.calls[n:] == flushes
     assert (got["hold_met"], got["hold_expired"]) == (0, 0)
     # The head waited for its neighbours (5 ms each), the tail not at all:
-    # neither the 100 ms a hold could have lasted.
-    assert got["wave.wait_window"] < 60 * MS
+    # neither the 300 ms a hold could have lasted.
+    assert got["wave.wait_window"] < 300 * MS
 
 
 @pytest.mark.parametrize("queued, floor_waits", [(3, 0), (1, 1)])
 def test_submissions_queued_during_a_launch_never_wait_for_a_hold(
         queued, floor_waits):
-    """Expecting bursts of 3, with a 50 ms floor and a 600 ms launch (a hold
-    may last 150 ms): what queues while a launch runs goes at once on its
+    """Expecting bursts of 3, with a 50 ms floor and a 2 s launch (a hold
+    may last 500 ms): what queues while a launch runs goes at once on its
     return if the expected burst is there, and with the floor if not."""
-    v, engine = _learned(3, launch=0.6, window=0.05)
+    v, engine = _learned(3, launch=2.0, window=0.05)
     n = len(engine.calls)
     before = FLUSHER.snapshot()
     head = threading.Thread(target=_burst, args=(v, 3, 0.0))
     head.start()
-    time.sleep(0.3)  # the head's launch is running
+    _until(lambda: len(engine.calls) > n)  # the head's launch is running
     _burst(v, queued, 0.0)
-    head.join(timeout=10.0)
+    head.join(timeout=30.0)
     assert not head.is_alive()
     v.close()
     got = _since(before)
     assert engine.calls[n:] == [30, 10 * queued]
     assert (got["hold_met"], got["hold_expired"]) == (0, 0)
-    # The head was all there within the floor and went at once, too.
-    waited = floor_waits * 50 * MS
-    assert waited <= got["wave.wait_window"] < waited + 45 * MS
+    # The head was all there within the floor and went at once, too: what
+    # was waited is the floor's, nowhere near a hold's 500 ms.
+    assert floor_waits * 50 * MS <= got["wave.wait_window"] < 500 * MS
 
 
 def test_close_ends_a_hold_at_once():
-    v, engine = _learned(3, launch=0.6, window=0.01)
+    v, engine = _learned(3, launch=1.2, window=0.01)
     n = len(engine.calls)
     before = FLUSHER.snapshot()
+    windows = v.windows
     lone = threading.Thread(target=_burst, args=(v, 1, 0.0))
     lone.start()
-    time.sleep(0.04)  # held: 150 ms is what the hold may last
+    _until(lambda: v.windows > windows)  # held: 300 ms is what the hold may last
+    time.sleep(0.04)
     v.close()
-    lone.join(timeout=10.0)
+    lone.join(timeout=30.0)
     got = _since(before)
     assert not lone.is_alive() and not v._thread.is_alive()
     assert engine.calls[n:] == [10]  # served all the same
-    assert 35 * MS <= got["wave.wait_window"] < 110 * MS
+    assert 40 * MS <= got["wave.wait_window"] < 300 * MS
     assert (got["hold_met"], got["hold_expired"]) == (0, 0)
 
 
@@ -330,16 +391,67 @@ def test_the_phases_close_the_threads_life_with_holds_in_it():
     for the thread's life."""
     before = FLUSHER.snapshot()
     t_born = time.monotonic_ns()
-    v, engine = _learned(4, launch=0.4, gap=0.005)
+    v, engine = _learned(4, launch=1.2, gap=0.005)
     assert v.verify_batch(*_wave(10)).all()  # lone: its hold runs out
     v.close()
     lifetime = time.monotonic_ns() - t_born
     assert not v._thread.is_alive()
     got = _since(before)
     assert got["hold_met"] >= 1 and got["hold_expired"] == 1
-    assert got["wave.wait_window"] >= (15 + 100) * MS
+    assert got["wave.wait_window"] >= 300 * MS
     accounted = sum(got[name] for name in WAVE) + got["engine_ns"]
     assert 0.98 * lifetime <= accounted <= lifetime, (accounted, lifetime)
+
+
+# -- the early paths of ``verify_batch``: none of them reaches the flusher ----
+
+
+def _flusher_untouched(v, before) -> bool:
+    """Nothing was queued, flushed or held since ``before``, and the flusher
+    booked nothing (the ``verify.*`` phases are the engine's, on whatever
+    thread it runs).  Read before ``close``: the flusher's one long
+    ``wave.wait_work`` is booked when it ends."""
+    return (v.joined, v.windows) == (0, 0) and not any(
+        ns for name, ns in _since(before).items() if not name.startswith("verify."))
+
+
+def test_an_empty_submission_returns_at_once_with_no_flush():
+    engine = _PhasedEngine({})
+    v = _Seen(engine, window=0.001, max_batch=100, hard_cap=100)
+    before = FLUSHER.snapshot()
+    got = v.verify_batch([], [], [])
+    assert got.shape == (0,) and got.dtype == bool
+    assert _flusher_untouched(v, before) and engine.calls == []
+    v.close()
+
+
+def test_lists_of_unequal_length_are_refused_before_anything_is_queued():
+    engine = _PhasedEngine({})
+    v = _Seen(engine, window=0.001, max_batch=100, hard_cap=100)
+    before = FLUSHER.snapshot()
+    for lengths in [(3, 2, 3), (3, 3, 2), (0, 1, 1)]:
+        msgs, sigs, keys = ([b"x"] * n for n in lengths)
+        with pytest.raises(ValueError, match="length mismatch"):
+            v.verify_batch(msgs, sigs, keys)
+    assert _flusher_untouched(v, before) and engine.calls == []
+    v.close()
+
+
+def test_a_submission_under_bypass_below_is_verified_on_the_callers_thread():
+    """The sidecar passes ``min_device_batch``: a wave the engine would
+    route to its host path anyway pays no window, no hold and no launch
+    slot, and the flusher ledger's ``submissions`` never sees it."""
+    engine = _PhasedEngine({})
+    v = _Seen(engine, window=0.5, max_batch=100, hard_cap=100, bypass_below=16)
+    before = FLUSHER.snapshot()
+    assert v.verify_batch(*_wave(15)).all()
+    assert engine.calls == [15] and engine.threads == [threading.get_ident()]
+    assert _flusher_untouched(v, before)
+    assert v.verify_batch(*_wave(16)).all()  # at the bound it rides a flush
+    assert engine.calls == [15, 16] and engine.threads[1] == v._thread.ident
+    got = _since(before)
+    assert (got["submissions"], got["flushes"], v.joined) == (1, 1, 1)
+    v.close()
 
 
 def test_a_flush_that_raises_is_still_counted_and_served_from_the_host():

@@ -96,7 +96,6 @@ def test_engine_for_config_device_prep_routing():
     from consensus_tpu.parallel import ShardedFusedEd25519Verifier
 
     class Cfg:
-        crypto_pad_pow2 = True
         crypto_tpu_min_batch = 4
         batch_verify_mode = False
         device_prep = True

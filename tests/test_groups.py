@@ -355,35 +355,3 @@ def test_chaos_sweep_script_groups():
     assert record["ok"]
     assert set(record["resolution"]) == {"group-0", "group-1"}
     assert len(set(record["resolution"].values())) == 1
-
-
-def test_bench_groups_family_records_the_shared_fleet_win():
-    """The host-side ``groups`` bench family must produce a well-formed
-    record whose structural fields pin the coalescing win: 4x the cert
-    work of the 1-group shape through FEWER than 4x the launches, with
-    the histogram accounting for every signature.  Calls bench_groups()
-    in-process so the last-good trail is untouched."""
-    import os
-    import sys
-
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo_root)
-    try:
-        import bench
-    finally:
-        sys.path.remove(repo_root)
-
-    rec = bench.bench_groups()
-    assert rec["metric"] == "groups_aggregate_throughput"
-    assert rec["unit"] == "tx/sec"
-    assert rec["value"] > 0
-    by = rec["by_groups"]
-    assert set(by) == {str(s) for s in bench.GROUPS_SHAPES}
-    # Identical per-group load scaled out: 4x the signatures...
-    assert by["4"]["total_signatures"] == 4 * by["1"]["total_signatures"]
-    # ...through fewer than 4x the launches — the coalescing win.
-    assert by["4"]["launches"] < 4 * by["1"]["launches"]
-    assert rec["multi_group_launches"] >= 1
-    assert sum(
-        int(size) * k for size, k in rec["launch_histogram"].items()
-    ) == by["4"]["total_signatures"]

@@ -227,25 +227,3 @@ def test_engine_knobs_inherited():
     engine = Ed25519BatchVerifier(min_device_batch=10**9)
     agg = HalfAggregator(engine=engine)
     assert agg._min_device_batch == 10**9  # rides the host twin like the engine
-
-
-# --- bench.py cert_verify family: no exit 0 without a chip -------------------
-
-
-def test_bench_cert_verify_without_tpu_fails_and_replays_nothing():
-    """``bench.py cert_verify`` on a TPU-less host must exit non-zero and
-    print no record — no skip line, no stale ``last_good`` trail."""
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "cert_verify"],
-        cwd=repo, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode != 0, proc.stdout + proc.stderr
-    assert "need a TPU" in proc.stderr
-    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert "last_good" not in proc.stdout + proc.stderr

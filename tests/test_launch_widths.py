@@ -125,13 +125,10 @@ def test_a_wave_rides_the_narrowest_width_that_holds_it(ladder, n, width):
 @pytest.mark.parametrize("n", [1, 8, 9, TOP, TOP + 1, 5 * TOP])
 def test_a_single_width_engine_pads_as_before(pad_to, n):
     """One width (or none) is the rule it was: ``pad_to`` if the wave fits,
-    else the next power of two, 8-lane floor (or ``n`` without pad_pow2)."""
+    else the next power of two, 8-lane floor."""
     was = pad_to if pad_to >= n else model._next_pow2(n)
     assert Ed25519BatchVerifier(pad_to=pad_to).launch_width(n) == was
     assert Ed25519BatchVerifier(pad_to=(pad_to,)).launch_width(n) == was
-    assert Ed25519BatchVerifier(pad_to=pad_to, pad_pow2=False).launch_width(n) == (
-        pad_to if pad_to >= n else n
-    )
 
 
 @pytest.mark.parametrize("width", [HALF, TOP])

@@ -66,9 +66,8 @@ def test_engine_padded_size_honours_knobs_and_shard_multiple():
     assert engine_padded_size(9, 8) == 16
     # pad_to wins when it covers the batch
     assert engine_padded_size(5, 4, pad_to=12) == 12
-    # exact padding still lands on a shard multiple
-    assert engine_padded_size(10, 8, pad_pow2=False) == 16
-    assert engine_padded_size(10, 5, pad_pow2=False) == 10
+    # a doubled size that is no shard multiple is rounded up to one
+    assert engine_padded_size(10, 5) == 20
 
 
 def test_mesh_for_shards_errors_are_loud():
@@ -123,12 +122,11 @@ def test_engine_for_config_selects_the_full_matrix():
 
 def test_engine_for_config_threads_pad_and_min_batch_knobs():
     cfg = dataclasses.replace(
-        Configuration(), mesh_shards=8, crypto_tpu_min_batch=7,
-        crypto_pad_pow2=False,
+        Configuration(), mesh_shards=8, crypto_tpu_min_batch=7
     )
-    eng = engine_for_config(cfg)
+    eng = engine_for_config(cfg, pad_to=64)
     assert eng._min_device_batch == 7
-    assert eng._pad_pow2 is False
+    assert eng._pad_to == 64
 
 
 # --- exact parity: 8-way host mesh vs single device -------------------------

@@ -468,8 +468,8 @@ def test_instrumented_jit_counts_launches_compiles_and_retraces():
 
 
 def test_signature_models_route_through_the_kernel_registry():
-    """The module-level verify kernels must be wrapped, so bench.py's live
-    path sees launches without any bench-side plumbing."""
+    """The module-level verify kernels must be wrapped, so the sidecar's
+    ``health`` sees launches without any plumbing of its own."""
     from consensus_tpu.models import ed25519
 
     assert getattr(ed25519._verify_kernel, "__wrapped__", None) is not None

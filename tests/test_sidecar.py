@@ -3,7 +3,7 @@ socket front (SURVEY §7 step 9; VERDICT r3 #2 deployment shape).
 
 These tests run server + clients in one process (threads stand in for the
 replica processes — the socket boundary is identical); the cross-process
-path is exercised by benchmarks/chain_crypto_mp.py.
+path is exercised by tests/test_zz_deploy_rig.py.
 """
 
 import threading
