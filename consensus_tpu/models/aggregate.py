@@ -46,8 +46,6 @@ from consensus_tpu.models.ed25519 import (
     _Z_WINDOWS,
     Ed25519BatchVerifier,
     L,
-    _bits_to_comb_digits8,
-    _bytes_rows_to_bits,
     _next_pow2,
     _prep_compressed,
     _ref_add,
@@ -297,7 +295,7 @@ class HalfAggregator:
         zk_digits = (zk_digits + 8).astype(np.uint8)
         z_digits = (z_digits + 8).astype(np.uint8)
         u_row = np.frombuffer(u.to_bytes(32, "little"), dtype=np.uint8).reshape(1, 32)
-        zs_digits8 = _bits_to_comb_digits8(_bytes_rows_to_bits(u_row))
+        zs_digits8 = np.ascontiguousarray(u_row.T)  # u's bytes ARE the comb's digits
         host_ok = np.ones(m, dtype=bool)
 
         if self._pad_to >= m:

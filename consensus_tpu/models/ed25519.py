@@ -7,14 +7,16 @@ Split of labor:
 * **Host** (cheap, irregular): parse signatures, range-check ``S < L`` and
   ``y < p``, hash ``k = SHA-512(R || A || M) mod L`` (hashing is
   variable-length and byte-oriented — the wrong shape for the MXU/VPU), and
-  pack scalars/field elements into fixed-shape limb/bit arrays.
-* **Device** (the 99%: elliptic-curve math): decompress R and A, then the
+  write the bytes of R, A, S and k into ONE fixed-shape uint8 array: a
+  wave reaches the device as the bytes it came in, in one copy.
+* **Device** (the 99%: elliptic-curve math): take the bytes apart (sign
+  bits, S's 8-bit comb digits, k's signed 4-bit digits), decompress R and A, then the
   double-scalar multiplication ``[S]B + [k](-A)`` — the variable half as a
   64-step 4-bit-window ``lax.scan``, the fixed-base half as an 8-bit comb
   over constant tables — and a projective comparison against R.  Everything
   is f32 8-bit-limb arithmetic (:mod:`consensus_tpu.ops.field25519`)
   batched on the trailing axis — one compiled kernel per padded batch size
-  verifies the whole quorum.  Inputs ship as uint8 (4x less transfer).
+  verifies the whole quorum.
 
 Batches are padded to the next power of two
 so XLA compiles a handful of shapes once and reuses them forever.
@@ -35,19 +37,10 @@ from consensus_tpu.obs.kernels import instrumented_jit, kernel_lane_suffix, phas
 from consensus_tpu.ops import ed25519 as ed
 from consensus_tpu.ops import field25519 as fe
 from consensus_tpu.ops import limbs
+from consensus_tpu.ops import scalar25519 as sc
 
 #: Group order of edwards25519 (RFC 8032).
 L = 2**252 + 27742317777372353535851937790883648493
-
-_SCALAR_BITS = 256
-
-
-def _bytes_rows_to_bits(rows: np.ndarray) -> np.ndarray:
-    """(n, 32) little-endian byte rows -> (n, 256) LSB-first bit rows
-    (uint8 — every host-side array stays at the wire width; the kernel
-    widens on device)."""
-    return np.unpackbits(rows, axis=-1, bitorder="little")
-
 
 _WINDOW_BITS = 4
 _WINDOWS = 256 // _WINDOW_BITS  # 64
@@ -130,19 +123,53 @@ def verify_impl(
     return host_ok & r_ok & a_ok & ed.equal(acc, r_point)
 
 
+#: Rows of a packed wave (:func:`pack_wave`): the 32 bytes each of R, A, S
+#: and k, little-endian as on the wire, then ``host_ok``.
+_PACKED_ROWS = 4 * 32 + 1
+#: Clears bit 255 (the sign of x) of a compressed point's 32 byte rows.
+_Y_MASK = np.array([0xFF] * 31 + [0x7F], dtype=np.int32)[:, None]
+
+
+def packed_verify_impl(wave: jnp.ndarray) -> jnp.ndarray:
+    """The program a launch runs: one ``(129, batch)`` uint8 array
+    (:func:`pack_wave`) in, the verdicts out.  It takes the bytes apart on
+    the device — bit 255 of R and A is the sign of x, S's bytes ARE the
+    comb's 8-bit digits, k's bytes recode into signed 4-bit digits — and
+    hands them to :func:`verify_impl`.  Every op keeps batch trailing, so it
+    shards over the batch axis like the body."""
+    rows = wave.astype(jnp.int32)
+    r, a, s, k = (rows[i:i + 32] for i in range(0, 128, 32))
+    return verify_impl(
+        r & _Y_MASK, r[31] >> 7,
+        a & _Y_MASK, a[31] >> 7,
+        s,
+        sc.signed_window_digits(k, _WINDOWS),
+        wave[128] != 0,
+    )
+
+
 _verify_kernel = instrumented_jit(
-    verify_impl, "ed25519.verify" + kernel_lane_suffix()
+    packed_verify_impl, "ed25519.verify" + kernel_lane_suffix()
 )
 
 
 _P_BYTES_BE = np.frombuffer(fe.P.to_bytes(32, "big"), dtype=np.uint8)
 
 
-def _prep_compressed(points: Sequence[bytes]) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Compressed point bytes -> (y limbs, sign bits, y<p validity).
+def _y_lt_p(rows: np.ndarray) -> np.ndarray:
+    """(n, 32) little-endian y rows (sign bit cleared) -> y < p, the
+    canonical-range check: a lexicographic compare of the big-endian byte
+    rows against p's bytes."""
+    rows_be = rows[:, ::-1]
+    diff = rows_be != _P_BYTES_BE
+    first = np.argmax(diff, axis=1)
+    lt = rows_be[np.arange(len(rows)), first] < _P_BYTES_BE[first]
+    return np.where(diff.any(axis=1), lt, False)  # y == p is out of range too
 
-    Fully vectorized: byte rows -> unpacked bits -> grouped limb dot; the
-    canonical-range check (y < p) is a lexicographic byte comparison."""
+
+def _prep_compressed(points: Sequence[bytes]) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Compressed point bytes -> (y limbs, sign bits, y<p validity), for the
+    lanes whose kernels take limbs and signs apart on the host."""
     n = len(points)
     ok = np.ones(n, dtype=bool)
     chunks: list[bytes] = []
@@ -157,72 +184,21 @@ def _prep_compressed(points: Sequence[bytes]) -> tuple[np.ndarray, np.ndarray, n
     signs = (rows[:, 31] >> 7)  # uint8
     rows = rows.copy()
     rows[:, 31] &= 0x7F
-
-    # y < p, vectorized: compare big-endian byte rows against p's bytes.
-    rows_be = rows[:, ::-1]
-    diff = rows_be != _P_BYTES_BE
-    first = np.argmax(diff, axis=1)
-    lt = rows_be[np.arange(n), first] < _P_BYTES_BE[first]
-    ok &= np.where(diff.any(axis=1), lt, False)  # y == p is out of range too
-
+    ok &= _y_lt_p(rows)
     return rows, signs, ok  # byte-sized limbs: the bytes ARE the limbs
 
 
-def _bits_to_signed_window_digits(bits: np.ndarray) -> np.ndarray:
-    """(n, 256) LSB-first bit rows -> (64, n) SIGNED 4-bit digits in
-    [-8, 7], wire-encoded as d+8 (uint8), MSB window first.
-
-    Signed digits halve the scan's per-batch table: |d| <= 8 needs 9
-    multiples of (-A) instead of 16 (negation is two mul-free field subs
-    on device).  The LSB-to-MSB carry cannot escape: k < L < 2^253, so
-    the top window is at most 1 before carry — no 65th window ever
-    needed."""
-    weights = np.array([1, 2, 4, 8], dtype=np.int32)
-    u = bits.reshape(bits.shape[0], _WINDOWS, _WINDOW_BITS) @ weights  # (n, 64)
-    d = np.zeros_like(u)
-    carry = np.zeros(u.shape[0], dtype=u.dtype)
-    for j in range(_WINDOWS):
-        t = u[:, j] + carry
-        over = t >= 8
-        d[:, j] = np.where(over, t - 16, t)
-        carry = over.astype(u.dtype)
-    if carry.any():  # unreachable for canonical k (< 2^253)
-        raise ValueError("scalar overflow in signed-digit recoding")
-    return np.ascontiguousarray(d[:, ::-1].T + 8).astype(np.uint8)
-
-
-def _bits_to_comb_digits8(bits: np.ndarray) -> np.ndarray:
-    """(n, 256) LSB-first bit rows -> (32, n) 8-bit digits, LSB window
-    first (the comb sums windows, order-free)."""
-    weights = np.array([1, 2, 4, 8, 16, 32, 64, 128], dtype=np.int32)
-    digits = bits.reshape(bits.shape[0], 32, 8) @ weights
-    return np.ascontiguousarray(digits.T).astype(np.uint8)
-
-
-def _kernel_layout_np(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
-    """Host row-major arrays -> the kernel's layout, still on the host:
-    limbs/digits leading (on the sublanes), batch trailing (on the lanes);
-    S as 8-bit comb digits, k as MSB-first 4-bit Horner digits.  Everything
-    in the narrowest integer dtype (uint8/bool) — the kernel widens on
-    device."""
-    return (
-        np.ascontiguousarray(y_r.T),
-        sign_r,
-        np.ascontiguousarray(y_a.T),
-        sign_a,
-        _bits_to_comb_digits8(s_bits),
-        _bits_to_signed_window_digits(k_bits),
-        host_ok,
-    )
-
-
-def to_kernel_layout(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
-    """Host row-major arrays -> device layout (:func:`_kernel_layout_np`),
-    shipped to the device."""
-    return tuple(
-        jnp.asarray(a)
-        for a in _kernel_layout_np(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)
-    )
+def pack_wave(rows: np.ndarray, host_ok: np.ndarray, width: int) -> np.ndarray:
+    """What :meth:`Ed25519BatchVerifier._prepare` made of ``n`` signatures
+    -> the ONE ``(129, width)`` uint8 array a launch of ``width`` lanes
+    takes (:func:`packed_verify_impl`): bytes leading (on the sublanes),
+    batch trailing (on the lanes).  The lanes past ``n`` are zero, so their
+    ``host_ok`` is false."""
+    n = len(host_ok)
+    wave = np.zeros((_PACKED_ROWS, width), dtype=np.uint8)
+    wave[:128, :n] = rows.T
+    wave[128, :n] = host_ok
+    return wave
 
 
 def _next_pow2(n: int, minimum: int = 8) -> int:
@@ -242,8 +218,8 @@ class Ed25519BatchVerifier:
     ``pad_to`` names the launch widths a deployment compiles before it
     serves: one (every wave pads to it) or a ladder of them (a wave pads
     to the narrowest that holds it, :meth:`launch_width`) — the device's
-    time and the host's layout work both follow the PADDED width, so a
-    half-empty wave in a half-width launch costs about half.
+    time follows the PADDED width, so a half-empty wave in a half-width
+    launch costs about half.
     """
 
     def __init__(
@@ -292,58 +268,38 @@ class Ed25519BatchVerifier:
         messages: Sequence[bytes],
         signatures: Sequence[bytes],
         public_keys: Sequence[bytes],
-    ) -> tuple[np.ndarray, ...]:
-        """Host-side parse/hash/pack: returns the 7 unpadded kernel inputs
-        ``(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)``."""
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Host-side parse/hash/check: ``(rows, host_ok)`` for
+        :func:`pack_wave`, unpadded.  ``rows`` is ``(n, 128)`` uint8, a lane
+        a row: R ‖ A ‖ S ‖ k as their 32 little-endian bytes each, with
+        k = SHA-512(R ‖ A ‖ M) mod L; a lane the checks here reject (length,
+        S < L, y < p for R and A) has ``host_ok`` false and whatever of its
+        bytes parsed."""
         n = len(messages)
         host_ok = np.ones(n, dtype=bool)
-        zeros32 = b"\x00" * 32
-        r_bytes: list[bytes] = []
-        s_chunks: list[bytes] = []
-        k_chunks: list[bytes] = []
+        zeros = b"\x00" * 128
+        chunks: list[bytes] = []
         sha512 = hashlib.sha512
         from_bytes = int.from_bytes
         for i in range(n):
-            sig = signatures[i]
-            if len(sig) != 64:
+            sig, key = signatures[i], public_keys[i]
+            if len(sig) != 64 or len(key) != 32:
                 host_ok[i] = False
-                r_bytes.append(zeros32)
-                s_chunks.append(zeros32)
-                k_chunks.append(zeros32)
+                chunks.append(zeros)
                 continue
             r_raw, s_raw = sig[:32], sig[32:]
-            r_bytes.append(r_raw)
             if from_bytes(s_raw, "little") >= L:  # malleability, RFC 8032 §5.1.7
                 host_ok[i] = False
-                s_chunks.append(zeros32)
-                k_chunks.append(zeros32)
+                chunks.append(zeros)
                 continue
-            k = (
-                from_bytes(sha512(r_raw + public_keys[i] + messages[i]).digest(), "little")
-                % L
-            )
-            s_chunks.append(s_raw)
-            k_chunks.append(k.to_bytes(32, "little"))
-        # Bulk copies + one vectorized unpack (no per-row numpy calls).
-        s_rows = np.frombuffer(b"".join(s_chunks), dtype=np.uint8).reshape(n, 32)
-        k_rows = np.frombuffer(b"".join(k_chunks), dtype=np.uint8).reshape(n, 32)
-        s_bits = _bytes_rows_to_bits(s_rows)
-        k_bits = _bytes_rows_to_bits(k_rows)
-
-        y_r, sign_r, r_ok = _prep_compressed(r_bytes)
-        y_a, sign_a, a_ok = _prep_compressed(list(public_keys))
-        host_ok &= r_ok & a_ok
-        return y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok
-
-    def _padded(self, prepared: tuple, n: int) -> tuple:
-        """The prepared rows padded to the width a wave of ``n`` launches
-        at."""
-        pad = self.launch_width(n) - len(prepared[-1])
-        if not pad:
-            return prepared
-        return tuple(
-            np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) for a in prepared
-        )
+            k = from_bytes(sha512(r_raw + key + messages[i]).digest(), "little") % L
+            chunks.append(r_raw + key + s_raw + k.to_bytes(32, "little"))
+        # One bulk copy (no per-row numpy calls).
+        rows = np.frombuffer(b"".join(chunks), dtype=np.uint8).reshape(n, 128)
+        y = rows[:, :64].copy().reshape(2 * n, 32)  # R and A, lane by lane
+        y[:, 31] &= 0x7F
+        host_ok &= _y_lt_p(y).reshape(n, 2).all(axis=1)
+        return rows, host_ok
 
     def compile_ahead(self, sizes: Sequence[int]) -> None:
         """Compile, one after the other on the calling thread, the width
@@ -363,9 +319,8 @@ class Ed25519BatchVerifier:
         jitted = _verify_kernel.__wrapped__
 
         def lower(n: int):
-            arrays = _kernel_layout_np(*self._padded(self._prepare([], [], []), n))
             return jitted.lower(
-                *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays)
+                jax.ShapeDtypeStruct((_PACKED_ROWS, self.launch_width(n)), np.uint8)
             )
 
         lowered = lower(sizes[0])
@@ -398,17 +353,11 @@ class Ed25519BatchVerifier:
         # Four phases of the calling thread, in the sidecar its flusher
         # (obs/kernels.py FLUSHER_PHASES).
         with phase("verify.prepare", cpu=True):
-            y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok = self._prepare(
-                messages, signatures, public_keys
-            )
-
+            rows, host_ok = self._prepare(messages, signatures, public_keys)
         with phase("verify.layout"):
-            kernel_inputs = to_kernel_layout(*self._padded(
-                (y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok), n
-            ))
-
+            wave = jnp.asarray(pack_wave(rows, host_ok, self.launch_width(n)))
         with phase("verify.dispatch"):
-            result = _verify_kernel(*kernel_inputs)
+            result = _verify_kernel(wave)
         with phase("verify.await"):
             verdicts = np.asarray(result)
         return verdicts[:n]
@@ -521,7 +470,8 @@ def _transcript_coefficients(
 
 
 def _signed_digits_int(value: int, windows: int) -> list[int]:
-    """Host-integer twin of :func:`_bits_to_signed_window_digits`: signed
+    """Host-integer twin of
+    :func:`consensus_tpu.ops.scalar25519.signed_window_digits`: signed
     4-bit digits in [-8, 7], MSB window first.  ``windows`` must leave one
     window of headroom for the recoding carry."""
     digits = [0] * windows
@@ -735,7 +685,7 @@ class Ed25519RandomizedBatchVerifier(Ed25519BatchVerifier):
         zk_digits = (zk_digits + 8).astype(np.uint8)
         z_digits = (z_digits + 8).astype(np.uint8)
         u_row = np.frombuffer(u.to_bytes(32, "little"), dtype=np.uint8).reshape(1, 32)
-        zs_digits8 = _bits_to_comb_digits8(_bytes_rows_to_bits(u_row))
+        zs_digits8 = np.ascontiguousarray(u_row.T)  # u's bytes ARE the comb's digits
         host_ok = np.ones(m, dtype=bool)
 
         if self._pad_to >= m:

@@ -184,7 +184,7 @@ FLUSHER_PHASES = (
     "wave.take",         # _take_batch and the list joins
     "wave.deliver",      # split, done.set() (a failed flush's host serving)
     "verify.prepare",    # Ed25519BatchVerifier._prepare
-    "verify.layout",     # np.pad + to_kernel_layout: the host->device copies
+    "verify.layout",     # pack_wave + the ONE host->device copy
     "verify.dispatch",   # the _verify_kernel(...) call
     "verify.await",      # np.asarray(result): the wait for the launch
 )

@@ -107,12 +107,15 @@ def lt_l(s_bytes: jnp.ndarray) -> jnp.ndarray:
 def signed_window_digits(
     k_bytes: jnp.ndarray, windows: int = 64
 ) -> jnp.ndarray:
-    """Canonical little-endian byte rows -> signed 4-bit window digits,
-    wire-encoded ``d+8``, MSB window first — the device twin of
-    ``models.ed25519._bits_to_signed_window_digits`` /
-    ``_signed_digits_int``.  ``windows`` must leave carry headroom
-    exactly as the host versions require (64 for k < 2^253, 33 for
-    128-bit coefficients)."""
+    """Canonical little-endian byte rows -> signed 4-bit window digits in
+    [-8, 7], encoded ``d+8``, MSB window first — the device twin of
+    ``models.ed25519._signed_digits_int``, and what every strict launch
+    recodes k with.  Signed digits halve the Horner scan's per-batch
+    table: |d| <= 8 needs 9 multiples of (-A) instead of 16 (negation is
+    two mul-free field subs).  ``windows`` must leave carry headroom as
+    the host version requires (64 for k < L < 2^253: the top window is at
+    most 1 before the carry, so no 65th is ever needed; 33 for 128-bit
+    coefficients)."""
     k = k_bytes.astype(jnp.int32)
     nibbles = jnp.stack([k & 0xF, k >> 4], axis=1).reshape(
         2 * k.shape[0], k.shape[-1]

@@ -45,8 +45,8 @@ from consensus_tpu.models.ecdsa_p256 import EcdsaP256BatchVerifier
 from consensus_tpu.models.ed25519 import (
     Ed25519BatchVerifier,
     Ed25519RandomizedBatchVerifier,
-    to_kernel_layout,
-    verify_impl,
+    pack_wave,
+    packed_verify_impl,
 )
 from consensus_tpu.models.fused import FusedEd25519BatchVerifier
 from consensus_tpu.obs.kernels import (
@@ -61,19 +61,11 @@ from consensus_tpu.parallel.topology import (
     mesh_padded_size,
 )
 
-#: Device-layout partition specs: limb/bit arrays are (20|256, batch) —
-#: batch is the trailing axis; per-element vectors are (batch,).  These are
-#: the 1-D templates; :func:`_mesh_specs` widens the batch entry to the full
-#: axis-name tuple for N-D topologies.
-_IN_SPECS = (
-    P(None, BATCH_AXIS),  # y_r
-    P(BATCH_AXIS),        # sign_r
-    P(None, BATCH_AXIS),  # y_a
-    P(BATCH_AXIS),        # sign_a
-    P(None, BATCH_AXIS),  # s_bits
-    P(None, BATCH_AXIS),  # k_bits
-    P(BATCH_AXIS),        # host_ok
-)
+#: Device-layout partition specs: the strict kernel takes ONE packed wave,
+#: (129, batch) (models/ed25519.py ``pack_wave``) — batch is the trailing
+#: axis.  These are the 1-D templates; :func:`_mesh_specs` widens the batch
+#: entry to the full axis-name tuple for N-D topologies.
+_IN_SPECS = (P(None, BATCH_AXIS),)
 
 
 def _reduce_axes(mesh: Mesh):
@@ -234,8 +226,8 @@ def sharded_verify_fn(mesh: Mesh):
         in_specs=_mesh_specs(mesh, _IN_SPECS),
         out_specs=_mesh_specs(mesh, (P(BATCH_AXIS), P())),
     )
-    def _shard(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok):
-        ok = verify_impl(y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok)
+    def _shard(wave):
+        ok = packed_verify_impl(wave)
         total = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), axes)
         return ok, total
 
@@ -266,26 +258,14 @@ class ShardedEd25519Verifier(_MeshEngine, Ed25519BatchVerifier):
             return np.zeros(0, dtype=bool)
         if n < self._min_device_batch:
             return self._verify_host(messages, signatures, public_keys)
-        # Reuse the host-side preparation from the base class by padding to
-        # the mesh-aligned size before the kernel call.
-        prepped = self._prepare(messages, signatures, public_keys)
-        y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok = prepped
+        # The base class's host-side preparation, packed at the mesh-aligned
+        # width: one sharded copy.
+        rows, host_ok = self._prepare(messages, signatures, public_keys)
         padded = engine_padded_size(
             n, self._n_shards, pad_to=self._pad_to
         )
-        if padded != n:
-            pad = padded - n
-            y_r = np.pad(y_r, ((0, pad), (0, 0)))
-            y_a = np.pad(y_a, ((0, pad), (0, 0)))
-            sign_r = np.pad(sign_r, (0, pad))
-            sign_a = np.pad(sign_a, (0, pad))
-            s_bits = np.pad(s_bits, ((0, pad), (0, 0)))
-            k_bits = np.pad(k_bits, ((0, pad), (0, 0)))
-            host_ok = np.pad(host_ok, (0, pad))
-        device_args = to_kernel_layout(
-            y_r, sign_r, y_a, sign_a, s_bits, k_bits, host_ok
-        )
-        ok, _total = self._fn(*self._put_sharded(device_args))
+        wave = pack_wave(rows, host_ok, padded)
+        ok, _total = self._fn(*self._put_sharded([wave]))
         return np.asarray(ok)[:n]
 
 
@@ -437,8 +417,6 @@ class ShardedEd25519RandomizedVerifier(_MeshEngine, Ed25519RandomizedBatchVerifi
 
     def _aggregate_device(self, idx, signatures, public_keys, scalars, zs):
         from consensus_tpu.models.ed25519 import (
-            _bits_to_comb_digits8,
-            _bytes_rows_to_bits,
             _prep_compressed,
             _signed_digits_int,
             _WINDOWS,
@@ -487,7 +465,7 @@ class ShardedEd25519RandomizedVerifier(_MeshEngine, Ed25519RandomizedBatchVerifi
             u_rows[s] = np.frombuffer(
                 (u_s % L).to_bytes(32, "little"), dtype=np.uint8
             )
-        zs_digits8 = _bits_to_comb_digits8(_bytes_rows_to_bits(u_rows))
+        zs_digits8 = np.ascontiguousarray(u_rows.T)  # u's bytes ARE the comb's digits
 
         device_args = (
             np.ascontiguousarray(y_r.T),

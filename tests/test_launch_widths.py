@@ -5,6 +5,13 @@ it, and gives the same strict verdicts at either; ``instrumented_jit``
 lowers each shape once and still reads the executable's cost estimates; the
 comb table a verify kernel bakes in is built with one modular inversion.
 
+The wave goes to the device as the bytes it came in: a launch is
+``_prepare`` -> ``pack_wave`` -> ONE host->device copy -> ONE program
+(``packed_verify_impl``), whose verdicts equal the host references' on every
+rejection class at every fill of either width; the device's signed-digit
+recoding equals the host integers' on the carry's edge scalars; what the
+lanes past a wave's end hold never reaches a verdict.
+
 Compiles two small strict kernels (16 and 32 lanes) on the CPU backend.
 """
 
@@ -178,6 +185,219 @@ def test_the_ladder_compiled_one_shape_a_width_and_no_more(ladder, corpus):
         assert ladder.verify_batch(msgs[:n], sigs[:n], keys[:n]).all()
     assert stats.compiles == compiles
     assert stats.flops is None or stats.flops > 0
+
+
+# --- the packed launch path -------------------------------------------------
+
+P = 2**255 - 19
+
+
+def _flip(raw: bytes, at: int, bit: int = 1) -> bytes:
+    return raw[:at] + bytes([raw[at] ^ bit]) + raw[at + 1:]
+
+
+def _no_x_on_the_curve() -> bytes:
+    """A canonical y that is no point's: decompression fails on the device."""
+    y = 2
+    while model._ref_recover_x(y, 0) is not None:
+        y += 1
+    return y.to_bytes(32, "little")
+
+
+#: One lane of each class the judge plants (served_bench/reference.py) and
+#: the two beside them: ``plant(msgs, sigs, keys, i)`` spoils lane ``i``.
+_CLASSES = {
+    "valid": lambda m, s, k, i: None,
+    "short_signature": lambda m, s, k, i: s.__setitem__(i, s[i][:63]),
+    "short_key": lambda m, s, k, i: k.__setitem__(i, k[i][:31]),
+    "s_ge_l": lambda m, s, k, i: s.__setitem__(i, s[i][:32] + (
+        int.from_bytes(s[i][32:], "little") + L).to_bytes(32, "little")),
+    "noncanonical_r": lambda m, s, k, i: s.__setitem__(
+        i, (P + 3).to_bytes(32, "little") + s[i][32:]),
+    "noncanonical_a": lambda m, s, k, i: k.__setitem__(
+        i, (P + 5).to_bytes(32, "little")),
+    "undecodable_r": lambda m, s, k, i: s.__setitem__(
+        i, _no_x_on_the_curve() + s[i][32:]),
+    "sign_bit_of_r": lambda m, s, k, i: s.__setitem__(i, _flip(s[i], 31, 0x80)),
+    "sign_bit_of_a": lambda m, s, k, i: k.__setitem__(i, _flip(k[i], 31, 0x80)),
+    "altered_message": lambda m, s, k, i: m.__setitem__(i, m[i] + b"!"),
+    "altered_r": lambda m, s, k, i: s.__setitem__(i, _flip(s[i], 10)),
+    "altered_s": lambda m, s, k, i: s.__setitem__(i, _flip(s[i], 40)),
+    "altered_key": lambda m, s, k, i: k.__setitem__(
+        i, ref_public_key(b"nobody's signer".ljust(32))),
+}
+
+
+@pytest.mark.parametrize("n", [1, HALF - 3, HALF, HALF + 5, TOP])
+@pytest.mark.parametrize("spoiled", list(_CLASSES))
+def test_the_packed_program_gives_the_references_verdicts(
+    ladder, corpus, spoiled, n
+):
+    """One lane (the last but one, or the only one) of the class, in a wave
+    that fills its width, falls short of it, or is a single lane: the
+    device's verdicts are OpenSSL's under the strict pre-checks
+    (``verify_host``) lane by lane, and the spoiled lane's is the plain
+    integers' (``ref_verify``)."""
+    msgs, sigs, keys = (list(x[:n]) for x in corpus)
+    at = max(0, n - 2)
+    _CLASSES[spoiled](msgs, sigs, keys, at)
+    got = ladder.verify_batch(msgs, sigs, keys)
+    assert got.shape == (n,)
+    assert np.array_equal(got, ladder.verify_host(msgs, sigs, keys))
+    assert bool(got[at]) == model.ref_verify(keys[at], sigs[at], msgs[at])
+    assert got.sum() == n - (spoiled != "valid")
+
+
+def _lone_eight(window: int) -> int:
+    return 8 << (4 * window)
+
+
+#: The carry's edge scalars, all under 2^253 as k < L is: a lone 8 makes a
+#: carry, a 7 above it passes it on.
+_EDGE_SCALARS = {
+    "zero": 0,
+    "one": 1,
+    "l_minus_1": L - 1,
+    "sevens_pass_a_carry_all_the_way": int("7" * 62 + "8", 16),
+    "sevens_make_none": int("7" * 63, 16),
+    "eights_under_l": int("8" * 64, 16) % L,
+    "eights_below_the_top_window": int("8" * 63, 16),
+    **{"lone_eight_in_window_%02d" % w: _lone_eight(w) for w in range(63)},
+}
+
+
+@pytest.fixture(scope="module")
+def edge_digits():
+    """``sc.signed_window_digits`` over all the edge scalars at once, a
+    scalar a lane: name -> its 64 digits, MSB window first."""
+    from consensus_tpu.ops import scalar25519 as sc
+
+    rows = np.stack([
+        np.frombuffer(v.to_bytes(32, "little"), dtype=np.uint8)
+        for v in _EDGE_SCALARS.values()
+    ])
+    digits = np.asarray(sc.signed_window_digits(rows.T, model._WINDOWS))
+    assert digits.shape == (model._WINDOWS, len(_EDGE_SCALARS))
+    return dict(zip(_EDGE_SCALARS, digits.T))
+
+
+@pytest.mark.parametrize("name", list(_EDGE_SCALARS))
+def test_the_device_recodes_k_as_the_host_integers_do(edge_digits, name):
+    value = _EDGE_SCALARS[name]
+    want = model._signed_digits_int(value, model._WINDOWS)
+    assert (edge_digits[name] - 8).tolist() == want
+    assert sum(d << (4 * (63 - j)) for j, d in enumerate(want)) == value
+
+
+def test_a_lone_eight_in_the_top_window_is_no_scalar_under_l():
+    """What the 64 windows cannot hold the host refuses; k < L never is it."""
+    assert _lone_eight(63) > L
+    with pytest.raises(ValueError):
+        model._signed_digits_int(_lone_eight(63), model._WINDOWS)
+
+
+@pytest.mark.parametrize("tail_ok", [0, 1])
+def test_what_the_lanes_past_the_wave_hold_never_flips_a_verdict(
+    ladder, corpus, planted, tail_ok
+):
+    """A short wave written over a full one (as a buffer kept between
+    launches would hold it; none is kept): the short wave's lanes read as
+    they do from a fresh array, whatever the tail holds, and the tail, with
+    ``host_ok`` cleared, rejects.  With the tail's ``host_ok`` LEFT it reads
+    as the full wave did: the lanes are independent, and ``verify_batch``
+    cuts them off."""
+    import jax.numpy as jnp
+
+    n = 5
+    full = model.pack_wave(*ladder._prepare(*planted), HALF)
+    rows, host_ok = ladder._prepare(*(x[8:8 + n] for x in corpus))
+    fresh = model.pack_wave(rows, host_ok, HALF)
+    assert not fresh[:, n:].any()
+    kept = full.copy()
+    kept[:, :n] = fresh[:, :n]
+    if not tail_ok:
+        kept[128, n:] = 0
+    assert kept[:128, n:].any()
+    want_full = np.asarray(model._verify_kernel(jnp.asarray(full)))
+    want = np.asarray(model._verify_kernel(jnp.asarray(fresh)))
+    got = np.asarray(model._verify_kernel(jnp.asarray(kept)))
+    assert want[:n].all() and not want[n:].any()
+    assert np.array_equal(got[:n], want[:n])
+    assert np.array_equal(got[n:], want_full[n:] if tail_ok else want[n:])
+
+
+def test_a_launch_is_one_copy_and_one_program(ladder, corpus, monkeypatch):
+    """One device launch: the four ``verify.*`` phases, ONE host->device
+    copy (``jnp.asarray`` of the one ``(129, width)`` uint8 array, no
+    ``device_put``), ONE call of one jitted program with that one argument;
+    no other kernel of the ledger launches."""
+    import jax
+
+    from consensus_tpu.obs.kernels import FLUSHER
+
+    copies, puts, calls = [], [], []
+
+    class JnpSpy:  # stands in for the module's ``jnp``
+        def __getattr__(self, name):
+            return getattr(jax.numpy, name)
+
+        @staticmethod
+        def asarray(a, *args, **kw):
+            copies.append((type(a), a.shape, a.dtype))
+            return jax.numpy.asarray(a, *args, **kw)
+
+    kernel, device_put = model._verify_kernel, jax.device_put
+
+    def spy_kernel(*args, **kw):
+        calls.append([(type(a).__name__, a.shape, a.dtype) for a in args] + list(kw))
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(model, "jnp", JnpSpy())
+    monkeypatch.setattr(model, "_verify_kernel", spy_kernel)
+    monkeypatch.setattr(
+        jax, "device_put", lambda *a, **kw: puts.append(a) or device_put(*a, **kw))
+    msgs, sigs, keys = (x[:HALF + 1] for x in corpus)
+    before, launched = FLUSHER.snapshot(), KERNELS.snapshot()
+    assert ladder.verify_batch(msgs, sigs, keys).all()
+    after = FLUSHER.snapshot()
+    assert {k for k in after if after[k] != before[k]} == {
+        "verify.prepare", "verify.prepare_cpu", "verify.layout",
+        "verify.dispatch", "verify.await"}
+    assert copies == [(np.ndarray, (129, TOP), np.dtype(np.uint8))]
+    assert puts == []
+    assert calls == [[("ArrayImpl", (129, TOP), np.dtype(np.uint8))]]
+    now = KERNELS.snapshot()
+    moved = {name for name in now
+             if now[name]["launches"] != launched.get(name, {}).get("launches", 0)}
+    assert moved == {KERNEL}
+    assert now[KERNEL]["launches"] == launched[KERNEL]["launches"] + 1
+
+
+def test_compile_ahead_lowers_the_widths_from_shapes_alone(ladder, monkeypatch):
+    """``compile_ahead`` of the sidecar's two warm-up sizes prepares and
+    packs no wave and launches nothing: a width's shape is enough."""
+
+    def never(*_a, **_kw):
+        raise AssertionError("compile_ahead built a wave")
+
+    monkeypatch.setattr(Ed25519BatchVerifier, "_prepare", never)
+    monkeypatch.setattr(model, "pack_wave", never)
+    launches = KERNELS.stats(KERNEL).launches
+    lowered = []
+    jitted = model._verify_kernel.__wrapped__
+
+    class Spy:
+        def lower(self, *args):
+            lowered.append([(a.shape, a.dtype) for a in args])
+            return jitted.lower(*args)
+
+    kernel = model._verify_kernel
+    monkeypatch.setattr(kernel, "__wrapped__", Spy())
+    ladder.compile_ahead((HALF + 1, 1))
+    assert KERNELS.stats(KERNEL).launches == launches
+    shapes = {tuple(x) for x in lowered}
+    assert shapes == {(((129, TOP), np.dtype(np.uint8)),),
+                      (((129, HALF), np.dtype(np.uint8)),)}
 
 
 @pytest.mark.parametrize("fused, randomized", [(False, True), (True, False)])

@@ -236,7 +236,7 @@ def test_strict_verdict_parity_single_device(monkeypatch):
     for lane, ctx in _LANES:
         with ctx():
             monkeypatch.setattr(
-                model, "_verify_kernel", _fresh_jit(model.verify_impl)
+                model, "_verify_kernel", _fresh_jit(model.packed_verify_impl)
             )
             v = model.Ed25519BatchVerifier(min_device_batch=1)
             out[lane] = np.asarray(v.verify_batch(msgs, sigs, keys))
@@ -260,7 +260,7 @@ def test_randomized_verdict_parity_single_device(monkeypatch):
                 model, "_batch_verify_kernel", _fresh_jit(model.batch_verify_impl)
             )
             monkeypatch.setattr(
-                model, "_verify_kernel", _fresh_jit(model.verify_impl)
+                model, "_verify_kernel", _fresh_jit(model.packed_verify_impl)
             )
             v = model.Ed25519RandomizedBatchVerifier(min_device_batch=5)
             out[lane] = np.asarray(v.verify_batch(msgs, sigs, keys))
