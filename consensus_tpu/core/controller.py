@@ -190,19 +190,26 @@ class Controller:
         self._fence_release: Optional[int] = None
         self._fence_resync_timer = None
         self._wal_degraded = False
-        #: Cumulative, for ``health``: calls of the synchronizer, decisions
-        #: they brought, requests of those decisions taken out of the pool,
-        #: and rotations of the leader (a reconfiguration builds a new
-        #: controller, which counts from 0 again).
         #: seq -> [(sender, message)] in arrival order: three-phase traffic
         #: the running view could not take (it was stopped for a sync, or the
         #: message is for a later sequence), kept for the view that replaces
         #: it.  See :meth:`_keep_ahead`.
         self._ahead: dict[int, list] = {}
+        #: Cumulative, for ``health``: calls of the synchronizer, decisions
+        #: they brought, requests of those decisions taken out of the pool,
+        #: rotations of the leader, the time they took (see
+        #: :meth:`_begin_handover`) and messages :meth:`_replay_ahead` handed
+        #: to a successor view (a reconfiguration builds a new controller,
+        #: which counts from 0 again).
         self.syncs = 0
         self.synced_decisions = 0
         self.sync_pool_removed = 0
         self.leader_handovers = 0
+        self.handover_ns = 0
+        self.ahead_replayed = 0
+        #: (instant, first sequence of the successor view) of the hand-over
+        #: that is under way, else None.
+        self._handover_began: Optional[tuple[float, int]] = None
 
     # ------------------------------------------------------------ identity
 
@@ -264,6 +271,8 @@ class Controller:
             "synced_decisions": self.synced_decisions,
             "sync_pool_removed": self.sync_pool_removed,
             "leader_handovers": self.leader_handovers,
+            "handover_ns": self.handover_ns,
+            "ahead_replayed": self.ahead_replayed,
         }
 
     # ----------------------------------------------------------- lifecycle
@@ -414,6 +423,8 @@ class Controller:
                 return
             self._keep_ahead(sender, msg)
             if self.curr_view is not None:
+                if self._handover_began is not None:
+                    self._end_handover_at(self.curr_view, sender, msg)
                 self.curr_view.handle_message(sender, msg)
             if self.view_changer is not None:
                 self.view_changer.handle_view_message(sender, msg)
@@ -493,6 +504,8 @@ class Controller:
         for seq in (here, here + 1):
             for sender, msg in list(self._ahead.get(seq, ())):
                 if msg.view == view.number and not view.stopped:
+                    if self._handover_began is not None:
+                        self._end_handover_at(view, sender, msg)
                     view.handle_message(sender, msg)
                     replayed += 1
         # The next sequence's stay kept: the view after this one (a rotation
@@ -500,9 +513,53 @@ class Controller:
         for seq in [seq for seq in self._ahead if seq <= here]:
             del self._ahead[seq]
         if replayed:
+            self.ahead_replayed += replayed
             logger.info(
                 "%d: replayed %d message(s) kept for seq %d-%d into the new view",
                 self.id, replayed, here, here + 1,
+            )
+
+    # ------------------------------------------------------------ hand-over
+
+    def _begin_handover(self, next_seq: int) -> None:
+        """A delivery ended this leader's turn.  The hand-over lasts until the
+        successor view's first pre-prepare is sent (on the new leader: after
+        its sealing wait and its WAL append) or reaches the view (on a
+        follower); ``handover_ns`` sums those stretches, so over
+        ``leader_handovers`` it says how long nobody proposed."""
+        if self._handover_began is not None:
+            self._end_handover()  # its pre-prepare never came: a sync went past it
+        self._handover_began = (self._sched.now(), next_seq)
+        if self._tracer.enabled:
+            self._tracer.begin(
+                "controller",
+                "handover",
+                seq=next_seq,
+                view=self.curr_view_number,
+                leader=self.leader_id(),
+                pooled=self.pool.count,
+            )
+
+    def _end_handover_at(self, view: View, sender: int, msg) -> None:
+        """End the hand-over if ``msg`` is the pre-prepare ``view`` takes up
+        (or, from this replica, sends): its leader's, for its sequence."""
+        if (
+            isinstance(msg, PrePrepare)
+            and sender == view.leader_id
+            and msg.view == view.number
+            and msg.seq == view.proposal_sequence
+            and msg.seq >= self._handover_began[1]
+            and not view.stopped
+        ):
+            self._end_handover()
+
+    def _end_handover(self) -> None:
+        began, seq = self._handover_began
+        self._handover_began = None
+        self.handover_ns += int((self._sched.now() - began) * 1e9)
+        if self._tracer.enabled:
+            self._tracer.end(
+                "controller", "handover", seq=seq, view=self.curr_view_number
             )
 
     # --------------------------------------------------------- requests
@@ -781,14 +838,7 @@ class Controller:
         if self._check_if_rotate(md.black_list):
             logger.info("%d: rotating leader after seq %d", self.id, md.latest_sequence)
             self.leader_handovers += 1
-            if self._tracer.enabled:
-                self._tracer.instant(
-                    "controller",
-                    "rotate",
-                    seq=md.latest_sequence,
-                    view=self.curr_view_number,
-                    leader=self.leader_id(),
-                )
+            self._begin_handover(md.latest_sequence + 1)
             self.change_view(
                 self.curr_view_number, md.latest_sequence + 1, self.curr_decisions_in_view
             )
@@ -1126,6 +1176,9 @@ class Controller:
             if node == self.id:
                 continue
             self._comm.send_consensus(node, msg)
+        if self._handover_began is not None and self.curr_view is not None:
+            # the new leader's first proposal is out
+            self._end_handover_at(self.curr_view, self.id, msg)
         if isinstance(msg, (PrePrepare, Prepare, Commit)) and self.i_am_the_leader():
             self.leader_monitor.heartbeat_was_sent()
 

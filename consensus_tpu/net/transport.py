@@ -59,6 +59,10 @@ from consensus_tpu.wire import ConsensusMessage, decode_message, encode_message
 
 logger = logging.getLogger("consensus_tpu.net")
 
+#: Most bytes one write of a peer's writer carries (whole frames; a frame
+#: larger than this goes alone).
+_COALESCE_BYTES = 64 * 1024
+
 _HEADER = struct.Struct(">IQB")
 _KIND_CONSENSUS = 0
 _KIND_REQUEST = 1
@@ -525,18 +529,37 @@ class _Peer:
 
     def _writer_loop(self) -> None:
         stopped = self._comm._stopped
+        pending: "list[bytes]" = []
         while not stopped.is_set():
-            try:
-                frame = self._queue.get(timeout=0.2)
-            except queue.Empty:
-                continue
-            self._send_with_retry(frame)
+            if not pending:
+                try:
+                    pending.append(self._queue.get(timeout=0.2))
+                except queue.Empty:
+                    continue
+            # Whatever else is queued rides the same write, up to
+            # _COALESCE_BYTES: a backlog (a peer that was away, a burst of
+            # requests) leaves as a few full segments and not as thousands
+            # of 150-byte ones, each a syscall here and a buffer of its own
+            # in the receiver's kernel.
+            size = sum(map(len, pending))
+            while size < _COALESCE_BYTES:
+                try:
+                    frame = self._queue.get_nowait()
+                except queue.Empty:
+                    break
+                pending.append(frame)
+                size += len(frame)
+            del pending[: self._send_with_retry(pending)]
 
-    def _send_with_retry(self, frame: bytes) -> None:
-        """Deliver one frame, riding out a peer killed mid-frame: an abrupt
-        close during ``sendall`` reconnects and re-sends the SAME frame up
-        to ``send_retries`` times before the fire-and-forget drop."""
+    def _send_with_retry(self, frames: "list[bytes]") -> int:
+        """Deliver ``frames`` in one write, riding out a peer killed
+        mid-write: an abrupt close during ``sendall`` reconnects and
+        re-sends the SAME bytes up to ``send_retries`` times.  Returns how
+        many frames are done with: all of them once the write went out, and
+        only the first when the budget ran out — the fire-and-forget drop
+        stays one frame a budget, the rest try again with the next one."""
         metrics = self._comm.metrics
+        data = frames[0] if len(frames) == 1 else b"".join(frames)
         for attempt in range(self._comm._send_retries + 1):
             sock = self._ensure_connected()
             if sock is None:
@@ -545,8 +568,8 @@ class _Peer:
                 plan = self._comm.fault_plan
                 if plan is not None:
                     plan.io_error("net.send.io_error")
-                sock.sendall(frame)
-                return
+                sock.sendall(data)
+                return len(frames)
             except OSError:
                 self._drop_connection()
                 if attempt < self._comm._send_retries:
@@ -555,6 +578,7 @@ class _Peer:
                     continue
         if metrics is not None:
             metrics.count_send_dropped.add(1)
+        return 1
 
     def _ensure_connected(self) -> Optional[socket.socket]:
         """Bounded connect: up to ``connect_attempts`` tries with capped

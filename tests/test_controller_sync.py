@@ -692,6 +692,106 @@ def test_rotation_is_counted_in_health():
     assert h.controller.health()["leader_handovers"] == 1
 
 
+# --- the hand-over is timed to the successor view's first pre-prepare ------
+
+
+def rotating(self_turn_next: bool) -> "Harness":
+    """Node 2 of 4 with a new leader after every decision, started so that
+    the next decision hands the lead to node 2 itself or on to node 3."""
+    h = Harness()
+    h.controller._config = Configuration(
+        self_id=2, leader_rotation=True, decisions_per_leader=1, collect_timeout=1.0)
+    if self_turn_next:
+        h.start(view=0, seq=1, dec=0)  # node 1 leads seq 1, node 2 seq 2
+    else:
+        h.start(view=0, seq=2, dec=1)  # node 2 leads seq 2, node 3 seq 3
+    return h
+
+
+def decide_next(h):
+    seq = h.controller.curr_view.proposal_sequence
+    h.controller.decide(
+        proposal_at(0, seq, seq - 1, requests=[make_request("cli", seq)]), (), ())
+    return seq + 1
+
+
+def counters(h):
+    health = h.controller.health()
+    return health["leader_handovers"], health["handover_ns"], health["ahead_replayed"]
+
+
+def test_a_static_leader_hands_nothing_over():
+    h = Harness()
+    h.start()
+    for _ in range(3):
+        decide_next(h)
+        h.sched.advance(0.05)
+    h.controller.broadcast(PrePrepare(view=0, seq=4, proposal=proposal_at(0, 4, 3)))
+    assert counters(h) == (0, 0, 0)
+    assert h.controller._handover_began is None
+
+
+def test_follower_hand_over_ends_when_its_view_gets_the_new_leaders_pre_prepare():
+    h = rotating(self_turn_next=False)
+    nxt = decide_next(h)
+    assert nxt == 3 and h.controller.leader_id() == 3
+    h.sched.advance(0.02)
+    # none of these is the pre-prepare the new view will take up
+    for sender, msg in [(3, Prepare(view=0, seq=nxt, digest="d")),
+                        (1, PrePrepare(view=0, seq=nxt, proposal=proposal_at(0, nxt, 2))),
+                        (3, PrePrepare(view=0, seq=nxt + 1, proposal=proposal_at(0, nxt + 1, 3)))]:
+        h.controller.process_message(sender, msg)
+    assert counters(h) == (1, 0, 0)
+    h.sched.advance(0.01)
+    h.controller.process_message(
+        3, PrePrepare(view=0, seq=nxt, proposal=proposal_at(0, nxt, 2)))
+    assert counters(h) == (1, 30_000_000, 0)
+    h.sched.advance(0.5)  # over: nothing more is added
+    h.controller.process_message(
+        3, PrePrepare(view=0, seq=nxt, proposal=proposal_at(0, nxt, 2)))
+    assert counters(h) == (1, 30_000_000, 0)
+
+
+def test_new_leaders_hand_over_ends_when_its_first_pre_prepare_goes_out():
+    h = rotating(self_turn_next=True)
+    nxt = decide_next(h)
+    assert nxt == 2 and h.controller.i_am_the_leader()
+    h.sched.advance(0.055)  # the sealing wait and the WAL append
+    h.controller.broadcast(Prepare(view=0, seq=nxt, digest="d"))
+    assert counters(h) == (1, 0, 0)
+    h.controller.broadcast(PrePrepare(view=0, seq=nxt, proposal=proposal_at(0, nxt, 1)))
+    assert counters(h) == (1, 55_000_000, 0)
+    assert any(isinstance(m, PrePrepare) for _, m in h.sent)
+
+
+def test_early_pre_prepare_is_replayed_into_the_successor_and_counted_once():
+    h = rotating(self_turn_next=False)
+    nxt = h.controller.curr_view.proposal_sequence + 1
+    early = PrePrepare(view=0, seq=nxt, proposal=proposal_at(0, nxt, 2))
+    h.controller.process_message(3, early)  # node 3 does not lead yet: kept
+    assert counters(h) == (0, 0, 0)
+    assert decide_next(h) == nxt
+    successor = h.controller.curr_view
+    h.sched.advance(0.004)  # the replay is the scheduler's next step
+    assert successor is h.controller.curr_view
+    handovers, ns, replayed = counters(h)
+    assert (handovers, replayed) == (1, 1) and 0 <= ns <= 4_000_000
+    assert h.controller._handover_began is None
+    h.controller._replay_ahead(successor)  # nothing is left to hand over
+    assert counters(h) == (handovers, ns, 1)
+
+
+def test_hand_over_a_sync_went_past_ends_at_the_next_one():
+    h = rotating(self_turn_next=False)
+    decide_next(h)
+    h.sched.advance(0.1)
+    h.controller.change_view(0, 6, 5)  # a sync: the turn's proposal never came
+    assert counters(h) == (1, 0, 0)
+    decide_next(h)
+    assert counters(h)[:2] == (2, 100_000_000)
+    assert h.controller._handover_began is not None
+
+
 # --- three-phase traffic ahead of the view is kept for its successor ------
 
 

@@ -41,10 +41,10 @@ def delivered(node) -> list:
     return [r for d in node.app.ledger for r in unpack_batch(d.proposal.payload)]
 
 
-def leader_of(decision) -> int:
+def leader_of(decision, n: int = N) -> int:
     md = decode_view_metadata(decision.proposal.metadata)
     return get_leader_id(
-        md.view_id, N, tuple(range(1, N + 1)), leader_rotation=True,
+        md.view_id, n, tuple(range(1, n + 1)), leader_rotation=True,
         decisions_in_view=md.decisions_in_view, decisions_per_leader=PER_LEADER,
         blacklist=tuple(md.black_list),
     )
@@ -54,10 +54,13 @@ class Run:
     """The cluster, the reference (``submitted``) and a paced feed: one
     batch at a time, so that a sync ends between two decisions."""
 
-    def __init__(self, sync_mode: str) -> None:
+    def __init__(self, sync_mode: str, n: int = N, batch: int = BATCH) -> None:
+        self.batch = batch
         self.cluster = Cluster(
-            N, leader_rotation=True, sync_mode=sync_mode,
-            config_tweaks=dict(QUIET, decisions_per_leader=PER_LEADER),
+            n, leader_rotation=True, sync_mode=sync_mode,
+            config_tweaks=dict(QUIET, decisions_per_leader=PER_LEADER,
+                               request_batch_max_count=batch,
+                               request_pool_size=max(400, 8 * batch)),
         )
         self.cluster.start()
         self.submitted: list = []
@@ -66,7 +69,8 @@ class Run:
         """One proposal's worth to every replica (the cut-off one too: a
         client's connection is not the replicas' network), then run until
         ``node_ids`` delivered it."""
-        batch = [make_request("c", len(self.submitted) + i) for i in range(BATCH)]
+        batch = [make_request("c", len(self.submitted) + i)
+                 for i in range(self.batch)]
         self.submitted.extend(batch)
         for raw in batch:
             self.cluster.submit_to_all(raw)
@@ -75,7 +79,7 @@ class Run:
         assert self.cluster.scheduler.run_until(
             lambda: all(want <= set(delivered(self.cluster.nodes[i])) for i in ids),
             max_time=30.0,
-        ), f"batch {len(self.submitted) // BATCH} was not delivered by {ids}"
+        ), f"batch {len(self.submitted) // self.batch} was not delivered by {ids}"
 
 
 @pytest.mark.parametrize("sync_mode", ["wire", "toy"])
@@ -84,7 +88,20 @@ class Run:
 def test_synced_replica_leads_without_proposing_what_it_synced(
     follower, entry, sync_mode
 ):
-    run = Run(sync_mode)
+    sync_then_lead(Run(sync_mode), follower, entry)
+
+
+@pytest.mark.parametrize("entry", ["do_sync", "deliver_checked"])
+@pytest.mark.parametrize("follower", [2, 6])
+def test_synced_replica_leads_at_n7_with_hundreds_to_forget(follower, entry):
+    """BASELINE configs[2]'s committee (n=7, f=2) on the shipped rotation,
+    with batches large enough that one sync takes 200 requests out of the
+    pool (the chip runs show it at 1,000 a batch: PERF.md section 6, PR 33)."""
+    sync_then_lead(Run("wire", n=7, batch=40), follower, entry)
+
+
+def sync_then_lead(run: Run, follower: int, entry: str) -> None:
+    n, batch = len(run.cluster.nodes), run.batch
     cluster, node = run.cluster, run.cluster.nodes[follower]
     others = [i for i in cluster.nodes if i != follower]
 
@@ -99,7 +116,7 @@ def test_synced_replica_leads_without_proposing_what_it_synced(
     assert len(node.app.ledger) == cut_at
     assert all(len(cluster.nodes[i].app.ledger) == cut_at + CUT_FOR for i in others)
     controller = node.consensus.controller
-    skipped = set(run.submitted[-CUT_FOR * BATCH:])
+    skipped = set(run.submitted[-CUT_FOR * batch:])
     assert controller.pool.count == len(skipped)  # they wait in its pool
     cluster.network.connect(follower)
 
@@ -123,7 +140,7 @@ def test_synced_replica_leads_without_proposing_what_it_synced(
     rejoined_at = len(node.app.ledger)
     assert controller.pool.count == 0
     assert controller.synced_decisions == rejoined_at - cut_at
-    assert controller.sync_pool_removed == (rejoined_at - cut_at) * BATCH
+    assert controller.sync_pool_removed == (rejoined_at - cut_at) * batch
     # A copy of a synced request that arrives late is refused.
     errors: list = []
     controller.pool.submit(sorted(skipped)[0], errors.append)
@@ -133,8 +150,8 @@ def test_synced_replica_leads_without_proposing_what_it_synced(
     led = 0
     while led < 2 * PER_LEADER:
         run.feed()
-        led = sum(1 for d in node.app.ledger[rejoined_at:] if leader_of(d) == follower)
-        assert len(run.submitted) <= 60 * BATCH, "the follower never led"
+        led = sum(1 for d in node.app.ledger[rejoined_at:] if leader_of(d, n) == follower)
+        assert len(run.submitted) <= 30 * n * batch, "the follower never led"
     cluster.scheduler.advance(2.0)  # anything stale would be proposed by now
 
     for i, replica in cluster.nodes.items():

@@ -482,3 +482,120 @@ def test_tcp_comm_resume_listener_failure_stays_healable():
     finally:
         comm1.stop()
         comm2.stop()
+
+
+# --- a backlog leaves in a few writes, and a drop is still one frame --------
+#
+# Found on the chip (PERF.md section 6, PR 33): a replica whose listener had
+# been away got the client's backlog as thousands of 150-byte segments, its
+# fresh connection crawled at 10 requests a second for 11 s and more, and
+# what arrived then was stale past the pool's dedup horizon.
+
+
+class _RecordingSock:
+    def __init__(self, fail_first=0):
+        self.writes, self._fail = [], fail_first
+
+    def sendall(self, data):
+        if self._fail:
+            self._fail -= 1
+            raise OSError("peer went away mid-write")
+        self.writes.append(bytes(data))
+
+    def close(self):
+        pass
+
+
+def _peer(socks, **comm_kw):
+    """A writer whose connections are the given fakes, in turn; ``None`` is
+    a connect budget that ran out."""
+    from consensus_tpu.net import transport
+
+    comm = TcpComm(1, {1: ("127.0.0.1", 1), 2: ("127.0.0.1", 2)},
+                   lambda *a: None, send_queue_depth=10_000, **comm_kw)
+    peer = transport._Peer(comm, 2, ("127.0.0.1", 2))
+    socks = list(socks)
+
+    def ensure_connected():
+        if peer._sock is None:
+            peer._sock = socks.pop(0)
+        return peer._sock
+
+    peer._ensure_connected = ensure_connected
+    return comm, peer
+
+
+def _run_writer(comm, peer, until, timeout=5.0):
+    thread = threading.Thread(target=peer._writer_loop, daemon=True)
+    thread.start()
+    deadline = time.monotonic() + timeout
+    while not until() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    comm._stopped.set()
+    thread.join(timeout=2.0)
+    assert until()
+
+
+def test_writer_sends_a_backlog_in_few_writes_in_order():
+    from consensus_tpu.net import transport
+
+    sock = _RecordingSock()
+    comm, peer = _peer([sock])
+    frames = [b"%05d" % i + bytes(145) for i in range(1600)]  # a 0.6 s backlog
+    for frame in frames:
+        peer.enqueue(frame)
+    _run_writer(comm, peer, lambda: sum(map(len, sock.writes)) == 1600 * 150)
+    assert b"".join(sock.writes) == b"".join(frames)
+    # whole frames only, each write as full as the cap allows
+    assert all(len(w) % 150 == 0 for w in sock.writes)
+    assert len(sock.writes) <= -(-1600 * 150 // transport._COALESCE_BYTES) + 1
+    assert max(map(len, sock.writes)) < transport._COALESCE_BYTES + 150
+
+
+def test_writer_sends_a_lone_frame_at_once_and_a_huge_one_alone():
+    from consensus_tpu.net import transport
+
+    sock = _RecordingSock()
+    comm, peer = _peer([sock])
+    huge = bytes(transport._COALESCE_BYTES + 1)
+    peer.enqueue(b"lone")
+    thread = threading.Thread(target=peer._writer_loop, daemon=True)
+    thread.start()
+    _wait(lambda: len(sock.writes) >= 1)
+    assert sock.writes == [b"lone"]  # nothing waits for company
+    peer.enqueue(huge)
+    _wait(lambda: len(sock.writes) >= 2)
+    peer.enqueue(b"after")
+    _wait(lambda: len(sock.writes) >= 3)
+    comm._stopped.set()
+    thread.join(timeout=2.0)
+    assert sock.writes == [b"lone", huge, b"after"]
+
+
+def _wait(cond, timeout=5.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert cond()
+
+
+def test_write_cut_midway_is_sent_again_whole_over_a_new_connection():
+    broken, fresh = _RecordingSock(fail_first=1), _RecordingSock()
+    comm, peer = _peer([broken, fresh])
+    frames = [b"%03d" % i for i in range(40)]
+    for frame in frames:
+        peer.enqueue(frame)
+    _run_writer(comm, peer, lambda: b"".join(fresh.writes) == b"".join(frames))
+    assert broken.writes == []
+
+
+def test_exhausted_budget_drops_one_frame_and_keeps_the_rest():
+    """A peer that is away costs one frame a connect budget, as before the
+    writes were joined: what was queued behind it is not thrown away with
+    it."""
+    sock = _RecordingSock()
+    comm, peer = _peer([None, None, sock])
+    frames = [b"%03d" % i for i in range(40)]
+    for frame in frames:
+        peer.enqueue(frame)
+    _run_writer(comm, peer, lambda: b"".join(sock.writes) == b"".join(frames[2:]))

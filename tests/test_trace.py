@@ -367,10 +367,12 @@ def test_net_injected_event_keys_pinned_and_mirror_trace_instants():
 # --- rotation and the pool removal after a sync show in a decision trace ---
 
 
-def test_rotation_instants_and_sync_forget_span_in_a_decision_trace():
+def test_hand_over_spans_and_sync_forget_span_in_a_decision_trace():
     """With the source's rotation (every 3 decisions) each hand-over is a
-    ``controller`` / ``rotate`` instant naming the decision it followed and
-    the next leader; a replica that catches up by sync brackets the removal
+    ``controller`` / ``handover`` span from the delivery that ended a turn to
+    the next leader's first pre-prepare (sent, on that leader; taken up, on a
+    follower), naming the turn's first sequence, the next leader and what was
+    pooled; a replica that catches up by sync brackets the removal
     of the synced requests from its pool in ``controller`` / ``sync.forget``,
     inside neither ``sync`` (the synchronizer's call) nor a decision."""
     tweaks = _traced_tweaks(decisions_per_leader=3)
@@ -393,14 +395,21 @@ def test_rotation_instants_and_sync_forget_span_in_a_decision_trace():
     assert cluster.scheduler.run_until(
         lambda: len(lagger.app.ledger) == 17, max_time=30.0)
 
-    leader_events = [
-        ev for ev in cluster.nodes[1].consensus.tracer.events()
-        if ev[0] == "i" and ev[1] == "controller" and ev[2] == "rotate"
-    ]
-    # one hand-over after every third decision: 3 -> node 2, 6 -> 3, 9 -> 4, ...
-    assert [(ev[4], ev[6]["leader"]) for ev in leader_events] == [
-        (3, 2), (6, 3), (9, 4), (12, 1), (15, 2)]
-    assert cluster.nodes[1].consensus.controller.health()["leader_handovers"] == 5
+    # one hand-over after every third decision: seq 4 on is node 2's, 7 on 3's, ...
+    turns = [(4, 2), (7, 3), (10, 4), (13, 1), (16, 2)]
+    for node_id in (1, 2, 3):  # node 1 leads the fourth turn, node 2 two others
+        spans = [ev for ev in cluster.nodes[node_id].consensus.tracer.events()
+                 if ev[1] == "controller" and ev[2] == "handover"]
+        assert [ev[0] for ev in spans] == ["B", "E"] * len(turns)
+        begins, ends = spans[0::2], spans[1::2]
+        assert [(ev[4], ev[6]["leader"]) for ev in begins] == turns
+        assert [ev[4] for ev in ends] == [seq for seq, _ in turns]
+        # one request a decision and all delivered: nothing was pooled
+        assert all(ev[6]["pooled"] == 0 for ev in begins)
+        health = cluster.nodes[node_id].consensus.controller.health()
+        assert health["leader_handovers"] == len(turns)
+        assert health["handover_ns"] == sum(
+            int((e[3] - b[3]) * 1e9) for b, e in zip(begins, ends)) > 0
 
     events = lagger.consensus.tracer.events()
     forget = [ev for ev in events if ev[1] == "controller" and ev[2] == "sync.forget"]
