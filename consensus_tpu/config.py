@@ -143,8 +143,9 @@ class Configuration:
     # TPU path over the CPU fallback, and the micro-batch coalescing window:
     # the FLOOR of the wave former's adaptive hold (models/engine.py
     # ThreadCoalescingVerifier waits at least this long for company, and
-    # past it, up to a quarter of the launch time it measures, for the rest
-    # of the burst of submissions it has learned to expect).
+    # past it, for the rest of the burst of submissions it has learned to
+    # expect: as long as the bursts it has seen were spread plus one such
+    # window, never longer than the launch time it measures).
     crypto_tpu_min_batch: int = 16
     crypto_batch_window: float = 0.002
     # Randomized Ed25519 batch verification (one shared-doubling aggregate

@@ -15,21 +15,23 @@ Boot order is the contract ``launcher.start`` relies on:
    non-zero exit — a sidecar that silently served from the host would let
    replicas commit happily while the chip did nothing;
 2. build the engine the spec selects (``engine_for_config`` over
-   ``spec.make_configuration``) behind the thread coalescer, with the TWO
-   launch widths the spec implies: the full wave
-   (:meth:`~consensus_tpu.deploy.spec.ClusterSpec.sidecar_wave_lanes`) and
-   the half of it.  A wave launches at the narrower one it fits (the
+   ``spec.make_configuration``) behind the thread coalescer, with the
+   ladder of launch widths the spec implies (:func:`launch_widths`): the
+   full wave
+   (:meth:`~consensus_tpu.deploy.spec.ClusterSpec.sidecar_wave_lanes`), the
+   half of it, and the quarter where that is still a launch worth
+   compiling.  A wave launches at the narrowest width that holds it (the
    device's time and the host's layout work follow the padded width, and
    most waves are under half the full one); an engine that launches at one
    width takes the full one, as before;
 3. compile each width by pushing a warm-up wave that selects it through
    the coalescer, one after the other, so every compile — like every later
    launch — runs on the flusher thread and no two threads of this process
-   ever compile at once.  Tracing and lowering are not compiling: with
-   two widths the flusher compiles both at the first wave, and while it
-   loads the first width's executable from the persistent cache (the
-   longest item of a warm start; no Python lock) a helper thread traces
-   and lowers the second;
+   ever compile at once.  Tracing and lowering are not compiling: the
+   flusher compiles every width at the first wave, and while it loads the
+   first width's executable from the persistent cache (the longest item of
+   a warm start; no Python lock) a helper thread traces and lowers the
+   later ones;
 4. only then open the verify and control sockets and print ``ready``.
 
 Whether small waves go to the host is the spec's existing decision
@@ -68,6 +70,21 @@ EXIT_NO_DEVICE = 3
 #: Budget for the cold compile of one launch shape (the launcher's own
 #: ``start`` deadline is usually the tighter bound).
 WARMUP_TIMEOUT = 900.0
+
+
+#: The narrowest quarter rung a ladder takes: a launch's fixed cost shows from
+#: here down (PERF.md section 5: 13.3 us a lane at 256 lanes, 9.6 at 4,096),
+#: so a narrower rung buys under a ms and costs a start one more executable.
+_NARROWEST_QUARTER = 256
+
+
+def launch_widths(lanes: int) -> tuple:
+    """The launch widths a sidecar whose full wave is ``lanes`` compiles,
+    ascending: the half and the whole, and the quarter where it is at least
+    ``_NARROWEST_QUARTER`` lanes (2,048 / 4,096 / 8,192 at n=7 with 1,000
+    requests a proposal, 256 / 512 at n=4 with 100)."""
+    quarter = (lanes // 4,) if lanes // 4 >= _NARROWEST_QUARTER else ()
+    return quarter + (lanes // 2, lanes)
 
 
 class _CountingEngine:
@@ -199,6 +216,7 @@ def main() -> int:
     # --- the engine the spec selects, at the widths it implies -------------
     config = spec.make_configuration(spec.node_ids()[0])
     lanes = spec.sidecar_wave_lanes()
+    widths = launch_widths(lanes)
     min_device_batch = config.crypto_tpu_min_batch
     #: The spec routes every wave to the host: no backend is opened and no
     #: kernel compiled, so this process never competes for the chip.
@@ -234,7 +252,7 @@ def main() -> int:
             return EXIT_NO_DEVICE
 
     engine = _CountingEngine(
-        engine_for_config(config, pad_to=(lanes // 2, lanes)),
+        engine_for_config(config, pad_to=widths),
         min_device_batch=min_device_batch,
         lanes=lanes,
     )
@@ -253,16 +271,18 @@ def main() -> int:
     warmed: list[int] = []
     if not host_only:
         t0 = time.monotonic()  # wallclock-ok
-        # The warm-up waves: the smallest that needs the full width, then
-        # the smallest device wave there is.  Each proves the width it
-        # rides; a one-width engine rides the same twice: one wave.
-        sizes = [max(n, min_device_batch) for n in (lanes // 2 + 1, 1)]
-        if engine.launch_width(sizes[0]) == engine.launch_width(sizes[1]):
-            del sizes[1]
+        # The warm-up waves, widest first: for each width the smallest
+        # device wave that needs it.  Each proves the width it rides; a
+        # one-width engine rides the same every time: one wave.
+        sizes: list[int] = []
+        for below in (*reversed(widths[:-1]), 0):
+            n = max(below + 1, min_device_batch)
+            if not sizes or engine.launch_width(n) != engine.launch_width(sizes[-1]):
+                sizes.append(n)
         if len(sizes) > 1:
-            # Two widths: the first wave's flush compiles both before it
-            # launches, the second one's trace and lowering hidden under
-            # the first one's load (Ed25519BatchVerifier.compile_ahead).
+            # Several widths: the first wave's flush compiles them all
+            # before it launches, the later ones' trace and lowering hidden
+            # under the first one's load (Ed25519BatchVerifier.compile_ahead).
             engine.compile_ahead_of_next_wave(sizes)
         failure = None
         for n in sizes:

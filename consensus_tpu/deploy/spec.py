@@ -254,8 +254,9 @@ class ClusterSpec:
 
     def sidecar_wave_lanes(self) -> int:
         """The FULL padded launch width a sidecar of this rig compiles
-        before it reports ready (it compiles the half of it too, and a wave
-        rides the narrower one it fits): the largest wave the cluster can
+        before it reports ready (it compiles the half of it too, and the
+        quarter where ``sidecar_main.launch_widths`` says so, and a wave
+        rides the narrowest one it fits): the largest wave the cluster can
         offer inside one coalescing window — every replica verifying one
         full proposal (``request_batch_max_count`` client signatures) fused
         with the previous decision's commit cert (at most ``n``) — rounded

@@ -311,9 +311,9 @@ class Ed25519BatchVerifier:
         sending one wave after the other: tracing and lowering are seconds
         of Python a shape, while a compile — with a warm persistent cache
         the load of an executable, the longest item of a start — holds no
-        Python lock.  So while this thread compiles one width a helper
-        thread traces and lowers the NEXT (it never compiles: no two
-        threads of a process compile at once)."""
+        Python lock.  So while this thread compiles the first width a
+        helper thread traces and lowers every LATER one (it never compiles:
+        no two threads of a process compile at once)."""
         import threading
 
         jitted = _verify_kernel.__wrapped__
@@ -323,18 +323,17 @@ class Ed25519BatchVerifier:
                 jax.ShapeDtypeStruct((_PACKED_ROWS, self.launch_width(n)), np.uint8)
             )
 
-        lowered = lower(sizes[0])
-        for nxt in list(sizes[1:]) + [None]:
-            ahead = None
-            if nxt is not None:
-                ahead = threading.Thread(
-                    target=lower, args=(nxt,), name="lower-ahead", daemon=True
-                )
-                ahead.start()
-            lowered.compile()
-            if ahead is not None:
-                ahead.join()
-                lowered = lower(nxt)  # jax's own caches answer
+        first, *later = sizes
+        lowered = lower(first)
+        ahead = threading.Thread(
+            target=lambda: [lower(n) for n in later], name="lower-ahead",
+            daemon=True,
+        )
+        ahead.start()
+        lowered.compile()
+        ahead.join()
+        for n in later:
+            lower(n).compile()  # jax's own caches answer the lowering
 
     def verify_batch(
         self,

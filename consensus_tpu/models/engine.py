@@ -81,20 +81,11 @@ def _slice_wave_target(engine, cap: int) -> int:
     return min(cap, preferred)
 
 
-#: How long the flusher may hold a flush for the rest of a burst, as a share
-#: of the launch time it measures.  Holding h to save a launch of L pays only
-#: while h is well under L: a hold that succeeds saves L - h, one that runs
-#: out costs h on top of the two launches it did not merge.  At a quarter
-#: a success saves three quarters of a launch and a failure adds an eighth
-#: to the two.  Measured on the chip (PERF.md section 6, PR 28): a
-#: 7-replica burst is all in 9-13 ms after its first (p95 ~17 ms) against
-#: the 26-28 ms a ~106 ms launch at 8,192 lanes allows, a 4-replica one in
-#: 2-4 ms against the 3.2-3.9 ms of a ~13 ms launch at 512 lanes.
-_HOLD_SHARE_OF_LAUNCH = 0.25
-#: Bursts (and launch times) the expectation is read from: the last few.
-#: The medians of 8 shrug off one lone submitter among the bursts (the
-#: once-a-second verdict wave, a compile-length launch) and follow a
-#: lasting change (a replica down, a tenant gone) within 5 of them.
+#: Bursts (with their spreads and launch times) the expectation is read
+#: from: the last few.  The medians of 8 shrug off one lone submitter among
+#: the bursts (the once-a-second verdict wave, a compile-length launch) and
+#: follow a lasting change (a replica down, a tenant gone, a narrower
+#: launch) within 5 of them.
 _RECENT_BURSTS = 8
 
 
@@ -113,16 +104,22 @@ class ThreadCoalescingVerifier:
     ``window`` is the FLOOR of an adaptive hold.  Replicas of one cluster
     submit in bursts (one submission each per decision) spread over more
     than the floor, and a burst cut in two pays two launches.  So the
-    flusher learns the burst it serves — how many submissions arrive
-    within the hold's reach of a burst's first, the median over the last
-    few bursts that found it idle — and, woken from idle with fewer than
-    that pending, keeps waiting past the floor until they are, or no
-    further one could fit under ``hard_cap``, or a quarter of the launch
-    time it measures has run out (``_HOLD_SHARE_OF_LAUNCH``).  A hold that
-    runs out counts as a burst of what did come, so a lone submitter or a
-    cluster that lost a replica un-learns within a few flushes.
-    Submissions that queued while a launch ran never wait for a hold: they
-    go with the floor at most, at once if the expected burst is there.
+    flusher learns the burst it serves from the flushes that found it
+    idle: how many submissions a burst brings (the riders and whoever
+    queued behind them while their launch ran), over how long (the last
+    one's queue instant less the first's), and how long the launch took —
+    medians over the last few.  Woken from idle with fewer than that
+    pending, it keeps waiting past the floor until they are, or no
+    further one could fit under ``hard_cap``, or the hold's reach runs out:
+    the spread of the bursts it has seen plus one floor window, never more
+    than a launch takes (the stragglers' own launch would have returned by
+    then) and never under the floor.  So the reach follows the burst, not
+    the launch: a narrower, shorter launch does not cut the bursts it was
+    compiled for.  A hold that runs out on a lone submitter books a burst
+    of one, so a lone submitter or a cluster that lost a replica un-learns
+    within a few flushes.  Submissions that queued while a launch ran
+    never wait for a hold: they go with the floor at most, at once if the
+    expected burst is there.
 
     The per-replica semantics are unchanged — every replica still checks
     exactly the signatures it chose to check; only the *execution* is
@@ -208,8 +205,9 @@ class ThreadCoalescingVerifier:
             self._probe_clock = time.monotonic  # wallclock-ok
         self._probe_interval = 30.0
         self._last_probe = -float("inf")
-        # (submissions of a burst, ns in the engine) of the last few flushes
-        # that found the flusher idle.  The flusher thread's alone.
+        # (submissions of a burst, ns from its first to its last, ns in the
+        # engine) of the last few flushes that found the flusher idle.  The
+        # flusher thread's alone.
         self._recent: collections.deque = collections.deque(maxlen=_RECENT_BURSTS)
         self._thread = threading.Thread(target=self._loop, daemon=True, name=name)
         self._thread.start()
@@ -425,15 +423,16 @@ class ThreadCoalescingVerifier:
 
     def _expectation(self) -> tuple[int, float]:
         """(submissions a burst is expected to bring, seconds a hold may
-        last past its start): the upper median of the recent bursts, and a
-        share of the lower median of the launch times measured with them —
+        last past its start): the upper median of the recent bursts, and
+        the upper median of their spreads plus one floor window — never
+        more than the lower median of the launch times measured with them,
         never under the floor.  With no burst seen yet: 1, the floor."""
         if not self._recent:
             return 1, self._window
-        bursts = sorted(burst for burst, _ in self._recent)
-        launches = sorted(ns for _, ns in self._recent)
-        reach = _HOLD_SHARE_OF_LAUNCH * launches[(len(launches) - 1) // 2] / 1e9
-        return bursts[len(bursts) // 2], max(self._window, reach)
+        bursts, spreads, launches = (sorted(col) for col in zip(*self._recent))
+        upper, lower = len(bursts) // 2, (len(bursts) - 1) // 2
+        reach = min(spreads[upper] / 1e9 + self._window, launches[lower] / 1e9)
+        return bursts[upper], max(self._window, reach)
 
     def _room_for_another(self) -> bool:
         """Would one more submission, as large as the pending ones are on
@@ -457,10 +456,10 @@ class ThreadCoalescingVerifier:
             self._cv.wait(remaining)
         now = time.monotonic()  # wallclock-ok
         if expected > 1 and now > floor and limit > floor:  # it held on
-            if len(self._pending) >= expected:
-                FLUSHER.add("hold_met", 1)
-            elif now >= limit:
-                FLUSHER.add("hold_expired", 1)
+            met = len(self._pending) >= expected
+            if met or now >= limit:
+                FLUSHER.add("hold_met" if met else "hold_expired", 1)
+                FLUSHER.add("hold_reach_ns", int(reach * 1e9))
 
     def _loop(self) -> None:
         # This thread's life, cut into exclusive phases (obs/kernels.py
@@ -510,18 +509,21 @@ class ThreadCoalescingVerifier:
             with phase("wave.deliver"):
                 self._deliver(batch, results, error)
                 if idle:
-                    self._learn(batch, reach, engine_ns)
+                    self._learn(batch, returned_ns, engine_ns)
 
-    def _learn(self, batch, reach: float, engine_ns: int) -> None:
+    def _learn(self, batch, returned_ns: int, engine_ns: int) -> None:
         """Book the burst a flush from idle was the head of: its riders and
-        whoever queued behind them within the hold's ``reach`` of the first
-        — the rest of the burst, who would have ridden along had the flush
-        been held that long.  Later arrivals are not counted, so a hold
-        that ran out on them lowers the expectation."""
-        horizon = batch[0].queued_ns + int(reach * 1e9)
+        whoever queued behind them until their launch came back at
+        ``returned_ns`` — the rest of the burst, who would have ridden along
+        had the flush been held for them — and how long after its first
+        the last of them queued.  What the verdicts just delivered set off
+        queued later and is the next burst's."""
         with self._cv:
-            tail = sum(1 for item in self._pending if item.queued_ns <= horizon)
-        self._recent.append((len(batch) + tail, engine_ns))
+            tail = [i.queued_ns for i in self._pending if i.queued_ns <= returned_ns]
+        last = tail[-1] if tail else batch[-1].queued_ns
+        self._recent.append(
+            (len(batch) + len(tail), last - batch[0].queued_ns, engine_ns)
+        )
 
     def _deliver(self, batch, results, error) -> None:
         """Hand each waiter of a flush its slice of the verdicts; a flush

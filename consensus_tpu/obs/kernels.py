@@ -193,12 +193,14 @@ FLUSHER_PHASES = (
 #: between ``_enqueue`` and ``_take_batch``, each flush by how full it was
 #: of ``hard_cap`` (<= 25%, <= 50%, <= 75%, <= 100%), and the flushes the
 #: wave former held past its window for the rest of a burst: those the
-#: burst then filled (``hold_met``) and those the hold ran out on
-#: (``hold_expired``).  The held time itself is ``wave.wait_window``'s.
+#: burst then filled (``hold_met``), those the hold ran out on
+#: (``hold_expired``), and the nanoseconds each of the two MIGHT have lasted
+#: (``hold_reach_ns``: over their count, the reach a hold).  The held time
+#: itself is ``wave.wait_window``'s.
 FLUSHER_COUNTERS = (
     "verify.prepare_cpu", "engine_ns", "queue_wait_ns", "submissions",
     "flushes", "fill_le_25", "fill_le_50", "fill_le_75", "fill_le_100",
-    "hold_met", "hold_expired",
+    "hold_met", "hold_expired", "hold_reach_ns",
 )
 
 

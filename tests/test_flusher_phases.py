@@ -72,19 +72,26 @@ class _SlowEdges(ThreadCoalescingVerifier):
 
 class _Seen(ThreadCoalescingVerifier):
     """A coalescer that counts what a causal test waits to SEE before its
-    next step: submissions that have joined the queue, and windows the
-    flusher has opened (it woke, or came back from a launch, to work)."""
+    next step: submissions that have joined the queue, windows the flusher
+    has opened (it woke, or came back from a launch, to work), how many of
+    them from idle, and how many bursts it has booked (one a flush from
+    idle, after its verdicts are out)."""
 
-    joined = windows = 0
+    joined = windows = from_idle = learned = 0
 
     def _enqueue(self, items):
         super()._enqueue(items)
         with self._cv:
             self.joined += 1
 
-    def _wait_window(self, *args):
+    def _wait_window(self, idle, *args):
         self.windows += 1  # the flusher's alone, under ``_cv``
-        return super()._wait_window(*args)
+        self.from_idle += bool(idle)
+        return super()._wait_window(idle, *args)
+
+    def _learn(self, *args):
+        super()._learn(*args)
+        self.learned += 1
 
 
 def _until(seen, timeout: float = 30.0) -> None:
@@ -203,10 +210,11 @@ def test_submissions_that_share_a_flush_each_count_their_own_wait():
 # SEEN (a submission in the queue, the flusher's window open, a launch
 # running), never from a sleep that was meant to be long enough.  The sleeps
 # left are the nominal gaps between a burst's submitters, which a loaded
-# machine may stretch: the launches are long enough that what a hold may
-# last (a quarter of one) is ten times and more what a burst nominally
-# takes.  Counts are exact; durations are bounded from below, and from
-# above only by what a hold could have lasted.
+# machine may stretch: so the bursts that TEACH the flusher are spread twice
+# as wide as the burst a test then sends (a hold reaches as far as the
+# bursts it has seen were spread, plus one floor window).  Counts are exact;
+# durations are bounded from below, and from above only by what a hold could
+# have lasted: the reach the coalescer itself states (``_expectation``).
 
 
 def _burst(v, k: int, gap: float, size: int = 10) -> None:
@@ -230,9 +238,11 @@ def _learned(k: int, launch: float, *, window: float = 0.002, gap: float = 0.0,
              hard_cap: int = 1000, size: int = 10, cut: bool = False):
     """A coalescer over an engine whose launch takes ``launch`` seconds,
     that has seen a lone wave (as the sidecar's warm-up is: it measures the
-    launch time) and then two bursts of ``k``.  Where the floor cut the
-    first (``cut``: always, the rest start once the head's launch runs), its
-    tail was counted with it, so the second was held for."""
+    launch time) and then two bursts of ``k``, ``gap`` seconds between
+    neighbours (so (k - 1) gaps wide: keep that under ``launch``).  The floor
+    cut the first (``cut``: always, the rest start once the head's launch
+    runs); its tail queued while the head's launch ran and was counted with
+    it, count and spread, so the second was held for."""
     engine = _PhasedEngine({"verify.await": launch})
     v = _Seen(engine, window=window, max_batch=hard_cap, hard_cap=hard_cap)
     assert v.verify_batch(*_wave(size)).all()
@@ -247,28 +257,44 @@ def _learned(k: int, launch: float, *, window: float = 0.002, gap: float = 0.0,
         _burst(v, k, gap, size)
     _burst(v, k, gap, size)
     assert sum(engine.calls) == (1 + 2 * k) * size
+    _until(lambda: v.learned == v.from_idle)  # the last flush is booked too
     return v, engine
 
 
-@pytest.mark.parametrize("k", [4, 7])
-def test_a_staggered_burst_rides_one_flush_once_learned(k):
-    """k submitters 5 ms apart — two and a half floor windows between
-    neighbours, 15 and 30 ms in all — against the 500 ms that a 2 s launch
-    lets a hold last."""
+def _reach_ns(v) -> int:
+    """What the next hold from idle may last, as the flusher will book it:
+    read once the last flush whose verdicts are out is in its books."""
+    _until(lambda: v.learned == v.from_idle)
+    return int(v._expectation()[1] * 1e9)
+
+
+@pytest.mark.parametrize("k, gap", [(4, 0.09), (7, 0.036)])
+def test_a_staggered_burst_rides_one_flush_once_learned(k, gap):
+    """The launch is 400 ms.  The bursts that teach are 180-270 ms wide; the
+    burst sent then is k submitters half as far apart, 135 ms in all: OVER a
+    quarter of the launch (a reach of 100 ms would cut it) and under the
+    launch.  It rides ONE flush: the reach is what the flusher has seen a
+    burst take, not a share of the launch."""
+    launch = 0.4
     before = FLUSHER.snapshot()
-    v, engine = _learned(k, launch=2.0, gap=0.005, cut=True)
-    # Unlearned, the floor cut the first burst; the second rode ONE flush.
-    lone, *cut, held = engine.calls
-    assert (lone, sum(cut), held) == (10, 10 * k, 10 * k) and len(cut) >= 2
+    v, engine = _learned(k, launch=launch, gap=gap, cut=True)
+    # Unlearned, the floor cut the first burst; the second was held for.
+    lone, *rest = engine.calls
+    assert lone == 10 and sum(rest) == 2 * 10 * k and len(rest) >= 3
     learning = _since(before)
-    assert (learning["hold_met"], learning["hold_expired"]) == (1, 0)
+    assert learning["hold_met"] + learning["hold_expired"] == 1
+    assert v._expectation()[0] == k
+    reach = _reach_ns(v)
+    # Both bursts were at least (k - 2) gaps wide, and a launch bounds it.
+    assert (k - 2) * gap * 1e9 + 2 * MS <= reach <= launch * 1e9
     n = len(engine.calls)
     before = FLUSHER.snapshot()
     windows = v.windows
-    head = threading.Thread(target=_burst, args=(v, 1, 0.005))
+    head = threading.Thread(target=_burst, args=(v, 1, gap / 2))
     head.start()
     _until(lambda: v.windows > windows)  # the flusher woke: it holds from here
-    _burst(v, k - 1, 0.005)
+    time.sleep(gap / 2)
+    _burst(v, k - 1, gap / 2)
     head.join(timeout=30.0)
     assert not head.is_alive()
     v.close()
@@ -276,80 +302,138 @@ def test_a_staggered_burst_rides_one_flush_once_learned(k):
     assert engine.calls[n:] == [10 * k]
     assert (got["flushes"], got["submissions"]) == (1, k)
     assert (got["hold_met"], got["hold_expired"]) == (1, 0)
-    # It held for the stragglers (the last was started k - 2 gaps after the
-    # flusher was seen holding), and not to the end of what it may.
-    assert 5 * (k - 2) * MS <= got["wave.wait_window"] < 500 * MS
+    assert got["hold_reach_ns"] == reach  # booked once, the hold's own reach
+    # The burst it rode was over a quarter of the launch wide ...
+    count, spread, _ = v._recent[-1]
+    assert count == k and spread > 0.25 * launch * 1e9
+    # ... it held for the stragglers (the last was started k - 1 gaps after
+    # the flusher was seen holding), and not to the end of what it may.
+    assert (k - 1) * (gap / 2) * 1e9 <= got["wave.wait_window"] < launch * 1e9
 
 
 def test_a_lone_submitter_pays_the_cap_a_bounded_number_of_times():
-    """After bursts of 4, lone submissions: the first is released when the
-    cap (a quarter of the 600 ms launch) runs out; each such hold counts as
-    a burst of one, so the expectation decays and the later ones wait only
-    the floor."""
-    v, engine = _learned(4, launch=0.6, window=0.005)
+    """After bursts of 4 that were 120 ms wide, lone submissions: the first
+    is released when the reach (those 120 ms and a floor window) runs out;
+    each such hold counts as a burst of one, of no width, so the expectation
+    decays and the later ones wait only the floor."""
+    v, engine = _learned(4, launch=0.6, window=0.005, gap=0.04)
     n = len(engine.calls)
-    waits, expired = [], []
+    waits, expired, reaches = [], [], []
     for _ in range(6):
         before = FLUSHER.snapshot()
+        reach = _reach_ns(v)
         assert v.verify_batch(*_wave(10)).all()
         got = _since(before)
         waits.append(got["wave.wait_window"])
         expired.append(got["hold_expired"])
+        reaches.append(got["hold_reach_ns"])
         assert got["hold_met"] == 0 and got["flushes"] == 1
+        assert got["hold_reach_ns"] == reach * got["hold_expired"]
     v.close()
-    assert waits[0] >= 150 * MS and expired[0] == 1
+    assert waits[0] >= 125 * MS and expired[0] == 1
     # Bounded: at most 5 of the last 8 bursts have to be lone ones.
     assert 1 <= sum(expired) <= 5 and expired == sorted(expired, reverse=True)
-    # The floor is 5 ms: thirty of them fit under the cap.
-    assert expired[-3:] == [0, 0, 0] and max(waits[-3:]) < 150 * MS
+    # The floor is 5 ms: two dozen of them fit under the reach.
+    assert expired[-3:] == [0, 0, 0] and max(waits[-3:]) < 120 * MS
+    assert reaches[-3:] == [0, 0, 0]
     assert min(waits) >= 5 * MS
     assert engine.calls[n:] == [10] * 6
 
 
-@pytest.mark.parametrize("launch", [0.001, 0.2, 0.4])
-def test_the_cap_follows_the_measured_launch_time(launch):
-    """A lone submitter after bursts of 3 waits a quarter of the launch
-    time the flusher measured and never less than the floor: 10 ms (the
-    floor) at a 1 ms launch, 50 ms at 200 ms, 100 ms at 400 ms."""
-    v, engine = _learned(3, launch=launch, window=0.01)
+@pytest.mark.parametrize("launch, gap, reach_is", [
+    (0.001, 0.0, "the floor"), (0.4, 0.05, "the bursts'"), (0.2, 0.05, "the bursts'")])
+def test_the_reach_follows_the_bursts_it_has_seen_under_the_measured_launch(
+        launch, gap, reach_is):
+    """A lone submitter after bursts of 3 waits as long as those bursts were
+    wide (100 ms) and a floor window (10 ms) more, whatever the launch takes
+    (200 ms, 400 ms: no share of it); under a 1 ms launch no hold can pay
+    and it waits the floor."""
+    v, engine = _learned(3, launch=launch, window=0.01, gap=gap)
+    reach = _reach_ns(v)
+    if reach_is == "the floor":
+        assert reach == 10 * MS
+    else:
+        assert (2 * gap + 0.01) * 1e9 <= reach <= launch * 1e9
     before = FLUSHER.snapshot()
     assert v.verify_batch(*_wave(10)).all()
     v.close()
     got = _since(before)
-    assert got["wave.wait_window"] >= max(0.01, 0.25 * launch) * 1e9
+    assert got["wave.wait_window"] >= reach
     # Under the floor there is nothing to hold for: no hold is counted.
-    assert got["hold_expired"] == (1 if launch > 0.04 else 0)
+    assert got["hold_expired"] == (1 if reach_is != "the floor" else 0)
+    assert got["hold_reach_ns"] == got["hold_expired"] * reach
     assert got["hold_met"] == 0
+
+
+@pytest.mark.parametrize("recent, want", [
+    # (count, spread ms, launch ms) of the flushes from idle, oldest first
+    ([], (1, 2.0)),                                       # nothing seen: the floor
+    ([(1, 0, 40_000), (1, 0, 45), (1, 0, 25)], (1, 2.0)),  # the warm-up waves
+    ([(7, 8.0, 29)] * 8, (7, 10.0)),          # n7 at 2,048 lanes: spread + a window
+    ([(7, 8.0, 29)] * 5 + [(7, 28.0, 29)] * 3, (7, 10.0)),  # the upper median
+    ([(7, 8.0, 29)] * 4 + [(7, 28.0, 29)] * 4, (7, 29.0)),  # ... never over a launch
+    ([(7, 40.0, 29)] * 8, (7, 29.0)),
+    ([(7, 40.0, 29)] * 4 + [(7, 40.0, 48)] * 4, (7, 29.0)),  # the LOWER median launch
+    ([(7, 40.0, 29)] * 3 + [(7, 40.0, 48)] * 5, (7, 42.0)),
+    ([(4, 3.0, 8)] * 8, (4, 5.0)),            # n4 at 512 lanes
+    ([(4, 3.0, 1.5)] * 8, (4, 2.0)),          # never under the floor
+    ([(4, 0.0, 8)] * 8, (4, 2.0)),
+    ([(4, 3.0, 8)] * 3 + [(1, 0.0, 8)] * 5, (1, 2.0)),  # 5 lone flushes un-learn it
+    ([(4, 3.0, 8)] * 4 + [(1, 0.0, 8)] * 4, (4, 5.0)),
+])
+def test_the_reach_is_the_seen_spread_and_a_window_between_the_floor_and_a_launch(
+        recent, want):
+    """``_expectation`` over planted books: (upper median count, reach) with
+    reach = upper median spread + one window, at most the lower median
+    launch, at least the window (2 ms here)."""
+    v = ThreadCoalescingVerifier(_PhasedEngine({}), window=0.002, max_batch=100)
+    try:
+        v._recent.extend((c, int(s * MS), int(l * MS)) for c, s, l in recent)
+        expected, reach = v._expectation()
+    finally:
+        v.close()
+    assert expected == want[0]
+    assert reach == pytest.approx(want[1] / 1e3, abs=1e-9)
+    if recent:
+        launches = sorted(l for _, _, l in recent)
+        assert 0.002 <= reach <= max(0.002, launches[(len(recent) - 1) // 2] / 1e3)
 
 
 @pytest.mark.parametrize("k, size, hard_cap, flushes", [
     (4, 40, 100, [80, 80]), (5, 10, 30, [30, 20])])
 def test_a_burst_that_cannot_fit_hard_cap_flushes_without_holding(
         k, size, hard_cap, flushes):
-    """Bursts the flusher has learned to expect, of which hard_cap holds
-    only 2 (or 3) submissions: with that many pending the next is not waited
-    for, and what the launch left behind goes with the floor."""
-    v, engine = _learned(k, launch=1.2, gap=0.005, hard_cap=hard_cap, size=size)
+    """Bursts the flusher has learned to expect (120-160 ms wide), of which
+    hard_cap holds only 2 (or 3) submissions: with that many pending the
+    next is not waited for, and what the launch left behind goes with the
+    floor."""
+    v, engine = _learned(k, launch=1.2, gap=0.04, hard_cap=hard_cap, size=size)
     assert max(engine.calls) <= hard_cap
+    assert v._expectation()[0] == k
+    reach = _reach_ns(v)
+    assert reach >= (k - 1) * 40 * MS
     n = len(engine.calls)
     before = FLUSHER.snapshot()
     _burst(v, k, 0.005, size=size)
     v.close()
     got = _since(before)
     assert engine.calls[n:] == flushes
-    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+    assert (got["hold_met"], got["hold_expired"], got["hold_reach_ns"]) == (0, 0, 0)
     # The head waited for its neighbours (5 ms each), the tail not at all:
-    # neither the 300 ms a hold could have lasted.
-    assert got["wave.wait_window"] < 300 * MS
+    # neither as long as a hold could have lasted.
+    assert got["wave.wait_window"] < reach
 
 
 @pytest.mark.parametrize("queued, floor_waits", [(3, 0), (1, 1)])
 def test_submissions_queued_during_a_launch_never_wait_for_a_hold(
         queued, floor_waits):
-    """Expecting bursts of 3, with a 50 ms floor and a 2 s launch (a hold
-    may last 500 ms): what queues while a launch runs goes at once on its
-    return if the expected burst is there, and with the floor if not."""
-    v, engine = _learned(3, launch=2.0, window=0.05)
+    """Expecting bursts of 3 that were 300 ms wide, with a 50 ms floor and
+    a 2 s launch (a hold may last 350 ms): what queues while a launch runs
+    goes at once on its return if the expected burst is there, and with the
+    floor if not."""
+    v, engine = _learned(3, launch=2.0, window=0.05, gap=0.15)
+    reach = _reach_ns(v)
+    assert reach >= 350 * MS
     n = len(engine.calls)
     before = FLUSHER.snapshot()
     head = threading.Thread(target=_burst, args=(v, 3, 0.0))
@@ -361,44 +445,48 @@ def test_submissions_queued_during_a_launch_never_wait_for_a_hold(
     v.close()
     got = _since(before)
     assert engine.calls[n:] == [30, 10 * queued]
-    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+    assert (got["hold_met"], got["hold_expired"], got["hold_reach_ns"]) == (0, 0, 0)
     # The head was all there within the floor and went at once, too: what
-    # was waited is the floor's, nowhere near a hold's 500 ms.
-    assert floor_waits * 50 * MS <= got["wave.wait_window"] < 500 * MS
+    # was waited is the floor's, nowhere near a hold's reach.
+    assert floor_waits * 50 * MS <= got["wave.wait_window"] < reach
 
 
 def test_close_ends_a_hold_at_once():
-    v, engine = _learned(3, launch=1.2, window=0.01)
+    v, engine = _learned(3, launch=1.2, window=0.01, gap=0.15)
+    reach = _reach_ns(v)
+    assert reach >= 310 * MS
     n = len(engine.calls)
     before = FLUSHER.snapshot()
     windows = v.windows
     lone = threading.Thread(target=_burst, args=(v, 1, 0.0))
     lone.start()
-    _until(lambda: v.windows > windows)  # held: 300 ms is what the hold may last
+    _until(lambda: v.windows > windows)  # held: 310 ms is what the hold may last
     time.sleep(0.04)
     v.close()
     lone.join(timeout=30.0)
     got = _since(before)
     assert not lone.is_alive() and not v._thread.is_alive()
     assert engine.calls[n:] == [10]  # served all the same
-    assert 40 * MS <= got["wave.wait_window"] < 300 * MS
-    assert (got["hold_met"], got["hold_expired"]) == (0, 0)
+    assert 40 * MS <= got["wave.wait_window"] < reach
+    assert (got["hold_met"], got["hold_expired"], got["hold_reach_ns"]) == (0, 0, 0)
 
 
 def test_the_phases_close_the_threads_life_with_holds_in_it():
-    """Held time is ``wave.wait_window``'s: with a hold that was met and
-    one that ran out, the wave phases and the engine call still account
-    for the thread's life."""
+    """Held time is ``wave.wait_window``'s: with a burst that was held for
+    and a lone submitter whose hold ran out, the wave phases and the engine
+    call still account for the thread's life."""
     before = FLUSHER.snapshot()
     t_born = time.monotonic_ns()
-    v, engine = _learned(4, launch=1.2, gap=0.005)
+    v, engine = _learned(4, launch=1.2, gap=0.1)
+    reach = _reach_ns(v)
     assert v.verify_batch(*_wave(10)).all()  # lone: its hold runs out
     v.close()
     lifetime = time.monotonic_ns() - t_born
     assert not v._thread.is_alive()
     got = _since(before)
-    assert got["hold_met"] >= 1 and got["hold_expired"] == 1
-    assert got["wave.wait_window"] >= 300 * MS
+    assert got["hold_met"] + got["hold_expired"] == 2
+    assert got["hold_expired"] >= 1 and got["hold_reach_ns"] >= 2 * 300 * MS
+    assert got["wave.wait_window"] >= reach >= 300 * MS
     accounted = sum(got[name] for name in WAVE) + got["engine_ns"]
     assert 0.98 * lifetime <= accounted <= lifetime, (accounted, lifetime)
 
@@ -515,7 +603,8 @@ def test_phase_cpu_tells_a_thread_that_computes_from_one_that_waits():
 
 def test_the_ledger_has_a_fixed_set_of_keys_and_loses_no_update():
     assert set(FLUSHER.snapshot()) == set(FLUSHER_PHASES + FLUSHER_COUNTERS)
-    assert len(set(FLUSHER_PHASES + FLUSHER_COUNTERS)) == 19
+    assert len(set(FLUSHER_PHASES + FLUSHER_COUNTERS)) == 20
+    assert "hold_reach_ns" in FLUSHER_COUNTERS
     ledger = PhaseLedger(("a", "b"))
     with pytest.raises(KeyError):
         ledger.add("c", 1)  # a name nobody declared is a bug, not a new key

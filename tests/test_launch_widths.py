@@ -1,7 +1,8 @@
 """The launch is as wide as its wave: ``Ed25519BatchVerifier`` with a ladder
-of compiled widths (``pad_to=(16, 32)`` here; the rig sidecar's are
-``lanes // 2`` and ``lanes``) pads a wave to the narrowest width that holds
-it, and gives the same strict verdicts at either; ``instrumented_jit``
+of compiled widths (``pad_to=(16, 32)`` and ``(8, 16, 32)`` here; the rig
+sidecar's are ``lanes // 2`` and ``lanes``, and ``lanes // 4`` where
+``sidecar_main.launch_widths`` takes it) pads a wave to the narrowest width
+that holds it, and gives the same strict verdicts at each; ``instrumented_jit``
 lowers each shape once and still reads the executable's cost estimates; the
 comb table a verify kernel bakes in is built with one modular inversion.
 
@@ -12,7 +13,7 @@ rejection class at every fill of either width; the device's signed-digit
 recoding equals the host integers' on the carry's edge scalars; what the
 lanes past a wave's end hold never reaches a verdict.
 
-Compiles two small strict kernels (16 and 32 lanes) on the CPU backend.
+Compiles three small strict kernels (8, 16 and 32 lanes) on the CPU backend.
 """
 
 import hashlib
@@ -34,7 +35,9 @@ from consensus_tpu.obs.kernels import (
     kernel_lane_suffix,
 )
 
-HALF, TOP = 16, 32
+QUARTER, HALF, TOP = 8, 16, 32
+#: The two ladders a sidecar builds: the n4 shape's, and the n7 shape's.
+TWO, THREE = (HALF, TOP), (QUARTER, HALF, TOP)
 KERNEL = "ed25519.verify" + kernel_lane_suffix()
 
 
@@ -64,18 +67,28 @@ def planted(corpus):
 
 
 @pytest.fixture(scope="module")
-def ladder():
-    return Ed25519BatchVerifier(min_device_batch=1, pad_to=(HALF, TOP))
+def ladders():
+    return {rungs: Ed25519BatchVerifier(min_device_batch=1, pad_to=rungs)
+            for rungs in (TWO, THREE)}
 
 
+@pytest.fixture(scope="module")
+def ladder(ladders):
+    return ladders[TWO]
+
+
+@pytest.mark.parametrize("rungs, sizes", [
+    (TWO, [HALF + 1, 1]), (THREE, [HALF + 1, QUARTER + 1, 1])])
 def test_compile_ahead_compiles_on_its_thread_and_lowers_the_next_beside_it(
-    ladder, corpus
+    ladders, corpus, rungs, sizes
 ):
     """``compile_ahead(sizes)`` compiles the width each size rides on the
-    thread that calls it, one after the other; the helper thread beside it
-    only traces and lowers (jax's own events, by thread).  The launches
-    that follow neither trace, lower nor compile, and the ledger books each
-    width's compile at its first launch, as for a wave that compiled it."""
+    thread that calls it, one after the other, each once; the helper thread
+    beside it only traces and lowers (jax's own events, by thread).  The
+    launches that follow neither trace, lower nor compile, and the ledger
+    books each width's compile at its first launch, as for a wave that
+    compiled it.  (The three-rung ladder finds two of its widths compiled
+    by the two-rung one: one kernel a width in the process.)"""
     import threading
 
     from jax import monitoring
@@ -89,43 +102,70 @@ def test_compile_ahead_compiles_on_its_thread_and_lowers_the_next_beside_it(
             events.append((threading.current_thread().name, event))
 
     msgs, sigs, keys = corpus
+    ladder = ladders[rungs]
     stats = KERNELS.stats(KERNEL)
     launches, compiles = stats.launches, stats.compiles
     monitoring.register_event_duration_secs_listener(listener)
     try:
         flusher = threading.Thread(
-            target=ladder.compile_ahead, args=([HALF + 1, 1],), name="flusher")
+            target=ladder.compile_ahead, args=(sizes,), name="flusher")
         flusher.start()
         flusher.join()
         ahead = {e for who, e in events if who == "lower-ahead"}
         assert ahead <= {"jaxpr_trace_duration", "jaxpr_to_mlir_module_duration"}
         assert {who for who, _ in events} <= {"flusher", "lower-ahead"}
-        # In a process that had compiled neither width: two compiles, both
-        # on the calling thread, and the second width's lowering beside it.
+        # In a process that had compiled none of the widths: a compile each,
+        # all on the calling thread, and the later widths' lowering beside
+        # the first one's.
         compiled = [who for who, e in events if e == "backend_compile_duration"]
-        assert compiled in ([], ["flusher"], ["flusher"] * 2)
-        if len(compiled) == 2:
-            assert ("lower-ahead", "jaxpr_to_mlir_module_duration") in events
+        assert compiled == ["flusher"] * len(compiled) and len(compiled) <= len(sizes)
+        if len(compiled) == len(sizes):
+            lowered = [e for e in events
+                       if e == ("lower-ahead", "jaxpr_to_mlir_module_duration")]
+            assert len(lowered) == len(sizes) - 1
         assert (stats.launches, stats.compiles) == (launches, compiles)
         del events[:]
-        for n in (HALF + 1, 1):
+        for n in sizes:
             assert ladder.verify_batch(msgs[:n], sigs[:n], keys[:n]).all()
     finally:
         monitoring.unregister_event_duration_listener(listener)
     assert not events, events
-    assert stats.launches == launches + 2
+    assert stats.launches == launches + len(sizes)
     assert stats.compiles - compiles in (0, len(compiled))
 
 
 @pytest.mark.parametrize(
-    "n, width",
-    [(1, HALF), (HALF, HALF), (HALF + 1, TOP), (TOP, TOP),
-     (TOP + 1, 2 * TOP), (3 * TOP, 4 * TOP)],
+    "rungs, n, width",
+    [(TWO, 1, HALF), (TWO, HALF, HALF), (TWO, HALF + 1, TOP), (TWO, TOP, TOP),
+     (TWO, TOP + 1, 2 * TOP), (TWO, 3 * TOP, 4 * TOP),
+     (THREE, 1, QUARTER), (THREE, QUARTER, QUARTER), (THREE, QUARTER + 1, HALF),
+     (THREE, HALF, HALF), (THREE, HALF + 1, TOP), (THREE, TOP, TOP),
+     (THREE, TOP + 1, 2 * TOP)],
 )
-def test_a_wave_rides_the_narrowest_width_that_holds_it(ladder, n, width):
-    """n = 1, half, half + 1, top; over the top the power-of-two fallback."""
-    assert ladder.launch_width(n) == width
-    assert ladder._pad_to == TOP  # what wave sizing and subclasses read
+def test_a_wave_rides_the_narrowest_width_that_holds_it(ladders, rungs, n, width):
+    """At every rung's boundary (rung, rung + 1), on the two-rung ladder and
+    the three-rung one; over the top the power-of-two fallback."""
+    assert ladders[rungs].launch_width(n) == width
+    assert ladders[rungs]._pad_to == TOP  # what wave sizing and subclasses read
+
+
+@pytest.mark.parametrize("lanes, widths", [
+    (512, (256, 512)),  # the n4 shape keeps two
+    (256, (128, 256)), (8, (4, 8)),
+    (1024, (256, 512, 1024)), (2048, (512, 1024, 2048)),
+    (8192, (2048, 4096, 8192)),  # the n7 shape gains the quarter
+    (16384, (4096, 8192, 16384)),
+])
+def test_a_sidecar_takes_the_quarter_only_where_it_is_a_launch_worth_compiling(
+    lanes, widths
+):
+    """``sidecar_main.launch_widths``: arithmetic on the full width alone;
+    a ladder gets the quarter from 256 lanes up, the half always."""
+    from consensus_tpu.deploy.sidecar_main import launch_widths
+
+    assert launch_widths(lanes) == widths
+    engine = Ed25519BatchVerifier(pad_to=launch_widths(lanes))
+    assert engine._widths == widths and engine._pad_to == lanes
 
 
 @pytest.mark.parametrize("pad_to", [0, 8, TOP])
@@ -172,16 +212,18 @@ def test_a_wave_over_the_half_rides_the_top_with_the_same_verdicts(
     assert got[HALF:].all()
 
 
-def test_the_ladder_compiled_one_shape_a_width_and_no_more(ladder, corpus):
-    """After waves of 1, ``HALF``, ``HALF + 1`` and ``TOP`` signatures the jit
-    cache of the strict kernel holds the two widths (other tests of this
-    file compiled them already: nothing new compiles here)."""
+@pytest.mark.parametrize("rungs", [TWO, THREE])
+def test_the_ladder_compiled_one_shape_a_width_and_no_more(ladders, corpus, rungs):
+    """After a wave at every rung and one past every rung the jit cache of
+    the strict kernel holds the ladder's widths (other tests of this file
+    compiled them already: nothing new compiles here)."""
     msgs, sigs, keys = corpus
+    ladder = ladders[rungs]
     stats = KERNELS.stats(KERNEL)
-    for n in (HALF, TOP):  # make sure both are there, whatever ran before
+    for n in rungs:  # make sure all are there, whatever ran before
         ladder.verify_batch(msgs[:n], sigs[:n], keys[:n])
     compiles = stats.compiles
-    for n in (1, HALF, HALF + 1, TOP):
+    for n in sorted({1, *rungs, *(r + 1 for r in rungs[:-1])}):
         assert ladder.verify_batch(msgs[:n], sigs[:n], keys[:n]).all()
     assert stats.compiles == compiles
     assert stats.flops is None or stats.flops > 0
@@ -228,16 +270,21 @@ _CLASSES = {
 }
 
 
-@pytest.mark.parametrize("n", [1, HALF - 3, HALF, HALF + 5, TOP])
+@pytest.mark.parametrize("rungs, n", [
+    (TWO, 1), (TWO, HALF - 3), (TWO, HALF), (TWO, HALF + 5), (TWO, TOP),
+    (THREE, 1), (THREE, QUARTER - 3), (THREE, QUARTER)])
 @pytest.mark.parametrize("spoiled", list(_CLASSES))
 def test_the_packed_program_gives_the_references_verdicts(
-    ladder, corpus, spoiled, n
+    ladders, corpus, spoiled, rungs, n
 ):
     """One lane (the last but one, or the only one) of the class, in a wave
-    that fills its width, falls short of it, or is a single lane: the
+    that fills its width, falls short of it, or is a single lane — on the
+    three-rung ladder the waves that ride the new, narrowest rung: the
     device's verdicts are OpenSSL's under the strict pre-checks
     (``verify_host``) lane by lane, and the spoiled lane's is the plain
     integers' (``ref_verify``)."""
+    ladder = ladders[rungs]
+    assert ladder.launch_width(n) == min(w for w in rungs if w >= n)
     msgs, sigs, keys = (list(x[:n]) for x in corpus)
     at = max(0, n - 2)
     _CLASSES[spoiled](msgs, sigs, keys, at)
@@ -296,9 +343,10 @@ def test_a_lone_eight_in_the_top_window_is_no_scalar_under_l():
         model._signed_digits_int(_lone_eight(63), model._WINDOWS)
 
 
+@pytest.mark.parametrize("width", [HALF, QUARTER])
 @pytest.mark.parametrize("tail_ok", [0, 1])
 def test_what_the_lanes_past_the_wave_hold_never_flips_a_verdict(
-    ladder, corpus, planted, tail_ok
+    ladder, corpus, planted, tail_ok, width
 ):
     """A short wave written over a full one (as a buffer kept between
     launches would hold it; none is kept): the short wave's lanes read as
@@ -309,9 +357,9 @@ def test_what_the_lanes_past_the_wave_hold_never_flips_a_verdict(
     import jax.numpy as jnp
 
     n = 5
-    full = model.pack_wave(*ladder._prepare(*planted), HALF)
+    full = model.pack_wave(*ladder._prepare(*(x[:width] for x in planted)), width)
     rows, host_ok = ladder._prepare(*(x[8:8 + n] for x in corpus))
-    fresh = model.pack_wave(rows, host_ok, HALF)
+    fresh = model.pack_wave(rows, host_ok, width)
     assert not fresh[:, n:].any()
     kept = full.copy()
     kept[:, :n] = fresh[:, :n]
@@ -373,9 +421,13 @@ def test_a_launch_is_one_copy_and_one_program(ladder, corpus, monkeypatch):
     assert now[KERNEL]["launches"] == launched[KERNEL]["launches"] + 1
 
 
-def test_compile_ahead_lowers_the_widths_from_shapes_alone(ladder, monkeypatch):
-    """``compile_ahead`` of the sidecar's two warm-up sizes prepares and
-    packs no wave and launches nothing: a width's shape is enough."""
+@pytest.mark.parametrize("rungs, sizes", [
+    (TWO, (HALF + 1, 1)), (THREE, (HALF + 1, QUARTER + 1, 1))])
+def test_compile_ahead_lowers_the_widths_from_shapes_alone(
+    ladders, monkeypatch, rungs, sizes
+):
+    """``compile_ahead`` of the sidecar's warm-up sizes prepares and packs
+    no wave and launches nothing: a width's shape is enough."""
 
     def never(*_a, **_kw):
         raise AssertionError("compile_ahead built a wave")
@@ -393,11 +445,10 @@ def test_compile_ahead_lowers_the_widths_from_shapes_alone(ladder, monkeypatch):
 
     kernel = model._verify_kernel
     monkeypatch.setattr(kernel, "__wrapped__", Spy())
-    ladder.compile_ahead((HALF + 1, 1))
+    ladders[rungs].compile_ahead(sizes)
     assert KERNELS.stats(KERNEL).launches == launches
     shapes = {tuple(x) for x in lowered}
-    assert shapes == {(((129, TOP), np.dtype(np.uint8)),),
-                      (((129, HALF), np.dtype(np.uint8)),)}
+    assert shapes == {(((129, width), np.dtype(np.uint8)),) for width in rungs}
 
 
 @pytest.mark.parametrize("fused, randomized", [(False, True), (True, False)])

@@ -8,8 +8,9 @@ when the sidecar's backend did every wave, and it FAILS (while the ledger
 still grows on the replicas' host fallback) when the sidecar is taken away.
 
 Subprocess-heavy and compiles the sidecar's two launch widths (512 lanes and
-the half) on the CPU: named to sort last so it never displaces the rest of
-the tier-1 suite inside its budget.
+the half) on the CPU, and for a rig whose full wave is 1,024 lanes the three
+of its ladder: named to sort last so it never displaces the rest of the
+tier-1 suite inside its budget.
 """
 
 import json
@@ -132,15 +133,22 @@ def test_sidecar_health_carries_the_flusher_ledger_and_it_only_grows(rehearsal):
     assert first["engine_ns"] > sum(
         first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
     buckets = [k for k in first if k.startswith("fill_le_")]
-    holds = ["hold_met", "hold_expired"]  # only a learned burst is held for
-    assert set(holds) < set(first)
+    # Only a learned burst is held for; the reach is booked with each hold.
+    holds = ["hold_met", "hold_expired", "hold_reach_ns"]
+    assert set(holds) < set(first) and set(holds) < set(FLUSHER_COUNTERS)
+    assert (first["hold_reach_ns"] > 0) == (first["hold_met"] + first["hold_expired"] > 0)
     assert all(last[k] > first[k] for k in first if k not in buckets + holds)
     flushes = last["flushes"] - first["flushes"]
     assert flushes == sum(last[k] - first[k] for k in buckets)
     assert flushes == (health[1]["launches_after_ready"]
                        - health[0]["launches_after_ready"])
     assert last["submissions"] - first["submissions"] >= flushes
-    assert sum(last[k] - first[k] for k in holds) <= flushes
+    held = sum(last[k] - first[k] for k in holds[:2])
+    assert held <= flushes
+    # A hold reaches at least the 2 ms floor and at most a launch.
+    engine_ns = last["engine_ns"] - first["engine_ns"]
+    assert 2_000_000 * held <= last["hold_reach_ns"] - first["hold_reach_ns"] <= (
+        held * engine_ns)
     # The engine call is its four phases and little else.
     inside = sum(last[k] - first[k] for k in FLUSHER_PHASES if k.startswith("verify."))
     engine = last["engine_ns"] - first["engine_ns"]
@@ -183,6 +191,60 @@ def test_sidecar_books_follow_the_width_each_wave_rode(rehearsal):
     assert [bool(v) for v in narrow["got"]] == want.tolist()
     assert sorted(narrow["planted"]) == [i for i, ok in enumerate(want) if not ok]
     assert len(narrow["planted"]) == 8
+
+
+def test_a_rig_whose_quarter_is_256_lanes_compiles_three_widths_before_ready(
+        tmp_path):
+    """n=4 with 200 requests a proposal: the full wave is 1,024 lanes, so the
+    sidecar's ladder is 256 / 512 / 1,024 (``launch_widths``: the n4 shape
+    above keeps two).  All three compile before ready, each proved by the
+    warm-up wave that selects it; then one wave for each rung through the
+    socket: ``launches_by_lanes`` sums to ``launches_after_ready`` over three
+    keys, ``device_lanes`` is the sum of the widths launched, nothing
+    compiles, and every rung gives the host twin's verdicts."""
+    from consensus_tpu.deploy import ClusterLauncher, ClusterSpec
+    from consensus_tpu.models import Ed25519BatchVerifier
+    from consensus_tpu.net.sidecar import SidecarVerifierClient
+    from consensus_tpu.obs.kernels import FLUSHER_COUNTERS
+
+    spec = ClusterSpec.generate(
+        4, 1, str(tmp_path / "cluster"),
+        config_overrides={"request_batch_max_count": 200}, hold_ports=True)
+    assert spec.sidecar_wave_lanes() == 1024
+    launcher = ClusterLauncher(spec)
+    try:
+        launcher.start(timeout=chip_smoke.DRY["start_timeout"])
+        ready = launcher.sidecars["sc-0"].probe()
+        assert ready["platform"] == "cpu" and ready["lanes"] == 1024
+        assert ready["compiles"] == 3 and ready["compiles_after_ready"] == 0
+        assert ready["launches_by_lanes"] == {"256": 0, "512": 0, "1024": 0}
+        assert ready["launches_after_ready"] == 0 == ready["device_lanes"]
+        # 513, 257 and 16 signatures: the smallest wave that needs each rung.
+        flusher = ready["flusher"]
+        assert "hold_reach_ns" in FLUSHER_COUNTERS and "hold_reach_ns" in flusher
+        assert flusher["flushes"] == 3 == flusher["submissions"]
+        assert (flusher["fill_le_75"], flusher["fill_le_50"],
+                flusher["fill_le_25"], flusher["fill_le_100"]) == (1, 1, 1, 0)
+        client = SidecarVerifierClient(
+            spec.sidecar_addresses()["sc-0"], auth_secret=spec.auth_secret,
+            request_timeout=120.0)
+        try:
+            for n, width in ((64, 256), (256, 256), (257, 512), (600, 1024)):
+                wave, planted = chip_smoke._ed25519_wave(n, 35)
+                want = Ed25519BatchVerifier().verify_host(*wave)
+                assert [bool(v) for v in client.verify_batch(*wave)] == want.tolist()
+                assert sorted(planted) == [i for i, ok in enumerate(want) if not ok]
+        finally:
+            client.close()
+        last = launcher.sidecars["sc-0"].probe()
+        assert last["compiles"] == 3 and last["compiles_after_ready"] == 0
+        assert last["launches_by_lanes"] == {"256": 2, "512": 1, "1024": 1}
+        assert sum(last["launches_by_lanes"].values()) == last["launches_after_ready"]
+        assert last["device_lanes"] == 2 * 256 + 512 + 1024
+        assert last["device_signatures"] == 64 + 256 + 257 + 600
+        assert last["host_signatures"] == 0
+    finally:
+        launcher.stop()
 
 
 def test_rehearsal_fails_when_the_sidecar_is_killed_before_traffic(tmp_path):
