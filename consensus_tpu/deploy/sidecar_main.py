@@ -44,8 +44,9 @@ The control socket's ``health`` reports what a run needs to prove the
 device did the work: ``platform`` / ``device_kind`` / ``device_count``, the
 kernel ledger (launches, compiles, compiles since ready), signatures and
 lanes launched on the device (``device_lanes``: the sum of the widths that
-ran; ``launches_by_lanes``: how many launches rode each width since
-ready), signatures served from the host, the
+ran; ``launches_by_lanes`` / ``signatures_by_lanes``: how many launches
+rode each width since ready, and how many signatures they carried),
+signatures served from the host, the
 coalescer's ``device_suspect`` flag and its degrade count, the flusher
 thread's phase ledger (``flusher``: nanoseconds per phase, queue wait and
 flushes by fill, :mod:`consensus_tpu.obs.kernels`) — plus the wave
@@ -103,6 +104,7 @@ class _CountingEngine:
         self.device_signatures = 0
         self.device_lanes = 0
         self.launches_by_lanes: dict[int, int] = {}
+        self.signatures_by_lanes: dict[int, int] = {}
         self.host_signatures = 0
         self.degraded = False
         self._compile_ahead = None
@@ -141,6 +143,9 @@ class _CountingEngine:
                 self.launches_by_lanes[width] = (
                     self.launches_by_lanes.get(width, 0) + 1
                 )
+                self.signatures_by_lanes[width] = (
+                    self.signatures_by_lanes.get(width, 0) + n
+                )
             else:
                 self.host_signatures += n
         return self._inner.verify_batch(messages, signatures, public_keys)
@@ -159,6 +164,7 @@ class _CountingEngine:
                 "device_signatures": self.device_signatures,
                 "device_lanes": self.device_lanes,
                 "launches_by_lanes": dict(self.launches_by_lanes),
+                "signatures_by_lanes": dict(self.signatures_by_lanes),
                 "host_signatures": self.host_signatures,
             }
 
@@ -340,6 +346,11 @@ def main() -> int:
             "launches_by_lanes": {
                 str(width): n - ready_counts["launches_by_lanes"].get(width, 0)
                 for width, n in sorted(counts["launches_by_lanes"].items())
+            },
+            # The signatures those waves carried, width by width.
+            "signatures_by_lanes": {
+                str(width): n - ready_counts["signatures_by_lanes"].get(width, 0)
+                for width, n in sorted(counts["signatures_by_lanes"].items())
             },
             "host_signatures": counts["host_signatures"],
             "device_suspect": coalescer.device_suspect,
