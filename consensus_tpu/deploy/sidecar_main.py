@@ -45,8 +45,9 @@ device did the work: ``platform`` / ``device_kind`` / ``device_count``, the
 kernel ledger (launches, compiles, compiles since ready), signatures and
 lanes launched on the device (``device_lanes``: the sum of the widths that
 ran; ``launches_by_lanes`` / ``signatures_by_lanes``: how many launches
-rode each width since ready, and how many signatures they carried),
-signatures served from the host, the
+rode each width since ready, and how many signatures they carried;
+``field_path_by_lanes``: whether each compiled width's field arithmetic runs
+as Mosaic kernels or as XLA code), signatures served from the host, the
 coalescer's ``device_suspect`` flag and its degrade count, the flusher
 thread's phase ledger (``flusher``: nanoseconds per phase, queue wait and
 flushes by fill, :mod:`consensus_tpu.obs.kernels`) — plus the wave
@@ -306,6 +307,11 @@ def main() -> int:
             return EXIT_NO_DEVICE
     ready_ledger = KERNELS.totals()
     ready_counts = engine.counts()
+    # The lane each compiled width's field arithmetic took (ops/mosaic25519.py).
+    ask_path = getattr(engine, "field_path", None)
+    field_paths = {
+        str(width): ask_path(width) for width in warmed if ask_path is not None
+    }
     logging.getLogger("consensus_tpu.deploy").info(
         "backend %s up in %.1fs, launch widths %s warm in %.1fs",
         device_report, backend_secs, warmed, warm_secs,
@@ -352,6 +358,7 @@ def main() -> int:
                 str(width): n - ready_counts["signatures_by_lanes"].get(width, 0)
                 for width, n in sorted(counts["signatures_by_lanes"].items())
             },
+            "field_path_by_lanes": field_paths,
             "host_signatures": counts["host_signatures"],
             "device_suspect": coalescer.device_suspect,
             "degrade_count": coalescer.health.suspect_marks,

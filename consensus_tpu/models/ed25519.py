@@ -15,8 +15,12 @@ Split of labor:
   64-step 4-bit-window ``lax.scan``, the fixed-base half as an 8-bit comb
   over constant tables — and a projective comparison against R.  Everything
   is f32 8-bit-limb arithmetic (:mod:`consensus_tpu.ops.field25519`)
-  batched on the trailing axis — one compiled kernel per padded batch size
-  verifies the whole quorum.
+  batched on the trailing axes — one compiled program per padded batch size
+  verifies the whole quorum.  On the TPU a width of 1,024 lanes or more is
+  held limb-major, ``(32, width // 128, 128)``, and runs every field
+  multiply and point operation as one Mosaic kernel
+  (:mod:`consensus_tpu.ops.mosaic25519`); other widths keep ``(32, width)``
+  and XLA.
 
 Batches are padded to the next power of two
 so XLA compiles a handful of shapes once and reuses them forever.
@@ -37,6 +41,7 @@ from consensus_tpu.obs.kernels import instrumented_jit, kernel_lane_suffix, phas
 from consensus_tpu.ops import ed25519 as ed
 from consensus_tpu.ops import field25519 as fe
 from consensus_tpu.ops import limbs
+from consensus_tpu.ops import mosaic25519 as mosaic
 from consensus_tpu.ops import scalar25519 as sc
 
 #: Group order of edwards25519 (RFC 8032).
@@ -70,6 +75,17 @@ def verify_impl(
     with the lookups riding the MXU.  Lookups are one-hot contractions (no
     gathers), and digit 0 adds the identity — the complete addition
     formulas make that branch-free."""
+    # Limb-major from here to the verdict where the width takes the Mosaic
+    # field and point kernels (ops/mosaic25519.py: the TPU, 1,024 lanes or
+    # more): (..., width) becomes (..., width // 128, 128), so a field
+    # element is (32, rows, 128) and its every limb whole vregs.  Every op
+    # below is batch-shape-generic; other widths keep (32, width).
+    width = y_r.shape[-1]
+    if mosaic.launch_path(width) == "mosaic":
+        y_r, sign_r, y_a, sign_a, s_digits8, k_digits, host_ok = (
+            mosaic.limb_major(x)
+            for x in (y_r, sign_r, y_a, sign_a, s_digits8, k_digits, host_ok)
+        )
     # Inputs arrive in the narrowest dtype that holds them (uint8 limbs and
     # digits) — 4x less host->device transfer.  Widen to the compute dtypes
     # on device.
@@ -80,32 +96,29 @@ def verify_impl(
     s_digits8 = s_digits8.astype(jnp.int32)
     k_digits = k_digits.astype(jnp.int32)
     # Decompress R and A in ONE instance of the (large) decompression graph
-    # by stacking them along the trailing batch axis — same total runtime
-    # work, half the traced/compiled graph.
-    batch = y_r.shape[-1]
+    # by stacking them on the first batch axis (the rows when limb-major) —
+    # same total runtime work, half the traced/compiled graph.
+    rows = y_r.shape[1]
     pt, pt_ok = ed.decompress(
-        jnp.concatenate([y_r, y_a], axis=-1),
-        jnp.concatenate([sign_r, sign_a], axis=-1),
+        jnp.concatenate([y_r, y_a], axis=1),
+        jnp.concatenate([sign_r, sign_a], axis=0),
     )
-    r_point = ed.Point(
-        x=pt.x[..., :batch], y=pt.y[..., :batch],
-        z=pt.z[..., :batch], t=pt.t[..., :batch],
-    )
-    a_point = ed.Point(
-        x=pt.x[..., batch:], y=pt.y[..., batch:],
-        z=pt.z[..., batch:], t=pt.t[..., batch:],
-    )
-    r_ok, a_ok = pt_ok[..., :batch], pt_ok[..., batch:]
+    r_point = ed.Point(*(c[:, :rows] for c in pt))
+    a_point = ed.Point(*(c[:, rows:] for c in pt))
+    r_ok, a_ok = pt_ok[:rows], pt_ok[rows:]
     neg_a = ed.negate(a_point)
     # The table coords inherit the inputs' sharding variance so the scan
     # carry type-checks under shard_map.
     a_table = ed.multiples_table(neg_a, _TABLE)
 
-    lanes = jnp.arange(_TABLE, dtype=jnp.int32)[:, None]  # (9, 1)
+    # (9, 1, ...): the table's entries against every lane's digit.
+    lanes = jnp.arange(_TABLE, dtype=jnp.int32)[
+        (slice(None),) + (None,) * (k_digits.ndim - 1)
+    ]
 
     def step(acc: ed.Point, k_w):
         d = k_w - 8             # signed digit in [-8, 7]
-        k_oh = (jnp.abs(d)[None] == lanes).astype(jnp.float32)  # (9, batch)
+        k_oh = (jnp.abs(d)[None] == lanes).astype(jnp.float32)  # (9, *batch)
         # 3 T-free doubles as an inner scan (one body in the graph) + the
         # final T-producing double — graph size, not runtime, economy.
         acc, _ = limbs.counted_scan(
@@ -120,7 +133,7 @@ def verify_impl(
     acc, _ = limbs.counted_scan(step, ed.identity_like(y_r), k_digits)
     acc = ed.add(acc, ed.fixed_base_mul_comb(s_digits8))
 
-    return host_ok & r_ok & a_ok & ed.equal(acc, r_point)
+    return (host_ok & r_ok & a_ok & ed.equal(acc, r_point)).reshape(width)
 
 
 #: Rows of a packed wave (:func:`pack_wave`): the 32 bytes each of R, A, S
@@ -300,6 +313,10 @@ class Ed25519BatchVerifier:
         y[:, 31] &= 0x7F
         host_ok &= _y_lt_p(y).reshape(n, 2).all(axis=1)
         return rows, host_ok
+
+    #: The lane the field arithmetic of a ``width``-lane launch takes on this
+    #: process's backend, ``"mosaic"`` or ``"xla"`` (the sidecar's health).
+    field_path = staticmethod(mosaic.launch_path)
 
     def compile_ahead(self, sizes: Sequence[int]) -> None:
         """Compile, one after the other on the calling thread, the width

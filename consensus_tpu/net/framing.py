@@ -122,8 +122,31 @@ def recv_exact(
     try-first pays it only on the reads that actually wait.
     """
     buf = bytearray()
-    first = True
     while len(buf) < n:
+        chunk = _recv_chunk(
+            conn, min(n - len(buf), RECV_CHUNK_BYTES), len(buf),
+            progress_timeout, patient_first, preset,
+        )
+        if chunk is None:
+            return None
+        buf += chunk
+    return bytes(buf)
+
+
+def _recv_chunk(
+    conn: socket.socket,
+    most: int,
+    received: int,
+    progress_timeout: Optional[float],
+    patient_first: bool,
+    preset: bool,
+) -> Optional[bytes]:
+    """One ``recv`` of at most ``most`` bytes for a read that has
+    ``received`` bytes so far, under :func:`recv_exact`'s rules; None on
+    EOF / reset / an unarmed timeout, :class:`FrameStall` past the
+    progress deadline."""
+    first = received == 0
+    while True:
         if progress_timeout is not None and not preset:
             try:
                 conn.settimeout(
@@ -132,7 +155,7 @@ def recv_exact(
             except OSError:
                 return None
         try:
-            chunk = conn.recv(min(n - len(buf), RECV_CHUNK_BYTES))
+            chunk = conn.recv(most)
         except BlockingIOError:
             # preset non-blocking lane: nothing waiting — block on
             # readiness, patiently for a frame's first byte, under the
@@ -145,7 +168,7 @@ def recv_exact(
             if not ready:
                 raise FrameStall(
                     f"no progress for {progress_timeout:g}s mid-frame",
-                    received=len(buf),
+                    received=received,
                 )
             continue
         except socket.timeout as exc:
@@ -154,16 +177,58 @@ def recv_exact(
             if progress_timeout is not None:
                 raise FrameStall(
                     f"no progress for {progress_timeout:g}s mid-frame",
-                    received=len(buf),
+                    received=received,
                 ) from exc
             return None
         except OSError:
             return None
-        if not chunk:
-            return None
-        buf += chunk
-        first = False
-    return bytes(buf)
+        return chunk or None
+
+
+class FrameReader:
+    """:func:`recv_exact` for a connection read frame after frame, through
+    one receive buffer: a ``recv`` takes whatever has arrived, up to
+    :data:`RECV_CHUNK_BYTES`, and the reads it covers take no syscall.  A
+    sender that writes frames back to back (a peer's joined writes) costs
+    one ``recv`` — and one hand-back of the interpreter lock — a chunk, not
+    two a frame.
+
+    The same rules as :func:`recv_exact`, read by read: the buffer holds
+    at most one chunk beyond the read in progress, so memory follows bytes
+    received, never a claimed length; the progress deadline and
+    ``patient_first`` apply to the bytes a read still waits for."""
+
+    __slots__ = ("_conn", "_buf", "_at")
+
+    def __init__(self, conn: socket.socket) -> None:
+        self._conn = conn
+        self._buf = bytearray()
+        self._at = 0  # first unread byte of _buf
+
+    def read(
+        self,
+        n: int,
+        *,
+        progress_timeout: Optional[float] = None,
+        patient_first: bool = False,
+        preset: bool = False,
+    ) -> Optional[bytes]:
+        """Exactly ``n`` bytes, or None / :class:`FrameStall` as
+        :func:`recv_exact` gives them."""
+        while len(self._buf) - self._at < n:
+            if self._at:
+                del self._buf[: self._at]
+                self._at = 0
+            chunk = _recv_chunk(
+                self._conn, RECV_CHUNK_BYTES, len(self._buf),
+                progress_timeout, patient_first, preset,
+            )
+            if chunk is None:
+                return None
+            self._buf += chunk
+        out = bytes(self._buf[self._at : self._at + n])
+        self._at += n
+        return out
 
 
 class GuardStats:

@@ -54,7 +54,7 @@ import threading
 from typing import Callable, Mapping, Optional, Sequence, Tuple
 
 from consensus_tpu.api.deps import Comm
-from consensus_tpu.net.framing import FrameStall, ListenerGuard, recv_exact
+from consensus_tpu.net.framing import FrameReader, FrameStall, ListenerGuard, recv_exact
 from consensus_tpu.wire import ConsensusMessage, decode_message, encode_message
 
 logger = logging.getLogger("consensus_tpu.net")
@@ -377,6 +377,9 @@ class TcpComm(Comm):
             except OSError:
                 pass
             return
+        # Frames come off one receive buffer: a peer's joined writes cost
+        # one recv a chunk, not two a frame (net/framing.py FrameReader).
+        reader = FrameReader(conn)
         try:
             while not self._stopped.is_set():
                 plan = self.fault_plan
@@ -403,8 +406,8 @@ class TcpComm(Comm):
                         guard.progress_timeout, True, True
                     )
                 try:
-                    header = recv_exact(
-                        conn, _HEADER.size,
+                    header = reader.read(
+                        _HEADER.size,
                         progress_timeout=timeout, patient_first=patient,
                         preset=preset,
                     )
@@ -424,8 +427,8 @@ class TcpComm(Comm):
                     strike("oversized")
                     return
                 try:
-                    payload = recv_exact(
-                        conn, length, progress_timeout=timeout, preset=preset,
+                    payload = reader.read(
+                        length, progress_timeout=timeout, preset=preset,
                     )
                 except FrameStall:
                     strike("stall")

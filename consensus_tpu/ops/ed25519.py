@@ -10,6 +10,15 @@ double + two selected adds of constant shape, exactly what XLA wants).
 Point decompression (RFC 8032 §5.1.3) runs on-device too: the square root
 is a fixed-exponent ``pow_const`` chain, so a batch of compressed keys and
 R points decompresses in two scans — no per-element host math.
+
+Each formula is written once over a field namespace ``F``: the XLA lane
+(:mod:`~consensus_tpu.ops.field25519`) or, inside a Mosaic kernel,
+:class:`~consensus_tpu.ops.field25519.VregField`.  Where the field ops
+would run as kernels (the TPU, limb-major whole-vreg elements), ``add`` /
+``double`` / ``add_affine`` run as ONE kernel each instead: the raw sums
+between the multiplies stay in vector registers (PERF.md section 6: on a
+TPU v5e a 2,048-lane verify launch took 6.48 ms by the host clock, against
+7.24 with a kernel a field op and 16.75 in XLA).
 """
 
 from __future__ import annotations
@@ -21,6 +30,7 @@ import jax.numpy as jnp
 
 from consensus_tpu.ops import field25519 as fe
 from consensus_tpu.ops import limbs
+from consensus_tpu.ops import mosaic25519 as mosaic
 
 # Base point of edwards25519 (RFC 8032).
 _BY = (4 * pow(5, fe.P - 2, fe.P)) % fe.P
@@ -28,7 +38,8 @@ _BX = 15112221349535400772501151409588531511454012693041857206046113283949847762
 
 
 class Point(NamedTuple):
-    """Batched point in extended coordinates; each field is (20, *batch) int32."""
+    """Batched point in extended coordinates; each field is a (32, *batch)
+    float32 limb vector."""
 
     x: jnp.ndarray
     y: jnp.ndarray
@@ -83,42 +94,107 @@ def negate(p: Point) -> Point:
 _D2 = fe.D2
 
 
-def add(p: Point, q: Point) -> Point:
-    """add-2008-hwcd-3: 8M + 1 constant mul.
+def _add(F, p: Point, q: Point) -> Point:
+    """add-2008-hwcd-3: 8M + 1 constant mul, over the field namespace ``F``
+    (:mod:`~consensus_tpu.ops.field25519`, or
+    :class:`~consensus_tpu.ops.field25519.VregField` inside a kernel).
 
     Every intermediate add/sub stays *unreduced* (one raw level, limb bound
     600/680) and feeds straight into a multiplication — all operand-bound
     products stay under the 2^19 exactness budget, so the formula needs no
     carry passes outside the multiplies themselves."""
-    a = fe.mul(fe.sub_raw(p.y, p.x), fe.sub_raw(q.y, q.x))
-    b = fe.mul(fe.add_raw(p.y, p.x), fe.add_raw(q.y, q.x))
-    c = fe.mul(fe.mul(p.t, fe.constant_like(_D2, p.t)), q.t)
-    d = fe.mul(fe.add_raw(p.z, p.z), q.z)
-    e = fe.sub_raw(b, a)
-    f = fe.sub_raw(d, c)
-    g = fe.add_raw(d, c)
-    h = fe.add_raw(b, a)
-    return Point(x=fe.mul(e, f), y=fe.mul(g, h), z=fe.mul(f, g), t=fe.mul(e, h))
+    a = F.mul(F.sub_raw(p.y, p.x), F.sub_raw(q.y, q.x))
+    b = F.mul(F.add_raw(p.y, p.x), F.add_raw(q.y, q.x))
+    c = F.mul(F.mul(p.t, F.constant_like(_D2, p.t)), q.t)
+    d = F.mul(F.add_raw(p.z, p.z), q.z)
+    e = F.sub_raw(b, a)
+    f = F.sub_raw(d, c)
+    g = F.add_raw(d, c)
+    h = F.add_raw(b, a)
+    return Point(x=F.mul(e, f), y=F.mul(g, h), z=F.mul(f, g), t=F.mul(e, h))
 
 
-def double(p: Point, *, need_t: bool = True) -> Point:
-    """dbl-2008-hwcd: 4M + 4S (3M + 4S with ``need_t=False`` — the T input
-    is never read by doubling, so runs of doubles skip producing it).
+def _double(F, p: Point, need_t: bool) -> Point:
+    """dbl-2008-hwcd: 4M + 4S (3M + 4S without T) over ``F``.
 
     Lazy-reduction layout: A/B/ZZ use the half-cost specialized squaring
     (inputs weakly reduced), C/H/G/XY stay raw; only E and F — whose raw
     bounds would overflow the multiply budget — get reduced."""
-    a = fe.square(p.x)
-    b = fe.square(p.y)
-    zz = fe.square(p.z)
-    c = fe.add_raw(zz, zz)          # <= 680
-    h = fe.add_raw(a, b)            # <= 680
-    xy = fe.add_raw(p.x, p.y)       # <= 680: square() bound is 500 -> mul
-    e = fe.sub(h, fe.mul(xy, xy))   # reduced: raw h - weak square
-    g = fe.sub_raw(a, b)            # <= 600
-    f = fe.add(c, g)                # reduced: 680 + 600 would exceed 724
-    t = fe.mul(e, h) if need_t else p.t
-    return Point(x=fe.mul(e, f), y=fe.mul(g, h), z=fe.mul(f, g), t=t)
+    a = F.square(p.x)
+    b = F.square(p.y)
+    zz = F.square(p.z)
+    c = F.add_raw(zz, zz)           # <= 680
+    h = F.add_raw(a, b)             # <= 680
+    xy = F.add_raw(p.x, p.y)        # <= 680: square() bound is 500 -> mul
+    e = F.sub(h, F.mul(xy, xy))     # reduced: raw h - weak square
+    g = F.sub_raw(a, b)             # <= 600
+    f = F.add(c, g)                 # reduced: 680 + 600 would exceed 724
+    t = F.mul(e, h) if need_t else p.t
+    return Point(x=F.mul(e, f), y=F.mul(g, h), z=F.mul(f, g), t=t)
+
+
+def _add_affine(F, p: Point, q_x, q_y, q_t) -> Point:
+    """madd-2008-hwcd-3 over ``F``: 7M + 1 constant mul (the D = 2 Z1 Z2
+    multiply degenerates to a raw doubling of p.z)."""
+    a = F.mul(F.sub_raw(p.y, p.x), F.sub_raw(q_y, q_x))
+    b = F.mul(F.add_raw(p.y, p.x), F.add_raw(q_y, q_x))
+    c = F.mul(F.mul(p.t, F.constant_like(_D2, p.t)), q_t)
+    d = F.add_raw(p.z, p.z)
+    e = F.sub_raw(b, a)
+    f = F.sub_raw(d, c)
+    g = F.add_raw(d, c)
+    h = F.add_raw(b, a)
+    return Point(x=F.mul(e, f), y=F.mul(g, h), z=F.mul(f, g), t=F.mul(e, h))
+
+
+# Kernel bodies: the formulas above on the coordinates a kernel was handed.
+def _add_body(F, x1, y1, z1, t1, x2, y2, z2, t2):
+    return _add(F, Point(x1, y1, z1, t1), Point(x2, y2, z2, t2))
+
+
+def _double_body(F, x, y, z):
+    return _double(F, Point(x, y, z, None), True)
+
+
+def _double_xyz_body(F, x, y, z):
+    return _double(F, Point(x, y, z, None), False)[:3]
+
+
+def _add_affine_body(F, x, y, z, t, q_x, q_y, q_t):
+    return _add_affine(F, Point(x, y, z, t), q_x, q_y, q_t)
+
+
+def _on_mosaic(*coords) -> bool:
+    """A point op runs as ONE kernel where its field ops would each run as
+    one (:func:`consensus_tpu.ops.mosaic25519.active`), after the MXU lane
+    (which keeps the XLA formula) and never while the counting shim traces
+    (the XLA formula notes each field op it makes)."""
+    from consensus_tpu.ops import mxu_limbs
+
+    return (
+        not limbs.counting()
+        and not mxu_limbs.lane_active()
+        and mosaic.active(*coords)
+    )
+
+
+def add(p: Point, q: Point) -> Point:
+    """p + q, complete (:func:`_add`); one kernel on the Mosaic path."""
+    if _on_mosaic(*p, *q):
+        return Point(*mosaic.run(_add_body, 4, *p, *q))
+    return _add(fe, p, q)
+
+
+def double(p: Point, *, need_t: bool = True) -> Point:
+    """2p (:func:`_double`); ``need_t=False`` skips T (doubling never reads
+    it, so runs of doubles only need it at the end).  One kernel on the
+    Mosaic path."""
+    if _on_mosaic(p.x, p.y, p.z):
+        if need_t:
+            return Point(*mosaic.run(_double_body, 4, p.x, p.y, p.z))
+        x, y, z = mosaic.run(_double_xyz_body, 3, p.x, p.y, p.z)
+        return Point(x, y, z, p.t)
+    return _double(fe, p, need_t)
 
 
 def select(cond: jnp.ndarray, p: Point, q: Point) -> Point:
@@ -282,40 +358,36 @@ def _comb_table_np() -> tuple["np.ndarray", "np.ndarray", "np.ndarray"]:
 
 
 def add_affine(p: Point, q_x: jnp.ndarray, q_y: jnp.ndarray, q_t: jnp.ndarray) -> Point:
-    """Mixed addition p + q with q affine (Z=1, T=XY given): madd-2008-hwcd-3
-    — 7M + 1 constant mul (the D = 2 Z1 Z2 multiply degenerates to a raw
-    doubling of p.z).  Same lazy-reduction discipline as :func:`add`."""
-    a = fe.mul(fe.sub_raw(p.y, p.x), fe.sub_raw(q_y, q_x))
-    b = fe.mul(fe.add_raw(p.y, p.x), fe.add_raw(q_y, q_x))
-    c = fe.mul(fe.mul(p.t, fe.constant_like(_D2, p.t)), q_t)
-    d = fe.add_raw(p.z, p.z)
-    e = fe.sub_raw(b, a)
-    f = fe.sub_raw(d, c)
-    g = fe.add_raw(d, c)
-    h = fe.add_raw(b, a)
-    return Point(x=fe.mul(e, f), y=fe.mul(g, h), z=fe.mul(f, g), t=fe.mul(e, h))
+    """Mixed addition p + q with q affine (Z=1, T=XY given):
+    :func:`_add_affine`, one kernel on the Mosaic path.  Same lazy-reduction
+    discipline as :func:`add`."""
+    if _on_mosaic(*p, q_x, q_y, q_t):
+        return Point(*mosaic.run(_add_affine_body, 4, *p, q_x, q_y, q_t))
+    return _add_affine(fe, p, q_x, q_y, q_t)
 
 
 def fixed_base_mul_comb(s_digits8: jnp.ndarray) -> Point:
-    """[S]B from 8-bit window digits ``s_digits8`` of shape (32, batch),
+    """[S]B from 8-bit window digits ``s_digits8`` of shape (32, *batch),
     LSB window first: one constant-table lookup + one mixed add per window,
     zero doubles.  The lookups are one-hot contractions against broadcast
     constants — they lower to (256 x 128) x batch matmuls (MXU work), while
     the adds stay on the VPU."""
     xs, ys, ts = _comb_table_np()
-    lanes = jnp.arange(1 << _COMB_BITS, dtype=jnp.int32)[:, None]  # (256, 1)
+    unit = (None,) * (s_digits8.ndim - 1)  # one per batch axis
+    lanes = jnp.arange(1 << _COMB_BITS, dtype=jnp.int32)[(slice(None),) + unit]
 
     # Stack the per-window tables as scan inputs, limbs trailing the entry
-    # axis: (32, 256, 32limbs, 1) broadcasting against (256, batch) one-hots.
+    # axis: (32, 256, 32limbs, 1, ...) broadcasting against (256, *batch)
+    # one-hots.
     def coords(arr) -> jnp.ndarray:
-        return jnp.asarray(arr)[..., None]  # (32, 256, 32, 1)
+        return jnp.asarray(arr)[(Ellipsis,) + unit]
 
     def step(acc: Point, inputs):
-        digits, tx, ty, tt = inputs  # (batch,), (256, 32, 1) x3
-        oh = (digits[None] == lanes).astype(jnp.float32)  # (256, batch)
+        digits, tx, ty, tt = inputs  # (*batch), (256, 32, 1, ...) x3
+        oh = (digits[None] == lanes).astype(jnp.float32)  # (256, *batch)
 
         def pick(tbl: jnp.ndarray) -> jnp.ndarray:
-            return jnp.sum(tbl * oh[:, None], axis=0)  # (32, batch)
+            return jnp.sum(tbl * oh[:, None], axis=0)  # (32, *batch)
 
         return add_affine(acc, pick(tx), pick(ty), pick(tt)), None
 

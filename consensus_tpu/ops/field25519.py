@@ -21,17 +21,25 @@ convolution) followed by *parallel* carry-save passes (split with
 borrows propagate like arithmetic shifts).  There are no sequential carry
 chains on the hot path.
 
-Why pure XLA and no hand-written Pallas kernel *on this lane*: the verify
-graph is a ``lax.scan`` of elementwise/broadcast limb arithmetic, which
-XLA already fuses into large VPU kernels; a per-field-op ``pallas_call``
-only adds launch overhead (a round-2 prototype confirmed parity but no
-win and was removed), and the whole-scan-in-VMEM kernels that followed
-were refused by Mosaic on the chip and removed in PR 22 (PERF.md §6).
+Two lanes run this arithmetic, chosen per op at trace time.  On the TPU,
+an element held **limb-major** as ``(32, rows, 128)`` with ``rows`` a
+multiple of 8 (1,024 lanes or more: every limb whole vregs) goes to ONE
+Mosaic kernel per ``mul`` / ``square``
+(:mod:`consensus_tpu.ops.mosaic25519`; the point ops of
+:mod:`consensus_tpu.ops.ed25519` likewise run one kernel each).  What the
+chip showed (PERF.md sections 5 and 6): XLA does NOT fuse this code into
+large VPU kernels there — the pads of the 32 product rows and the
+concatenated one-limb slices of the carry passes split one ``mul`` at
+2,048 lanes into 15 fusions that write 12 MB through VMEM, and one step of
+the verify kernel's Horner scan into 1,015; a kernel keeps the product
+columns in vector registers and writes only the 32 result limbs.  Every
+other shape (the CPU, 256 / 512 lanes, a broadcast operand) runs the XLA
+code below, bit for bit the same result.
 One headroom item remains behind ``CTPU_MXU_LIMBS=1``:
 :mod:`consensus_tpu.ops.mxu_limbs` re-expresses the schoolbook convolution
 as integer ``dot_general`` tiles for the MXU (``mul``/``square`` below
-dispatch there at trace time, bit-identical output).  Counted denominators
-for the A/B live in PERF.md §5.
+dispatch there first, bit-identical output).  Counted denominators for the
+A/B live in PERF.md §5.
 
 Normalization contract: public ops take and return *weakly reduced*
 elements — |limb| <= 340 with value within (-2^250, 2^255 + 2^13), exact
@@ -44,8 +52,10 @@ from __future__ import annotations
 import numpy as np
 
 import jax.numpy as jnp
+from jax import lax
 
 from consensus_tpu.ops import limbs
+from consensus_tpu.ops import mosaic25519 as mosaic
 from consensus_tpu.ops.limbs import carry_i32
 
 
@@ -121,9 +131,10 @@ def zeros_like_batch(batch_shape) -> jnp.ndarray:
 
 def _split(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """x -> (x mod 256, floor(x / 256)); exact for |x| < 2^24, floor
-    semantics so negative limbs borrow correctly."""
-    hi = jnp.floor(x * INV_BASE)
-    return x - hi * BASE, hi
+    semantics so negative limbs borrow correctly.  Both lanes split with
+    it: an array of limbs here, one limb at a time in :class:`VregField`."""
+    hi = lax.floor(lax.mul(x, INV_BASE))
+    return lax.sub(x, lax.mul(hi, BASE)), hi
 
 
 def _relax(x: jnp.ndarray) -> jnp.ndarray:
@@ -216,13 +227,16 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     With ``CTPU_MXU_LIMBS=1`` (trace-time) this dispatches to the
     bit-identical MXU lane, which records its work as ``note_dot`` MACs —
     the dispatch sits BEFORE the ``note_mul`` so a counted trace reports
-    muls or dots per site, never both."""
+    muls or dots per site, never both.  Then, after the note, whole-vreg
+    limb-major operands on the TPU take the one-kernel Mosaic lane."""
     from consensus_tpu.ops import mxu_limbs
 
     if mxu_limbs.lane_active():
         return mxu_limbs.mul25519(a, b)
     if limbs.counting():
         limbs.note_mul(_note_lanes(a, b))
+    if mosaic.active(a, b):
+        return mosaic.mul(a, b)
     batch_pad = [(0, 0)] * (a.ndim - 1)
     terms = [
         jnp.pad(a[i] * b, [(i, LIMBS - 1 - i)] + batch_pad) for i in range(LIMBS)
@@ -240,13 +254,16 @@ def square(a: jnp.ndarray) -> jnp.ndarray:
 
     The MXU lane squares via ``mul(a, a)`` — the full product columns
     equal these doubled-triangle columns as integers, so the output stays
-    bit-identical."""
+    bit-identical.  Whole-vreg limb-major operands on the TPU take the
+    Mosaic lane, as :func:`mul` does."""
     from consensus_tpu.ops import mxu_limbs
 
     if mxu_limbs.lane_active():
         return mxu_limbs.square25519(a)
     if limbs.counting():
         limbs.note_square(_note_lanes(a))
+    if mosaic.active(a):
+        return mosaic.square(a)
     batch_pad = [(0, 0)] * (a.ndim - 1)
     doubled = a + a
     terms = []
@@ -258,6 +275,104 @@ def square(a: jnp.ndarray) -> jnp.ndarray:
         row = jnp.concatenate([a[i : i + 1] * a[i], doubled[i + 1 :] * a[i]], axis=0)
         terms.append(jnp.pad(row, [(2 * i, LIMBS - 1 - i)] + batch_pad))
     return _reduce_cols(sum(terms))
+
+
+# --- the same field on a list of limbs --------------------------------------
+
+
+def _sum(terms):
+    acc = terms[0]
+    for t in terms[1:]:
+        acc = lax.add(acc, t)
+    return acc
+
+
+class VregField:
+    """The field above on an element held as a LIST of its 32 limbs: inside
+    a Mosaic kernel (:mod:`~consensus_tpu.ops.mosaic25519`) each limb is one
+    ``(8, 128)`` vreg, and the point formulas of
+    :mod:`~consensus_tpu.ops.ed25519` run on this class as on this module.
+
+    The array code above, op for op, with its shifts written on the list:
+    the same :func:`_split`, column sums, fold at 38, three relax passes,
+    top fold at 19 and 2p bias.  Every value is an integer under 2^24, so
+    the order of a column's additions cannot change a bit and the results
+    are the array code's bit for bit (``tests/test_mosaic25519.py``).
+    Written with ``lax`` primitives: a point kernel's body is some 20,000
+    of them, traced about four times faster than ``jnp`` operators."""
+
+    _TWO_P = [float(v) for v in _TWO_P]
+
+    @staticmethod
+    def _relax(x):
+        lo, hi = zip(*map(_split, x))
+        return [lax.add(lo[0], lax.mul(hi[LIMBS - 1], float(FOLD)))] + [
+            lax.add(lo[i], hi[i - 1]) for i in range(1, LIMBS)
+        ]
+
+    @staticmethod
+    def _weak_reduce(x):
+        x = VregField._relax(VregField._relax(VregField._relax(x)))
+        high = lax.floor(lax.mul(x[LIMBS - 1], 1.0 / 128.0))
+        return (
+            [lax.add(x[0], lax.mul(high, float(TOP_FOLD)))]
+            + list(x[1 : LIMBS - 1])
+            + [lax.sub(x[LIMBS - 1], lax.mul(high, 128.0))]
+        )
+
+    @staticmethod
+    def _reduce_cols(cols):
+        lo, hi = zip(*map(_split, cols))
+        c = (
+            [lo[0]]
+            + [lax.add(lo[k], hi[k - 1]) for k in range(1, 2 * LIMBS - 1)]
+            + [hi[-1]]
+        )
+        return VregField._weak_reduce(
+            [lax.add(c[i], lax.mul(c[i + LIMBS], float(FOLD))) for i in range(LIMBS)]
+        )
+
+    @staticmethod
+    def mul(a, b):
+        return VregField._reduce_cols([
+            _sum([lax.mul(a[i], b[k - i])
+                  for i in range(max(0, k - LIMBS + 1), min(k, LIMBS - 1) + 1)])
+            for k in range(2 * LIMBS - 1)
+        ])
+
+    @staticmethod
+    def square(a):
+        doubled = [lax.add(x, x) for x in a]
+        cols = []
+        for k in range(2 * LIMBS - 1):
+            terms = [lax.mul(a[i], doubled[k - i])
+                     for i in range(max(0, k - LIMBS + 1), (k + 1) // 2)]
+            if k % 2 == 0:
+                terms.append(lax.mul(a[k // 2], a[k // 2]))
+            cols.append(_sum(terms))
+        return VregField._reduce_cols(cols)
+
+    @staticmethod
+    def add_raw(a, b):
+        return [lax.add(x, y) for x, y in zip(a, b)]
+
+    @staticmethod
+    def sub_raw(a, b):
+        return [lax.sub(lax.add(x, t), y) for x, t, y in zip(a, VregField._TWO_P, b)]
+
+    @staticmethod
+    def add(a, b):
+        return VregField._weak_reduce(VregField.add_raw(a, b))
+
+    @staticmethod
+    def sub(a, b):
+        return VregField._weak_reduce(VregField.sub_raw(a, b))
+
+    @staticmethod
+    def constant_like(value, like):
+        """A constant's limbs as Python floats: a limb of a product with it
+        is a vreg times a scalar."""
+        return [float(v) for v in int_to_limbs(value % P)]
 
 
 _P_LIMBS_I32 = np.array(

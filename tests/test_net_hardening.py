@@ -41,6 +41,7 @@ from consensus_tpu.metrics import (
 from consensus_tpu.net import TcpComm
 from consensus_tpu.net.framing import (
     MALFORMED_KINDS,
+    FrameReader,
     FrameStall,
     ListenerGuard,
     recv_exact,
@@ -197,6 +198,84 @@ def test_recv_exact_eof_returns_none():
     a.close()
     try:
         assert recv_exact(b, 4) is None
+    finally:
+        b.close()
+
+
+# --- FrameReader: recv_exact's rules through one receive buffer --------------
+
+
+class _CountingConn:
+    """A blocking socket whose ``recv`` calls are counted."""
+
+    def __init__(self, sock):
+        self.sock, self.recvs = sock, 0
+
+    def recv(self, n):
+        self.recvs += 1
+        return self.sock.recv(n)
+
+    def settimeout(self, t):
+        self.sock.settimeout(t)
+
+
+def test_frame_reader_takes_back_to_back_frames_with_one_recv():
+    frames = [struct.pack(">I", len(p)) + p for p in (b"a" * 140, b"", b"b" * 7)]
+    a, b = socket.socketpair()
+    try:
+        a.sendall(b"".join(frames))
+        conn = _CountingConn(b)
+        reader = FrameReader(conn)
+        got = []
+        for _ in frames:
+            (length,) = struct.unpack(">I", reader.read(4))
+            got.append(reader.read(length))
+        assert got == [b"a" * 140, b"", b"b" * 7]
+        assert conn.recvs == 1  # recv_exact: two a frame
+    finally:
+        a.close()
+        b.close()
+
+
+def test_frame_reader_huge_claim_allocates_only_received_bytes():
+    a, b = socket.socketpair()
+    try:
+        a.sendall(b"x" * 100)
+        a.close()
+        tracemalloc.start()
+        out = FrameReader(b).read(HUGE_LENGTH)
+        _, peak = tracemalloc.get_traced_memory()
+        tracemalloc.stop()
+        assert out is None  # EOF long before 2 GiB
+        assert peak < 8 * 1024 * 1024, f"allocated {peak} bytes for a claim"
+    finally:
+        b.close()
+
+
+def test_frame_reader_midframe_stall_counts_the_bytes_of_its_read():
+    a, b = socket.socketpair()
+    try:
+        a.sendall(b"\x00\x01\x02\x03\x04\x05")
+        reader = FrameReader(b)
+        assert reader.read(4, progress_timeout=0.2) == b"\x00\x01\x02\x03"
+        with pytest.raises(FrameStall) as exc:
+            reader.read(10, progress_timeout=0.2)
+        assert exc.value.received == 2  # the buffered rest of this read
+    finally:
+        a.close()
+        b.close()
+
+
+def test_frame_reader_patient_first_byte_then_eof_returns_none():
+    a, b = socket.socketpair()
+    try:
+        b.setblocking(False)  # the pinned connection's non-blocking lane
+        reader = FrameReader(b)
+        threading.Timer(0.3, lambda: (a.sendall(b"\x07" * 3), a.close())).start()
+        assert reader.read(
+            3, progress_timeout=0.1, patient_first=True, preset=True
+        ) == b"\x07" * 3  # the first byte waited past the deadline
+        assert reader.read(1, progress_timeout=0.1, preset=True) is None
     finally:
         b.close()
 

@@ -114,6 +114,8 @@ def test_the_ladder_is_the_launch_widths_rule_on_the_spec(rig):
     assert ready["compiles"] == 3 and ready["compiles_after_ready"] == 0
     assert ready["launches_by_lanes"] == {"256": 0, "512": 0, "1024": 0}
     assert ready["signatures_by_lanes"] == {"256": 0, "512": 0, "1024": 0}
+    # The CPU backend: every width's field arithmetic is XLA code.
+    assert ready["field_path_by_lanes"] == {"256": "xla", "512": "xla", "1024": "xla"}
 
 
 def test_every_replica_delivers_every_request_once_in_the_references_order(rig):
